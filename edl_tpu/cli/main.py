@@ -920,6 +920,9 @@ def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool):
     import jax
 
     from edl_tpu.models import llama
+    from edl_tpu.utils import jaxcache
+
+    jaxcache.configure()
 
     if mesh_arg:
         from edl_tpu.parallel.mesh import MeshPlan
@@ -1349,7 +1352,9 @@ def run_loadgen(args) -> int:
         import jax
 
         from edl_tpu.models import llama
+        from edl_tpu.utils import jaxcache
 
+        jaxcache.configure()
         cfg = llama.LlamaConfig.tiny(vocab=args.vocab)
         params = jax.jit(
             lambda: llama.init_params(jax.random.PRNGKey(1), cfg)
@@ -1549,7 +1554,9 @@ def _run_fleet_replica(args) -> int:
 
     from edl_tpu.serving.replica import ReplicaServer
     from edl_tpu.serving.scheduler import RequestQueue
+    from edl_tpu.utils import jaxcache
 
+    jaxcache.configure()
     params = cfg = None
     if getattr(args, "warm_from", None) == "p2p":
         # p2p warm-start: pull live weights + architecture doc from a
@@ -1933,12 +1940,14 @@ def run_predict(args) -> int:
         load_rows,
         predict_batch,
     )
+    from edl_tpu.utils import jaxcache
 
     try:
         rows = load_rows(args.input, args.data_dir, n_rows=args.rows)
     except (ValueError, FileNotFoundError) as e:
         print(f"bad input: {e}", file=sys.stderr)
         return 1
+    jaxcache.configure()
     try:
         params, doc = load_params_for_predict(
             args.export_dir, args.mesh or None

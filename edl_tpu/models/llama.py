@@ -41,7 +41,9 @@ class LlamaConfig:
     rope_theta: float = 500000.0
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16  # activation dtype (params stay f32)
-    use_flash: bool = False  # pallas flash attention (TPU, T % 128 == 0)
+    # pallas flash attention; a length flash_supported() rejects raises
+    # (never a silent dense substitute)
+    use_flash: bool = False
     # rematerialize each layer in the backward pass: only the [B,T,d]
     # layer inputs are saved across the scan, trading ~33% more forward
     # FLOPs for O(L·B·T·d) instead of O(L·B·T·(d+ff+heads)) activation
@@ -124,6 +126,22 @@ class LlamaConfig:
     @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
         return cls()
+
+    @classmethod
+    def flagship(cls) -> "LlamaConfig":
+        """THE flagship training definition (BASELINE config #5 at the
+        scale one v5e chip trains): d2048/L16/ff6144/v32768, bf16
+        activations, pallas flash attention, per-layer remat. The ONE
+        factory ``chip_smoke.py``, ``bench.py`` and every
+        ``scripts/exp_*`` measurement share — a drifted inline copy
+        silently invalidates "same config as the recorded numbers".
+        Serving uses it with ``remat=False`` (what an export's
+        ``to_meta`` round trip yields)."""
+        return cls(
+            vocab=32768, d_model=2048, n_layers=16, n_heads=16,
+            n_kv_heads=8, d_ff=6144, dtype=jnp.bfloat16, use_flash=True,
+            remat=True,
+        )
 
     @classmethod
     def tiny(cls, vocab: int = 256) -> "LlamaConfig":
@@ -273,15 +291,19 @@ def attention(
         elif cfg.sp_impl == "ulysses":
             from edl_tpu.parallel.ulysses import ulysses_attention
 
-            return ulysses_attention(q, k, v, mesh, axis="sp", causal=True)
+            return ulysses_attention(
+                q, k, v, mesh, axis="sp", causal=True,
+                use_flash=cfg.use_flash,
+            )
         raise ValueError(f"unknown sp_impl {cfg.sp_impl!r}")
     if cfg.use_flash:
-        from edl_tpu.ops.flash_attention import attention_auto, flash_supported
+        from edl_tpu.ops.flash_attention import attention_auto
 
-        if flash_supported(t):
-            # kernel handles GQA natively (no K/V repeat) and falls back
-            # to interpret mode off-TPU
-            return attention_auto(q, k, v, causal=True)
+        # the kernel or its ValueError (a length it does not support),
+        # never a silent dense substitute. GQA-native: no K/V repeat.
+        if mesh is not None and mesh.size > 1:
+            return _flash_per_shard(q, k, v, mesh)
+        return attention_auto(q, k, v, causal=True)
     groups = h // k.shape[2]
     k = jnp.repeat(k, groups, axis=2)
     v = jnp.repeat(v, groups, axis=2)
@@ -290,6 +312,35 @@ def attention(
     scores = jnp.where(mask[None, None], scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
+
+
+def _flash_per_shard(q, k, v, mesh) -> jnp.ndarray:
+    """The flash kernel under a multi-device mesh. GSPMD cannot
+    partition a Mosaic kernel (the TPU compiler refuses the program:
+    "wrap the call in a shard_map"), and attention is independent per
+    batch row and per kv-head group — so each device runs the kernel on
+    its own shard: batch over the mesh's batch axes, heads over ``tp``
+    when it divides the kv heads (contiguous head blocks keep each
+    query head beside its GQA kv head). Any other axis sees replicas."""
+    from jax import shard_map
+
+    from edl_tpu.api.job import BATCH_AXES
+    from edl_tpu.ops.flash_attention import attention_auto
+
+    batch = tuple(a for a in mesh.axis_names if a in BATCH_AXES)
+    tp = (
+        "tp"
+        if "tp" in mesh.axis_names and k.shape[2] % mesh.shape["tp"] == 0
+        else None
+    )
+    spec = P(batch or None, None, tp, None)
+    return shard_map(
+        lambda q, k, v: attention_auto(q, k, v, causal=True),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 _INT8_WEIGHTS = ("wq", "wk", "wv", "wo", "w1", "w2", "w3")
@@ -454,24 +505,6 @@ def forward(
         # ring/ulysses attention is itself a shard_map; nesting it inside
         # the pipeline shard_map is not supported by jax
         raise ValueError("sp and pp cannot be combined in one llama mesh")
-    if (
-        sp == 1  # the pp path also runs the flash kernel per stage
-        and cfg.remat
-        and cfg.remat_policy == "attn"
-        and cfg.use_flash
-    ):
-        from edl_tpu.ops.flash_attention import flash_supported
-
-        if not flash_supported(tokens.shape[1]):
-            # attention() would silently take the dense XLA path, the
-            # flash_out/flash_lse names would never exist, and the
-            # policy would degrade to FULL remat — the exact failure
-            # the use_flash guard in _remat_policy documents
-            raise ValueError(
-                f'remat_policy="attn" needs the flash kernel, but '
-                f"seq len {tokens.shape[1]} is not flash-supported "
-                f"(flash_supported() is False) — pad T or switch policy"
-            )
     if sp > 1 and cfg.remat and cfg.remat_policy == "attn":
         # the sp paths never run the flash kernel, so the flash_out /
         # flash_lse names the policy saves would not exist — the policy
@@ -491,8 +524,12 @@ def forward(
             x, plan.sequence_sharding(mesh, rank=3)
         )
 
+    # inside the pipeline's own shard_map every array is already a
+    # per-device shard: attention must not open a second one
+    layer_mesh = None if pp > 1 else mesh
+
     def body(carry, lp):
-        return _layer(cfg, carry, lp, mesh=mesh, sp=sp), None
+        return _layer(cfg, carry, lp, mesh=layer_mesh, sp=sp), None
 
     if cfg.remat:
         body = jax.checkpoint(body, policy=_remat_policy(cfg))

@@ -83,20 +83,25 @@ _PEAK_TABLE = (
     ("v4", 275e12, 1228e9),
 )
 
-# conservative default (v5e-class) when the kind is opaque — also what
-# a CPU run uses, which keeps CPU-dryrun gauges tiny but NON-ZERO
-_DEFAULT_PEAK = DevicePeak("v5e-assumed", 197e12, 819e9)
+# what a host WITHOUT an accelerator gets from detect_peak(): a nominal
+# denominator that keeps CPU-dryrun gauges tiny but NON-ZERO, named so
+# no reading of it passes for a device's. Never the answer for a TPU.
+_HOST_NOMINAL_PEAK = DevicePeak("host-nominal", 197e12, 819e9)
 
 
 def peak_for_kind(kind: str) -> DevicePeak:
     """Spec-table lookup by device-kind substring, no env overrides —
     what the bench uses so published pct-of-peak stays comparable
-    across rounds."""
+    across rounds. A kind the table does not know is an ERROR, not a
+    default: a share of an assumed peak is not a measurement."""
     k = (kind or "").lower()
     for sub, fl, bw in _PEAK_TABLE:
         if sub in k:
             return DevicePeak(sub, fl, bw)
-    return _DEFAULT_PEAK
+    raise KeyError(
+        f"device kind {kind!r} is not in the peak table "
+        f"(obs/costmodel.py _PEAK_TABLE) — add its published rates"
+    )
 
 
 def peak_for_device(device) -> DevicePeak:
@@ -105,23 +110,28 @@ def peak_for_device(device) -> DevicePeak:
 
 
 def detect_peak(device: Any = None) -> DevicePeak:
-    """The LIVE-telemetry peak: auto-detected from the local device
-    (lazily importing jax; falls back to the conservative default when
-    jax or devices are unavailable) with env overrides
-    ``EDL_PEAK_TFLOPS`` / ``EDL_PEAK_HBM_GBS`` applied on top — the
-    escape hatch for fleets whose device_kind the table predates."""
-    if device is not None:
-        peak = peak_for_device(device)
-    else:
+    """The LIVE-telemetry peak: the local device's table entry (lazily
+    importing jax) with env overrides ``EDL_PEAK_TFLOPS`` /
+    ``EDL_PEAK_HBM_GBS`` applied on top — the escape hatch for fleets
+    whose device_kind the table predates. A host with no accelerator
+    (CPU tests, device-free control plane) gets the nominal host
+    denominator; an accelerator the table does not know raises unless
+    the overrides name its rates."""
+    tf = os.environ.get("EDL_PEAK_TFLOPS")
+    bw = os.environ.get("EDL_PEAK_HBM_GBS")
+    if device is None:
         try:
             import jax
 
-            peak = peak_for_device(jax.devices()[0])
-        except Exception as e:  # no jax / no devices: defaults, noted
-            peak = DevicePeak(f"unknown ({type(e).__name__})",
-                              _DEFAULT_PEAK.flops, _DEFAULT_PEAK.hbm_bytes_s)
-    tf = os.environ.get("EDL_PEAK_TFLOPS")
-    bw = os.environ.get("EDL_PEAK_HBM_GBS")
+            device = jax.devices()[0]
+        except (ImportError, RuntimeError):  # no jax / no backend
+            device = None
+    if device is None or getattr(device, "platform", None) == "cpu":
+        peak = _HOST_NOMINAL_PEAK
+    elif tf and bw:  # both rates named: the table is not consulted
+        peak = DevicePeak(str(getattr(device, "device_kind", "")), 0.0, 0.0)
+    else:
+        peak = peak_for_device(device)
     if tf or bw:
         peak = DevicePeak(
             peak.kind + "+env",

@@ -184,14 +184,7 @@ def sharded_embedding_lookup(
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-        vma_kwargs = {"check_vma": False}
-    except ImportError:
-        # pre-0.6 jax: shard_map lives in experimental and the
-        # replication-check kwarg is still called check_rep
-        from jax.experimental.shard_map import shard_map
-        vma_kwargs = {"check_rep": False}
+    from jax import shard_map
 
     n = mesh.shape[vocab_axis]
     vocab, _ = table.shape
@@ -215,5 +208,5 @@ def sharded_embedding_lookup(
         mesh=mesh,
         in_specs=(P(vocab_axis, None), ids_pspec),
         out_specs=out_pspec,
-        **vma_kwargs,
+        check_vma=False,
     )(table, ids)

@@ -38,12 +38,19 @@ attention portion of a real remat train step runs at ~53 TF/s effective
 — within 10% of the standalone kernel composite (55.7) — i.e. there is
 NO standalone-vs-in-model integration gap; the long-context MFU ~0.50
 is the honest mix of the ~55%-peak matmul chain with this ~27%-peak
-VPU-bound kernel under mandatory full remat. Falls back to interpret
-mode off-TPU (same code path, test-coverable on CPU).
+VPU-bound kernel under mandatory full remat.
+
+Off-TPU the kernel does not run unless the caller asks for the Pallas
+interpreter: tests pass ``interpret=True`` to :func:`flash_attention`, or
+open :func:`interpret_kernels` around a model call. No device probe
+picks it — a production path that lands on a CPU fails loudly instead
+of timing the interpreter.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -481,7 +488,9 @@ def flash_attention(
     block_k = _fit_block(block_k, t)
     if t % block_q or t % block_k:
         raise ValueError(
-            f"seq len {t} must divide block sizes ({block_q},{block_k})"
+            f"seq len {t} is not flash-supported (flash_supported() is "
+            f"False): it does not divide into blocks ({block_q},{block_k})"
+            f" — pad T to a multiple of 128, or use dense attention"
         )
 
     qb = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
@@ -507,19 +516,38 @@ def flash_supported(t: int, block_q: int = 512, block_k: int = 1024) -> bool:
     return t % bq == 0 and t % bk == 0
 
 
+_INTERPRET = contextvars.ContextVar("edl_flash_interpret", default=False)
+
+
+@contextlib.contextmanager
+def interpret_kernels():
+    """While open, :func:`attention_auto` traces the kernel for the
+    Pallas interpreter — how a CPU test or rehearsal drives a
+    ``use_flash`` model. Nothing in the program opens it. (jax's own
+    ``pltpu.force_tpu_interpret_mode`` cannot stand in: its io_callback
+    effects are rejected under ``jax.checkpoint``.) Read at TRACE time:
+    open it around the first call of the jitted function."""
+    token = _INTERPRET.set(True)
+    try:
+        yield
+    finally:
+        _INTERPRET.reset(token)
+
+
 def attention_auto(q, k, v, causal: bool = True):
-    """flash_attention on TPU; interpret-mode pallas elsewhere (tiny
-    shapes only — tests). Block sizes are sequence-length-tuned,
-    measured on v5e for BOTH directions: at T=2048 (512, 1024) is
+    """flash_attention with sequence-length-tuned block sizes — the
+    model path's entry (``LlamaConfig.use_flash``, Ulysses). The
+    compiled kernel unless the caller opened :func:`interpret_kernels`;
+    never a device probe's choice. Blocks measured on v5e
+    for BOTH directions: at T=2048 (512, 1024) is
     fastest (fwd 11.6 vs 10.7 TF/s for square blocks); at T=8192
     square 1024 blocks win fwd +12% (41.6 vs 37.1) and fwd+bwd +1.5%
     (46.1 vs 45.4), and the full T8192 train step (fwd x2 + bwd under
     remat) improves 13,945 -> 14,365 tok/s — longer rows amortize the
     per-block softmax reduces better."""
-    on_tpu = jax.devices()[0].platform == "tpu"
     t = q.shape[1]
     bq, bk = (1024, 1024) if t >= 4096 else (512, 1024)
     return flash_attention(
         q, k, v, causal=causal, block_q=bq, block_k=bk,
-        interpret=not on_tpu,
+        interpret=_INTERPRET.get(),
     )

@@ -34,12 +34,17 @@ def ulysses_attention(
     mesh: Mesh,
     axis: str = "sp",
     causal: bool = True,
+    use_flash: bool = False,
 ) -> jnp.ndarray:
     """Attention over sequence-sharded [B, S, H, d] q/k/v.
 
     S is the *global* sequence length (each device holds S/sp); H must
     be divisible by the ``axis`` size. Returns output with the same
-    sequence sharding as q.
+    sequence sharding as q. ``use_flash`` (the model's
+    ``LlamaConfig.use_flash``) runs the local full-sequence attention
+    through the pallas kernel and raises on a length it does not
+    support; off, it is the f32 dense oracle. The caller chooses — no
+    device probe does.
     """
     n = mesh.shape[axis]
     if q.shape[2] % n:
@@ -67,18 +72,16 @@ def ulysses_attention(
             )
 
         q, k, v = scatter_heads(q), scatter_heads(k), scatter_heads(v)
-        s_global = q.shape[1]
-        from edl_tpu.ops.flash_attention import attention_auto, flash_supported
+        if use_flash:
+            from edl_tpu.ops.flash_attention import attention_auto
 
-        if jax.devices()[0].platform == "tpu" and flash_supported(s_global):
             # full-sequence attention on the local head shard via the
             # blockwise pallas kernel (GQA-native, O(S) memory) — the
             # whole point of Ulysses: any single-device kernel drops in
             o = attention_auto(q, k, v, causal=causal)
         else:
-            # oracle fallback (tests / unsupported lengths): f32
-            # softmax (the bf16-drift guard ring_attention documents),
-            # O(S^2) scores — fine at test scale only
+            # dense oracle: f32 softmax (the bf16-drift guard
+            # ring_attention documents), O(S^2) scores
             if k.shape[2] != q.shape[2]:  # expand GQA groups
                 k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
                 v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)

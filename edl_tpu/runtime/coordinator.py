@@ -1,4 +1,4 @@
-"""Coordinator bindings — native C++ service + client + Python fallback.
+"""Coordinator bindings — native C++ service + client + Python twin.
 
 The coordination plane replacing the reference's etcd sidecar + Paddle
 master Go binary (reference: pkg/jobparser.go:167-227,
@@ -9,7 +9,8 @@ interface:
   (libedl_coord.so, auto-built from native/coordinator).
 - ``CoordinatorClient(host, port)`` — TCP client to a running
   ``edl-coordinator`` server (multi-host jobs).
-- ``PyCoordinator()``      — pure-Python fallback when no toolchain.
+- ``PyCoordinator()``      — pure-Python twin, chosen by name (tests,
+  toolchain-less hosts); nothing substitutes it for the native one.
 
 Interface: kv_put/kv_get/kv_del · register/heartbeat/leave/expire/
 epoch/members · barrier_arrive/barrier_count · queue_init/lease/ack/
@@ -34,7 +35,7 @@ from edl_tpu.obs import disttrace
 from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.runtime.data import ElasticDataQueue, Task
 from edl_tpu.runtime.lease_table import LeaseTable
-from edl_tpu.utils import faults, tracing
+from edl_tpu.utils import faults, nativebuild, tracing
 from edl_tpu.utils.logging import kv_logger
 
 log = kv_logger("coordinator")
@@ -70,13 +71,10 @@ def _emit_rpc_error(op: str, err: Exception) -> None:
         error=f"{type(err).__name__}: {err}",
     )
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-    "coordinator",
-)
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libedl_coord.so")
-_BIN_PATH = os.path.join(_NATIVE_DIR, "build", "edl-coordinator")
+_NATIVE_DIR = nativebuild.source_dir("coordinator")
+_BUILD_DIR = nativebuild.build_dir("coordinator")
+_LIB_PATH = os.path.join(_BUILD_DIR, "libedl_coord.so")
+_BIN_PATH = os.path.join(_BUILD_DIR, "edl-coordinator")
 
 _build_lock = threading.Lock()
 
@@ -112,13 +110,13 @@ def ensure_native_built() -> bool:
             return True
         try:
             subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
+                ["make", "-C", _NATIVE_DIR, f"BUILD={_BUILD_DIR}"],
                 check=True,
                 capture_output=True,
                 timeout=120,
             )
             return True
-        except Exception as e:  # no g++/make: fall back to PyCoordinator
+        except (OSError, subprocess.SubprocessError) as e:  # no g++/make
             log.warn("native coordinator build failed", error=str(e))
             return False
 
@@ -969,10 +967,3 @@ class PyCoordinator:
     def wal_stats(self):
         return {"appended_bytes": 0, "compactions": 0}
 
-
-def make_coordinator(member_ttl_s: float = 10.0):
-    """Best available in-process coordinator: native, else Python."""
-    try:
-        return NativeCoordinator(member_ttl_s)
-    except RuntimeError:
-        return PyCoordinator(member_ttl_s)

@@ -113,6 +113,13 @@ def _setup_platform(cfg: WorkerConfig) -> None:
         prepare_virtual_cpu(cfg.local_devices)
         # cross-process CPU collectives need gloo
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    else:
+        # the real backend: its compiles are the expensive ones. (The
+        # virtual-CPU platform above is tests and examples — toy
+        # compiles, and XLA:CPU's cache loader warns on every hit.)
+        from edl_tpu.utils import jaxcache
+
+        jaxcache.configure()
 
 
 def _initialize_distributed(
@@ -181,18 +188,10 @@ def _shutdown_distributed() -> None:
 
 
 def _clear_backends() -> None:
-    import jax
+    import jax.extend.backend
 
     jax.clear_caches()
-    try:
-        from jax._src import xla_bridge
-
-        xla_bridge._clear_backends()
-    # edl: no-lint[silent-failure] version probe: the handler body IS the handling (the newer-jax fallback path)
-    except Exception:  # pragma: no cover - jax-version fallback
-        import jax.extend.backend
-
-        jax.extend.backend.clear_backends()
+    jax.extend.backend.clear_backends()
 
 
 # --------------------------------------------------------------------------
@@ -929,6 +928,13 @@ class ElasticWorker:
                 else:
                     val = ""  # slice-blind epoch
                 cl.kv_put(self._k("mesh_slices"), val)
+                # which backend this epoch's mesh really is — what a
+                # launcher that stays off JAX (chip_smoke.py) reads to
+                # know the workers trained on the chip
+                cl.kv_put(
+                    self._k("devices"),
+                    f"{devs[0].platform}|{devs[0].device_kind}|{len(devs)}",
+                )
             rows = cfg.per_device_batch * plan.batch_shards()
             if rows % world:
                 raise ValueError(

@@ -18,16 +18,14 @@ from typing import Dict, List, Optional
 
 from edl_tpu.cluster import topology
 from edl_tpu.cluster.resource import ClusterResource
+from edl_tpu.utils import nativebuild
 from edl_tpu.utils.logging import kv_logger
 
 log = kv_logger("sched.native")
 
-_NATIVE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-    "scheduler",
-)
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libedl_sched.so")
+_NATIVE_DIR = nativebuild.source_dir("scheduler")
+_BUILD_DIR = nativebuild.build_dir("scheduler")
+_LIB_PATH = os.path.join(_BUILD_DIR, "libedl_sched.so")
 
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -61,12 +59,12 @@ def ensure_native_built() -> bool:
             # (a half-linked .so would be dlopen'd by the loser)
             import fcntl
 
-            os.makedirs(os.path.join(_NATIVE_DIR, "build"), exist_ok=True)
-            with open(os.path.join(_NATIVE_DIR, "build", ".lock"), "w") as lk:
+            os.makedirs(_BUILD_DIR, exist_ok=True)
+            with open(os.path.join(_BUILD_DIR, ".lock"), "w") as lk:
                 fcntl.flock(lk, fcntl.LOCK_EX)
                 if not _lib_fresh():
                     subprocess.run(
-                        ["make", "-C", _NATIVE_DIR],
+                        ["make", "-C", _NATIVE_DIR, f"BUILD={_BUILD_DIR}"],
                         check=True,
                         capture_output=True,
                         timeout=120,

@@ -713,7 +713,11 @@ class ContinuousBatchingEngine:
         self.prefill_chunk = int(prefill_chunk)
         self._use_prefix = bool(prefix_cache)
         self._admit_seq = 0
-        self.params = params
+        # resident on the device ONCE: an export loads as host numpy, and
+        # a host array handed to a jitted program is re-uploaded on
+        # every dispatch (the whole weight set per decode block). A
+        # tree already on devices (sharded --mesh load) passes through.
+        self.params = jax.device_put(params)
         self.cfg = cfg
         self.max_slots = max_slots
         self.max_len = max_len

@@ -1,28 +1,41 @@
-"""Persistent-compilation-cache setup shared by every benchmark entry
-point (bench.py, scripts/*.py) — ONE place for the cache policy, so no
-probe silently runs with a cold or mismatched cache (the exact
-cross-run-variance failure the probes exist to rule out).
+"""Persistent-compilation-cache placement — ONE policy for every entry
+point that compiles (``edl serve`` / ``generate`` / ``predict``, the
+fleet replica, ``worker_main``, ``chip_smoke.py``, ``bench.py`` and the
+``scripts/exp_*`` probes).
 
-Call :func:`configure` right after ``import jax`` and before any
-compilation. Per-user path: a fixed /tmp name breaks (and is
-poisonable) on shared hosts.
+The directory is part of the cache key's lookup, so it must not move
+between runs:
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — the operator placed the cache
+  (a volume that outlives the machine); JAX reads the variable itself
+  and this module sets no directory in code.
+* unset — one fixed directory inside the checkout, ``.jax_cache/``
+  (git-ignored). Never a temp-dir, user, pid or time-derived name: a
+  directory that moves never hits.
+
+Call :func:`configure` right after ``import jax`` and before the first
+compilation.
 """
 
 from __future__ import annotations
 
-import getpass
 import os
-import tempfile
+
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+DEFAULT_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def configure(min_compile_time_s: float = 2.0) -> str:
+def configure() -> str:
+    """Turn the persistent compile cache on; returns the directory in
+    use (for logs — cold vs warm compile times only mean something next
+    to the path they were taken against)."""
+    env_dir = os.environ.get(_ENV)
+    if env_dir:
+        return env_dir
     import jax
 
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-        tempfile.gettempdir(), f"edl_jax_cache_{getpass.getuser()}"
-    )
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs", min_compile_time_s
-    )
-    return cache_dir
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+    return DEFAULT_DIR

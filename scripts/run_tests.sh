@@ -160,13 +160,18 @@ echo "== phase 8: hardware-efficiency profile + perf-regression gate =="
 # edl_bw_util_ratio, edl_hbm_bytes{category="kv"} on the memory
 # ledger, edl_compile_seconds recorded, and ZERO obs.recompile events
 # on the steady-state serving loop after warmup. Then the perf gate
-# checks the committed BENCH_r* trajectory is internally regression-
-# free under the per-metric tolerances (the same code CI would use to
-# gate a fresh bench round).
+# CLI loads a BENCH_r* trajectory from disk and checks it is internally
+# regression-free under the per-metric tolerances (the same code CI
+# would use to gate a fresh bench round). The trajectory is synthetic,
+# written to a temp dir: the chip rounds once committed here were taken
+# on an earlier installation and are deleted.
 rc8=0
 JAX_PLATFORMS=cpu python -m edl_tpu.cli profile --dryrun --metrics-port 0 \
     || rc8=1
-python scripts/perf_gate.py || rc8=1
+PGDIR=$(mktemp -d)
+python -c "import sys; from tests.test_perf_gate import write_synthetic_trajectory as w; w(sys.argv[1])" "$PGDIR" \
+    && python scripts/perf_gate.py --dir "$PGDIR" || rc8=1
+rm -rf "$PGDIR"
 t8=$(date +%s)
 echo "== phase 8 done in $((t8 - t7))s (rc=$rc8) =="
 

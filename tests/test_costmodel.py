@@ -82,12 +82,33 @@ def test_peak_table_matches_bench_values():
         ("TPU v5", 459e12, 2765e9),
         ("TPU v4", 275e12, 1228e9),
         ("TPU v6e", 918e12, 1640e9),
-        ("weird-backend", 197e12, 819e9),  # conservative default
     ):
         assert bench._peak_flops(D(kind)) == fl, kind
         assert bench._peak_hbm_bw(D(kind)) == bw, kind
         assert cm.peak_for_kind(kind).flops == fl
         assert cm.peak_for_kind(kind).hbm_bytes_s == bw
+    # a device the table does not know is an error, never a default
+    for call in (bench._peak_flops, bench._peak_hbm_bw, cm.peak_for_device):
+        with pytest.raises(KeyError, match="weird-backend"):
+            call(D("weird-backend"))
+
+
+def test_detect_peak_unknown_accelerator_raises_cpu_is_nominal(monkeypatch):
+    class D:
+        def __init__(self, platform, kind):
+            self.platform, self.device_kind = platform, kind
+
+    monkeypatch.delenv("EDL_PEAK_TFLOPS", raising=False)
+    monkeypatch.delenv("EDL_PEAK_HBM_GBS", raising=False)
+    assert cm.detect_peak(D("cpu", "cpu")).kind == "host-nominal"
+    assert cm.detect_peak(D("tpu", "TPU v5 lite")).flops == 197e12
+    with pytest.raises(KeyError):
+        cm.detect_peak(D("tpu", "TPU v9 mystery"))
+    # the escape hatch names BOTH rates for a kind the table predates
+    monkeypatch.setenv("EDL_PEAK_TFLOPS", "100")
+    monkeypatch.setenv("EDL_PEAK_HBM_GBS", "500")
+    p = cm.detect_peak(D("tpu", "TPU v9 mystery"))
+    assert (p.flops, p.hbm_bytes_s) == (100e12, 500e9)
 
 
 def test_detect_peak_env_override(monkeypatch):

@@ -266,16 +266,36 @@ def test_remat_attn_policy_runs_with_flash():
     )
     from edl_tpu.ops.flash_attention import flash_supported
 
+    from edl_tpu.ops.flash_attention import interpret_kernels
+
     t = 128
     assert flash_supported(t)
-    l_attn, g = _remat_loss_and_grads(cfg, t=t)
-    ref = dataclasses.replace(base, use_flash=True)
-    l_ref, _ = _remat_loss_and_grads(ref, t=t)
+    # the model path never picks the interpreter itself: the test asks
+    with interpret_kernels():
+        l_attn, g = _remat_loss_and_grads(cfg, t=t)
+        ref = dataclasses.replace(base, use_flash=True)
+        l_ref, _ = _remat_loss_and_grads(ref, t=t)
     np.testing.assert_allclose(l_attn, l_ref, rtol=1e-4)
     assert all(
         np.isfinite(np.asarray(x)).all()
         for x in jax.tree_util.tree_leaves(g)
     )
+
+
+def test_use_flash_never_degrades_silently():
+    """use_flash=True either runs the kernel or raises: an unsupported
+    length is a config error (no dense substitute), and on a CPU with
+    no interpreter requested the kernel itself refuses."""
+    import dataclasses
+
+    import pytest
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), use_flash=True)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    with pytest.raises(ValueError, match="not flash-supported"):
+        llama.forward(params, jnp.zeros((1, 520), jnp.int32), cfg)
+    with pytest.raises(ValueError, match="interpret mode"):
+        llama.forward(params, jnp.zeros((1, 128), jnp.int32), cfg)
 
 
 def test_remat_attn_policy_guards():

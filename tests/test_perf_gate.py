@@ -1,8 +1,9 @@
 """Perf-regression gate (scripts/perf_gate.py): synthetic improving /
 regressing / noisy trajectories, the empty-trajectory bootstrap,
-sentinel and config-mismatch skipping, and the committed BENCH_r*
-trajectory itself (the CI phase-8 invocation, run in-process).
-jax-free."""
+sentinel and config-mismatch skipping, and an on-disk BENCH_r*
+trajectory loaded the way the CI phase-8 invocation loads one (a
+synthetic one in tmp_path: the rounds once committed here came from an
+earlier installation and are deleted). jax-free."""
 
 import json
 import os
@@ -86,21 +87,51 @@ def test_config_mismatch_is_incomparable():
     traj = [r(tps=100000, config="old", rnd="r1")]
     rep = gate(traj, r(tps=100, config="new"), metrics=SPECS)
     assert verdict(rep, "tps").status == "bootstrap"
-    # the real shape: BENCH_r01's llama figure predates llama_config
+    # the real shape: a first round that predates llama_config
     traj2 = [{"_round": "r1", "tps": 100000}]  # no config key at all
     rep2 = gate(traj2, r(tps=100, config="new"), metrics=SPECS)
     assert verdict(rep2, "tps").status == "bootstrap"
 
 
-def test_committed_trajectory_passes_and_synthetic_regression_fails():
-    rounds = load_rounds(REPO)
-    assert len(rounds) >= 5, "committed BENCH_r*.json rounds missing"
+def write_synthetic_trajectory(dirpath, n_rounds=5):
+    """Write ``BENCH_r01..rNN.json`` in the on-disk shape the gate loads
+    (``{"parsed": {...}}``): a slowly improving trajectory of made-up
+    values — round k is 1% better than round k-1 on every metric — with
+    the first round predating ``llama_config``, like the first recorded
+    round did. No number here was measured anywhere. Also what
+    scripts/run_tests.sh phase 8 feeds the CLI."""
+    base = {
+        "value": 1000.0, "llama_tokens_per_sec_per_chip": 100.0,
+        "mfu": 0.5, "long_mfu": 0.4, "decode_tokens_per_sec": 200.0,
+        "decode_pct_peak_bw": 0.8, "prefill_s": 0.2,
+        "reshard_stall_s": 0.1, "reshard_stall_host_fallback_s": 5.0,
+        "p2p_bw_gbs": 1.0, "host_stage_bw_gbs": 0.5,
+    }
+    for k in range(1, n_rounds + 1):
+        doc = {
+            name: v * (1.01 ** k if METRICS[name].direction > 0
+                       else 0.99 ** k)
+            for name, v in base.items()
+        }
+        doc["decode_config"] = "synthetic-decode"
+        if k > 1:
+            doc["llama_config"] = "synthetic-llama"
+        path = os.path.join(str(dirpath), f"BENCH_r{k:02d}.json")
+        with open(path, "w") as f:
+            json.dump({"parsed": doc}, f)
+
+
+def test_loaded_trajectory_passes_and_synthetic_regression_fails(tmp_path):
+    write_synthetic_trajectory(tmp_path)
+    rounds = load_rounds(str(tmp_path))
+    assert len(rounds) >= 5, "synthetic BENCH_r*.json rounds missing"
+    assert [d["_round"] for d in rounds] == [f"r{k:02d}" for k in range(1, 6)]
     cand, traj = rounds[-1], rounds[:-1]
     rep = gate(traj, cand)
     assert rep.ok, [v.detail for v in rep.failed]
     # the gate is not vacuous: >= 8 real comparisons happened
     assert sum(1 for v in rep.verdicts if v.status == "pass") >= 8
-    # a synthetically-regressed r05 (MFU -30%, CTR -30%) must FAIL
+    # a synthetically-regressed last round (MFU -30%, CTR -30%) must FAIL
     bad = dict(cand)
     bad["mfu"] = cand["mfu"] * 0.7
     bad["value"] = cand["value"] * 0.7
@@ -109,16 +140,22 @@ def test_committed_trajectory_passes_and_synthetic_regression_fails():
 
 
 def test_cli_main_json_and_exit_codes(tmp_path, capsys):
-    assert main(["--json"]) == 0
+    write_synthetic_trajectory(tmp_path)
+    assert main(["--dir", str(tmp_path), "--json"]) == 0
     out = json.loads(capsys.readouterr().out)
     assert out["ok"] is True
     # a regressed candidate file fails with exit 1
-    rounds = load_rounds(REPO)
+    rounds = load_rounds(str(tmp_path))
     bad = dict(rounds[-1])
     bad["mfu"] = bad["mfu"] * 0.5
-    p = tmp_path / "BENCH_bad.json"
+    p = tmp_path / "cand_bad.json"
     p.write_text(json.dumps({"parsed": bad}))
-    assert main(["--candidate", str(p)]) == 1
+    assert main(["--dir", str(tmp_path), "--candidate", str(p)]) == 1
+    # an empty directory bootstraps (what the repo root is until the
+    # benchmark records rounds on today's machine)
+    empty = tmp_path / "empty"
+    empty.mkdir()
+    assert main(["--dir", str(empty)]) == 0
 
 
 def test_gated_catalog_covers_the_headline_metrics():
