@@ -149,12 +149,17 @@ def start_jax(args):
 
 
 def compile_seconds(program: str) -> float:
-    """Seconds the process spent in first calls (trace + compile) of one
-    program family, from the repo's own compile watch."""
+    """Seconds the process spent building (trace, lower, compile or
+    cache load) the programs whose name starts with ``program``, from
+    the repo's own compile watch."""
     from edl_tpu.obs import metrics as obs_metrics
 
     fam = obs_metrics.default_registry().get("edl_compile_seconds")
-    return float(fam.stats(program=program)["sum"]) if fam else 0.0
+    if not fam:
+        return 0.0
+    at = fam.labelnames.index("program")
+    return float(sum(s.sum for key, s in fam.samples()
+                     if key[at].startswith(program)))
 
 
 def device_memory_line() -> str:
@@ -210,7 +215,7 @@ def compiled_step_text(trainer, batch) -> str:
     compiler made of it."""
     from edl_tpu.train.trainer import global_batch
 
-    jitted = trainer._step_fn.program[0].__wrapped__
+    jitted = trainer._step_fn.program[0]
     dev_batch = global_batch(batch, trainer.plan, trainer.mesh)
     return jitted.lower(trainer.state, dev_batch).compile().as_text()
 
@@ -296,7 +301,7 @@ def phase_train(args, sz: Sizes) -> dict:
     a.start(llama.init_params(key, cfg), n_workers=1)
     with kernels(args):
         first_loss, cold_s = one_step(a, batches[0])
-    compile_cold = compile_seconds("train.step")
+    compile_cold = compile_seconds("edl_train_step")
     print(f"  step 0: loss={first_loss:.6f} seconds={cold_s:.2f} "
           f"(first call: trace+compile {compile_cold:.2f}s)", flush=True)
     if args.rehearse:
@@ -338,7 +343,7 @@ def phase_train(args, sz: Sizes) -> dict:
           flush=True)
     with kernels(args):
         loss_b, warm_s = one_step(b, batches[1])
-    compile_warm = compile_seconds("train.step") - compile_cold
+    compile_warm = compile_seconds("edl_train_step") - compile_cold
     print(f"  resumed step: loss={loss_b:.6f} seconds={warm_s:.2f} "
           f"(the same program's first call from a new trainer: trace+compile "
           f"{compile_warm:.2f}s, served by the compile cache)", flush=True)
@@ -425,7 +430,7 @@ def phase_serve(args, sz: Sizes, horizon: int) -> dict:
     recoveries = reg.get("edl_serving_recoveries_total").value()
     check(recoveries == 0, "recoveries == 0 (no dispatch was refused)")
     tokens = sz.n_requests * sz.max_new
-    c_pre, c_blk = compile_seconds("serve.prefill"), compile_seconds("serve.block")
+    c_pre, c_blk = compile_seconds("edl_serve_prefill"), compile_seconds("edl_serve_block")
     print(f"  {tokens} tokens in {serve_s:.2f}s incl. load+compile; "
           f"first calls: prefill {c_pre:.2f}s, decode block {c_blk:.2f}s; "
           f"ttft_s per request: "
@@ -444,7 +449,7 @@ def phase_serve(args, sz: Sizes, horizon: int) -> dict:
                 )
                 ref[r["id"]] = [int(t) for t in np.asarray(toks)[0]]
         print(f"  llama.generate reference: {time.perf_counter() - t0:.1f}s "
-              f"(first calls {compile_seconds('llama.generate'):.2f}s)",
+              f"(first calls {compile_seconds('edl_llama_generate'):.2f}s)",
               flush=True)
         with open(ref_path, "w") as f:
             json.dump(ref, f)
@@ -586,7 +591,7 @@ def phase_elastic4(args, sz: Sizes) -> dict:
     with kernels(args):
         loss_four, s = one_step(tr, batch16)
     print(f"  {n} devices: first-step loss={loss_four:.6f} ({s:.1f}s, "
-          f"trace+compile {compile_seconds('train.step'):.1f}s total so far)",
+          f"trace+compile {compile_seconds('edl_train_step'):.1f}s total so far)",
           flush=True)
     tol = 2.0 ** -8 * abs(loss_one)  # one bf16 ulp: another reduction order
     check(abs(loss_four - loss_one) <= tol,

@@ -138,7 +138,7 @@ class RecompileHazardRule(Rule):
                     for t in n.targets
                 ):
                     # the jit itself, or a jit nested in a decorator-
-                    # style wrapper call (compilewatch.wrap(jax.jit(f),
+                    # style wrapper call (wrap(jax.jit(f),
                     # ...)) — still the build-once builder shape
                     for sub in ast.walk(n.value):
                         if isinstance(sub, ast.Call) and is_jit_call(sub):

@@ -29,6 +29,8 @@ from edl_tpu.obs import compilewatch
 from edl_tpu.obs import costmodel as _costmodel
 from edl_tpu.parallel.mesh import MeshPlan
 
+compilewatch.install()  # compile telemetry for every program built here
+
 
 @dataclass(frozen=True)
 class LlamaConfig:
@@ -422,6 +424,7 @@ def _qkv(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions=None):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _mlp(cfg: LlamaConfig, x: jnp.ndarray, lp: Dict) -> jnp.ndarray:
     """Post-attention SwiGLU block (residual included) — shared by the
     training layer and the decode step."""
@@ -445,10 +448,11 @@ def _layer(
     training path must NOT set it (materializing every layer's K/V
     across the scan costs O(L·B·T) HBM)."""
     b, t, d = x.shape
-    a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(cfg, a, lp)
-    o = attention(q, k, v, cfg, mesh=mesh, sp=sp).reshape(b, t, -1)
-    x = x + _matw(o, lp["wo"], cfg.int8_mxu, cfg.int8_wgrad_bf16)
+    with jax.named_scope("attn"):
+        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(cfg, a, lp)
+        o = attention(q, k, v, cfg, mesh=mesh, sp=sp).reshape(b, t, -1)
+        x = x + _matw(o, lp["wo"], cfg.int8_mxu, cfg.int8_wgrad_bf16)
     out = _mlp(cfg, x, lp)
     return (out, k, v) if with_kv else out
 
@@ -514,7 +518,8 @@ def forward(
             'remat_policy="attn" requires the flash kernel, which the '
             "sp (ring/Ulysses) attention paths do not use"
         )
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     if sp > 1:
         if tokens.shape[1] % sp:
             raise ValueError(
@@ -566,8 +571,9 @@ def forward(
         x = xm.reshape((b,) + xm.shape[2:])
     else:
         x, _ = jax.lax.scan(body, x, params["layers"])
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return _matw(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        return _matw(x, params["lm_head"]).astype(jnp.float32)
 
 
 # -- inference: KV-cache decode ---------------------------------------------
@@ -584,15 +590,17 @@ def _prefill(params: Dict, tokens: jnp.ndarray, cfg: LlamaConfig):
     """Forward over the prompt, returning (logits_last [B, V],
     k_cache, v_cache [L, B, T, KV, hd]). Runs the SAME ``_layer`` as
     training (``with_kv=True`` collects the cache)."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
 
     def body(carry, lp):
         y, k, v = _layer(cfg, carry, lp, with_kv=True)
         return y, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x[:, -1], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x[:, -1], params["lm_head"]).astype(jnp.float32)
     return logits, ks, vs
 
 
@@ -619,31 +627,34 @@ def _decode_step(params: Dict, tok: jnp.ndarray, pos, kc, vc, cfg: LlamaConfig):
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     groups = h // kv
     s = kc.shape[2]
-    x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
     positions = jnp.full((1,), pos)
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        # same projections/RoPE as training (_qkv); only the
-        # cache-update + masked-dense attention differ by construction
-        q, knew, vnew = _qkv(cfg, a, lp, positions)
-        kc = jax.lax.dynamic_update_slice(kc, knew[None], (i, 0, pos, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, vnew[None], (i, 0, pos, 0, 0))
-        kci, vci = kc[i], vc[i]  # static-index slices of the carry
-        # GQA-native: group the query heads against the un-repeated
-        # cache — no groups-fold bandwidth multiplier on the
-        # token-latency-critical path
-        qg = q.reshape(b, 1, kv, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        mask = (jnp.arange(s) <= pos)[None, None, None, None, :]
-        scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            # same projections/RoPE as training (_qkv); only the
+            # cache-update + masked-dense attention differ by construction
+            q, knew, vnew = _qkv(cfg, a, lp, positions)
+            kc = jax.lax.dynamic_update_slice(kc, knew[None], (i, 0, pos, 0, 0))
+            vc = jax.lax.dynamic_update_slice(vc, vnew[None], (i, 0, pos, 0, 0))
+            kci, vci = kc[i], vc[i]  # static-index slices of the carry
+            # GQA-native: group the query heads against the un-repeated
+            # cache — no groups-fold bandwidth multiplier on the
+            # token-latency-critical path
+            qg = q.reshape(b, 1, kv, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            mask = (jnp.arange(s) <= pos)[None, None, None, None, :]
+            scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
     return logits, kc, vc
 
 
@@ -661,16 +672,18 @@ def prefill_padded(params: Dict, tokens: jnp.ndarray, last, cfg: LlamaConfig):
     prompt length. ``last`` is a traced scalar or [B] vector, so every
     length inside a bucket reuses one program."""
     b = tokens.shape[0]
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
 
     def body(carry, lp):
         y, k, v = _layer(cfg, carry, lp, with_kv=True)
         return y, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    xl = x[jnp.arange(b), last]  # [B, d] — each row's last real token
-    logits = _matw(xl, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        xl = x[jnp.arange(b), last]  # [B, d] — each row's last real token
+        logits = _matw(xl, params["lm_head"]).astype(jnp.float32)
     return logits, ks, vs
 
 
@@ -703,25 +716,28 @@ def decode_step_slots(
     groups = h // kvh
     s = kc.shape[2]
     rows = jnp.arange(b)
-    x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
-        kc = kc.at[i, rows, pos].set(knew[:, 0])
-        vc = vc.at[i, rows, pos].set(vnew[:, 0])
-        kci, vci = kc[i], vc[i]  # static-index slices of the carry
-        qg = q.reshape(b, 1, kvh, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
-        scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
+            kc = kc.at[i, rows, pos].set(knew[:, 0])
+            vc = vc.at[i, rows, pos].set(vnew[:, 0])
+            kci, vci = kc[i], vc[i]  # static-index slices of the carry
+            qg = q.reshape(b, 1, kvh, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
+            scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
     return logits, kc, vc
 
 
@@ -775,10 +791,13 @@ def decode_horizon_slots(
     def step(carry, k):
         tok, pos, active, rem, kc, vc = carry
         logits, kc, vc = decode_step_slots(params, tok, pos, kc, vc, cfg)
-        if sampling:
-            nxt = jax.random.categorical(k, logits / temperature, axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
+        with jax.named_scope("head"):
+            if sampling:
+                nxt = jax.random.categorical(
+                    k, logits / temperature, axis=-1
+                )
+            else:
+                nxt = jnp.argmax(logits, axis=-1)
         nxt = jnp.where(active, nxt.astype(jnp.int32), tok)
         out = jnp.where(active, nxt, -1)
         pos = jnp.where(active, pos + 1, pos)
@@ -1019,51 +1038,54 @@ def decode_step_slots_paged(
         inb, table[rows, jnp.clip(pos // bs, 0, m - 1)], 0
     )  # [B] physical block per row
     off = jnp.where(inb, pos % bs, 0)
-    x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
-        if quant:
-            kc, ks = _kvq_store(kc, ks, i, blk, off, knew[:, 0], kv_quant)
-            vc, vs = _kvq_store(vc, vs, i, blk, off, vnew[:, 0], kv_quant)
-            kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
-                b, s, kvh, hd
-            ).astype(dt)
-            vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
-                b, s, kvh, hd
-            ).astype(dt)
-            ksc = _kvq_scale_strip(ks[i], table, bs)  # [B, KV, 1, 1, S]
-            vsc = _kvq_scale_strip(vs[i], table, bs)
-        else:
-            kc = kc.at[i, blk, off].set(knew[:, 0])
-            vc = vc.at[i, blk, off].set(vnew[:, 0])
-            # table gather: [n_blocks, bs, KV, hd][table] -> the row's
-            # logical [B, M, bs, KV, hd] view, flat to [B, S, KV, hd]
-            kci = kc[i][table].reshape(b, s, kvh, hd)
-            vci = vc[i][table].reshape(b, s, kvh, hd)
-        qg = q.reshape(b, 1, kvh, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        if quant:
-            # the K-side dequant: per-(block, kv-head) scale lands on
-            # the f32 scores (constant along the contracted hd axis),
-            # never on a dequantized [S, KV, hd] temp — _matw's
-            # discipline, the f32 multiply included
-            scores = scores.astype(jnp.float32) * ksc
-        mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
-        scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        if quant:
-            # the V-side dequant folds into the probs (scale varies
-            # along the contracted s axis but indexes like the probs)
-            probs = probs * vsc
-        probs = probs.astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
+            if quant:
+                kc, ks = _kvq_store(kc, ks, i, blk, off, knew[:, 0], kv_quant)
+                vc, vs = _kvq_store(vc, vs, i, blk, off, vnew[:, 0], kv_quant)
+                kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
+                    b, s, kvh, hd
+                ).astype(dt)
+                vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
+                    b, s, kvh, hd
+                ).astype(dt)
+                ksc = _kvq_scale_strip(ks[i], table, bs)  # [B, KV, 1, 1, S]
+                vsc = _kvq_scale_strip(vs[i], table, bs)
+            else:
+                kc = kc.at[i, blk, off].set(knew[:, 0])
+                vc = vc.at[i, blk, off].set(vnew[:, 0])
+                # table gather: [n_blocks, bs, KV, hd][table] -> the row's
+                # logical [B, M, bs, KV, hd] view, flat to [B, S, KV, hd]
+                kci = kc[i][table].reshape(b, s, kvh, hd)
+                vci = vc[i][table].reshape(b, s, kvh, hd)
+            qg = q.reshape(b, 1, kvh, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            if quant:
+                # the K-side dequant: per-(block, kv-head) scale lands on
+                # the f32 scores (constant along the contracted hd axis),
+                # never on a dequantized [S, KV, hd] temp — _matw's
+                # discipline, the f32 multiply included
+                scores = scores.astype(jnp.float32) * ksc
+            mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
+            scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            if quant:
+                # the V-side dequant folds into the probs (scale varies
+                # along the contracted s axis but indexes like the probs)
+                probs = probs * vsc
+            probs = probs.astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
     if quant:
         return logits, kc, vc, ks, vs
     return logits, kc, vc
@@ -1113,10 +1135,13 @@ def decode_horizon_slots_paged(
             logits, kc, vc = decode_step_slots_paged(
                 params, tok, pos, table, kc, vc, cfg, block_size
             )
-        if sampling:
-            nxt = jax.random.categorical(k, logits / temperature, axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
+        with jax.named_scope("head"):
+            if sampling:
+                nxt = jax.random.categorical(
+                    k, logits / temperature, axis=-1
+                )
+            else:
+                nxt = jnp.argmax(logits, axis=-1)
         nxt = jnp.where(active, nxt.astype(jnp.int32), tok)
         out = jnp.where(active, nxt, -1)
         pos = jnp.where(active, pos + 1, pos)
@@ -1197,46 +1222,49 @@ def prefill_paged(
     )
     woff = jnp.where(real, positions % bs, 0)
     quant = kv_quant != "off"
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     qmask = (jnp.arange(s)[None, :] <= positions[:, None])[
         None, None, None, :, :
     ]  # [1,1,1,Tb,S]: query t sees pool positions <= start + t
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, knew, vnew = _qkv(cfg, a, lp, positions)
-        if quant:
-            kc, ks = _kvq_store(kc, ks, i, wblk, woff, knew[0], kv_quant)
-            vc, vs = _kvq_store(vc, vs, i, wblk, woff, vnew[0], kv_quant)
-            kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
-                1, s, kvh, hd
-            ).astype(dt)
-            vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
-                1, s, kvh, hd
-            ).astype(dt)
-            ksc = _kvq_scale_strip(ks[i], table, bs)
-            vsc = _kvq_scale_strip(vs[i], table, bs)
-        else:
-            kc = kc.at[i, wblk, woff].set(knew[0])
-            vc = vc.at[i, wblk, woff].set(vnew[0])
-            kci = kc[i][table].reshape(1, s, kvh, hd)
-            vci = vc[i][table].reshape(1, s, kvh, hd)
-        qg = q.reshape(b, tb, kvh, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        if quant:
-            scores = scores.astype(jnp.float32) * ksc
-        scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        if quant:
-            probs = probs * vsc
-        probs = probs.astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, tb, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, knew, vnew = _qkv(cfg, a, lp, positions)
+            if quant:
+                kc, ks = _kvq_store(kc, ks, i, wblk, woff, knew[0], kv_quant)
+                vc, vs = _kvq_store(vc, vs, i, wblk, woff, vnew[0], kv_quant)
+                kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
+                    1, s, kvh, hd
+                ).astype(dt)
+                vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
+                    1, s, kvh, hd
+                ).astype(dt)
+                ksc = _kvq_scale_strip(ks[i], table, bs)
+                vsc = _kvq_scale_strip(vs[i], table, bs)
+            else:
+                kc = kc.at[i, wblk, woff].set(knew[0])
+                vc = vc.at[i, wblk, woff].set(vnew[0])
+                kci = kc[i][table].reshape(1, s, kvh, hd)
+                vci = vc[i][table].reshape(1, s, kvh, hd)
+            qg = q.reshape(b, tb, kvh, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            if quant:
+                scores = scores.astype(jnp.float32) * ksc
+            scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            if quant:
+                probs = probs * vsc
+            probs = probs.astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, tb, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    xl = x[jnp.arange(b), last]  # [1, d] — the chunk's last real token
-    logits = _matw(xl, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        xl = x[jnp.arange(b), last]  # [1, d] — the chunk's last real token
+        logits = _matw(xl, params["lm_head"]).astype(jnp.float32)
     if quant:
         return logits, kc, vc, ks, vs
     return logits, kc, vc
@@ -1322,7 +1350,8 @@ def verify_step_slots(
     # (argmax >= 0 never equals -1), so the embedded value is dead
     toks = jnp.concatenate([tok[:, None], jnp.maximum(draft, 0)], axis=1)
     qpos = pos[:, None] + jnp.arange(k)[None, :]  # [B, K] absolute
-    x = jnp.take(params["embed"], toks, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], toks, axis=0).astype(cfg.dtype)
     # lane j sees cache positions <= pos+j — its own write included,
     # garbage beyond masked exactly like the decode step's tail
     qmask = (jnp.arange(s)[None, None, :] <= qpos[:, :, None])[
@@ -1331,24 +1360,26 @@ def verify_step_slots(
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, knew, vnew = _qkv(cfg, a, lp, qpos)
-        # per-row K-lane scatter; rows[:, None] broadcasts against the
-        # [B, K] positions. Writes past S drop (frozen rows parked at
-        # the cache end), never clamp — a clamp would alias S-1.
-        kc = kc.at[i, rows[:, None], qpos].set(knew, mode="drop")
-        vc = vc.at[i, rows[:, None], qpos].set(vnew, mode="drop")
-        kci, vci = kc[i], vc[i]
-        qg = q.reshape(b, k, kvh, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, k, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, knew, vnew = _qkv(cfg, a, lp, qpos)
+            # per-row K-lane scatter; rows[:, None] broadcasts against the
+            # [B, K] positions. Writes past S drop (frozen rows parked at
+            # the cache end), never clamp — a clamp would alias S-1.
+            kc = kc.at[i, rows[:, None], qpos].set(knew, mode="drop")
+            vc = vc.at[i, rows[:, None], qpos].set(vnew, mode="drop")
+            kci, vci = kc[i], vc[i]
+            qg = q.reshape(b, k, kvh, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, k, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x, params["lm_head"]).astype(jnp.float32)  # [B, K, V]
-    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K]
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x, params["lm_head"]).astype(jnp.float32)  # [B, K, V]
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, K]
     return _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc)
 
 
@@ -1444,52 +1475,55 @@ def verify_step_slots_paged(
     )
     woff = jnp.where(inb, qpos % bs, 0)
     quant = kv_quant != "off"
-    x = jnp.take(params["embed"], toks, axis=0).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], toks, axis=0).astype(cfg.dtype)
     qmask = (jnp.arange(s)[None, None, :] <= qpos[:, :, None])[
         :, None, None, :, :
     ]
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         dt = x.dtype
-        a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, knew, vnew = _qkv(cfg, a, lp, qpos)
-        if quant:
-            kc, ks = _kvq_store(
-                kc, ks, i, wblk.reshape(-1), woff.reshape(-1),
-                knew.reshape(b * k, kvh, hd), kv_quant,
-            )
-            vc, vs = _kvq_store(
-                vc, vs, i, wblk.reshape(-1), woff.reshape(-1),
-                vnew.reshape(b * k, kvh, hd), kv_quant,
-            )
-            kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
-                b, s, kvh, hd
-            ).astype(dt)
-            vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
-                b, s, kvh, hd
-            ).astype(dt)
-            ksc = _kvq_scale_strip(ks[i], table, bs)
-            vsc = _kvq_scale_strip(vs[i], table, bs)
-        else:
-            kc = kc.at[i, wblk, woff].set(knew)
-            vc = vc.at[i, wblk, woff].set(vnew)
-            kci = kc[i][table].reshape(b, s, kvh, hd)
-            vci = vc[i][table].reshape(b, s, kvh, hd)
-        qg = q.reshape(b, k, kvh, groups, hd)
-        scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-        if quant:
-            scores = scores.astype(jnp.float32) * ksc
-        scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        if quant:
-            probs = probs * vsc
-        probs = probs.astype(dt)
-        o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, k, h * hd)
-        x = x + _matw(o, lp["wo"])
+        with jax.named_scope("attn"):
+            a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, knew, vnew = _qkv(cfg, a, lp, qpos)
+            if quant:
+                kc, ks = _kvq_store(
+                    kc, ks, i, wblk.reshape(-1), woff.reshape(-1),
+                    knew.reshape(b * k, kvh, hd), kv_quant,
+                )
+                vc, vs = _kvq_store(
+                    vc, vs, i, wblk.reshape(-1), woff.reshape(-1),
+                    vnew.reshape(b * k, kvh, hd), kv_quant,
+                )
+                kci = _kvq_unpack(kc[i][table], kv_quant).reshape(
+                    b, s, kvh, hd
+                ).astype(dt)
+                vci = _kvq_unpack(vc[i][table], kv_quant).reshape(
+                    b, s, kvh, hd
+                ).astype(dt)
+                ksc = _kvq_scale_strip(ks[i], table, bs)
+                vsc = _kvq_scale_strip(vs[i], table, bs)
+            else:
+                kc = kc.at[i, wblk, woff].set(knew)
+                vc = vc.at[i, wblk, woff].set(vnew)
+                kci = kc[i][table].reshape(b, s, kvh, hd)
+                vci = vc[i][table].reshape(b, s, kvh, hd)
+            qg = q.reshape(b, k, kvh, groups, hd)
+            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
+            if quant:
+                scores = scores.astype(jnp.float32) * ksc
+            scores = jnp.where(qmask, scores, jnp.finfo(scores.dtype).min)
+            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+            if quant:
+                probs = probs * vsc
+            probs = probs.astype(dt)
+            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, k, h * hd)
+            x = x + _matw(o, lp["wo"])
         x = _mlp(cfg, x, lp)
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _matw(x, params["lm_head"]).astype(jnp.float32)
-    out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _matw(x, params["lm_head"]).astype(jnp.float32)
+        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     acc = _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc)
     if quant:
         return acc + (ks, vs)
@@ -1583,7 +1617,7 @@ def _generate_program(cfg: LlamaConfig, b: int, t0: int, max_new: int,
     max_len = t0 + max_new
 
     @jax.jit
-    def run(params, tokens, key, temperature, top_p):
+    def edl_llama_generate(params, tokens, key, temperature, top_p):
         logits, ks, vs = _prefill(params, tokens, cfg)
         pad = jnp.zeros((L, b, max_len - t0, kvh, hd), ks.dtype)
         kc = jnp.concatenate([ks, pad], axis=2)
@@ -1613,7 +1647,8 @@ def _generate_program(cfg: LlamaConfig, b: int, t0: int, max_new: int,
         def step(carry, i):
             logits, kc, vc, k = carry
             k, sub = jax.random.split(k)
-            tok = sample(logits, sub).astype(jnp.int32)
+            with jax.named_scope("head"):
+                tok = sample(logits, sub).astype(jnp.int32)
             logits, kc, vc = _decode_step(params, tok, t0 + i, kc, vc, cfg)
             return (logits, kc, vc, k), tok
 
@@ -1622,11 +1657,10 @@ def _generate_program(cfg: LlamaConfig, b: int, t0: int, max_new: int,
         )
         return jnp.swapaxes(toks, 0, 1)  # [B, max_new]
 
-    # compile watch: each cache key is one distinct program — its first
-    # call is timed into edl_compile_seconds{program="llama.generate"}
-    # and flagged as obs.recompile once the process declared warmup over
-    run = compilewatch.wrap(run, "llama.generate")
-
+    # the function's name is the program's: compile telemetry
+    # (edl_compile_seconds{program="edl_llama_generate"}) and the
+    # profiler's XLA Modules line both read it
+    run = edl_llama_generate
     while len(_generate_programs) >= _GENERATE_PROGRAM_CAP:
         _generate_programs.popitem(last=False)  # evict least-recent
     _generate_programs[cache_key] = run
@@ -1672,10 +1706,13 @@ def make_loss_fn(cfg: LlamaConfig, plan: Optional[MeshPlan] = None, mesh=None):
             )
         from edl_tpu.models.losses import row_mean
 
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-        # per-row mean over T, then the runtime's real-row weighting
-        # (identical to the global mean when no "_w" rides the batch)
-        return row_mean(jnp.mean(ce, axis=-1), batch)
+        with jax.named_scope("loss"):
+            ce = optax.softmax_cross_entropy_with_integer_labels(
+                logits, targets
+            )
+            # per-row mean over T, then the runtime's real-row weighting
+            # (identical to the global mean when no "_w" rides the batch)
+            return row_mean(jnp.mean(ce, axis=-1), batch)
 
     return loss_fn
 

@@ -565,12 +565,13 @@ def ensure_core_series(reg: Optional[MetricsRegistry] = None) -> MetricsRegistry
     )
     r.histogram(
         "edl_compile_seconds",
-        "first-call (trace + compile) time per distinct jit program",
-        ("program",),
+        "time to build one jit program, by stage (trace, lower, "
+        "backend compile or persistent-cache load)",
+        ("program", "stage"),
     )
     r.counter(
         "edl_compiles_total",
-        "distinct jit programs compiled, by factory",
+        "distinct jit programs built, by the jitted function's name",
         ("program",),
     )
     # tracing bridge (obs/fleet.py bridge_tracer)

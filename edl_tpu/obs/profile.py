@@ -44,11 +44,13 @@ _Fams = Dict[str, List[Tuple[Dict[str, str], float]]]
 
 
 def _by_label(fams: _Fams, name: str, label: str) -> Dict[str, float]:
-    return {
-        labels[label]: v
-        for labels, v in fams.get(name, ())
-        if labels.get(label)
-    }
+    """Series of ``name`` summed per value of ``label`` (a family may
+    carry further labels: edl_compile_seconds has ``stage``)."""
+    out: Dict[str, float] = {}
+    for labels, v in fams.get(name, ()):
+        if labels.get(label):
+            out[labels[label]] = out.get(labels[label], 0.0) + v
+    return out
 
 
 def report_from_fams(fams: _Fams, source: str = "") -> dict:
@@ -64,7 +66,7 @@ def report_from_fams(fams: _Fams, source: str = "") -> dict:
         if v
     }
     compiles: Dict[str, dict] = {}
-    for pg, n in _by_label(fams, "edl_compile_seconds_count", "program").items():
+    for pg, n in _by_label(fams, "edl_compiles_total", "program").items():
         if n:
             compiles[pg] = {"count": n}
     for pg, s in _by_label(fams, "edl_compile_seconds_sum", "program").items():

@@ -227,6 +227,7 @@ def _fwd_call(
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="edl_flash_fwd",
     )(qb, kb, vb)
 
 
@@ -419,6 +420,7 @@ def _flash_bwd(groups, block_q, block_k, causal, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct((bh, t, d), qb.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="edl_flash_bwd_dq",
     )(qb, kb, vb, do, lse, delta)
 
     # dk/dv: grid sweeps q innermost; outputs are per QUERY head, then
@@ -449,6 +451,7 @@ def _flash_bwd(groups, block_q, block_k, causal, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="edl_flash_bwd_dkv",
     )(qb, kb, vb, do, lse, delta)
     hkv = bh // groups
     dk = dk_full.reshape(hkv, groups, t, d).sum(axis=1).astype(kb.dtype)

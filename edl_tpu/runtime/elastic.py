@@ -41,6 +41,7 @@ from edl_tpu.train.trainer import (
     make_train_step,
     shard_state,
 )
+from edl_tpu.obs import compilewatch
 from edl_tpu.obs import costmodel as _costmodel
 from edl_tpu.obs import disttrace
 from edl_tpu.obs import events as flight
@@ -82,12 +83,22 @@ class ReshardEvent:
     from_workers: int
     to_workers: int
     stall_s: float  # snapshot + remesh + reshard (the traffic-stopping window)
-    recompile_s: float  # first-step compile on the new mesh (overlappable)
+    # the first step on the new mesh, dispatch to loss (overlappable)
+    recompile_s: float
     step: int
     # True when the direct device-to-device move failed and the reshard
     # went through host-RAM staging — the slow path whose cost scales
     # with per-host state bytes (see doc/reshard_stall.md for the bound)
     fallback: bool = False
+    # recompile_s taken apart, from JAX's own compile events while the
+    # first step ran (obs/compilewatch.py): Python to jaxpr, jaxpr to
+    # MLIR, then the executable — compiled by XLA, or (cache_hit) found
+    # in the persistent cache and loaded onto the new mesh's chips.
+    # What is left of recompile_s after the three is the step running.
+    trace_s: float = 0.0
+    lower_s: float = 0.0
+    load_s: float = 0.0
+    cache_hit: bool = False
 
 
 @dataclass
@@ -476,7 +487,8 @@ class ElasticTrainer:
             flight.crash_dump("trainer", e)
             raise
         tb = time.perf_counter()
-        jax.block_until_ready(self.state.params)
+        with tracing.span("train.host_block"):
+            jax.block_until_ready(self.state.params)
         h_block.observe(time.perf_counter() - tb)
         self.report.train_seconds += time.perf_counter() - t0
         self.report.losses.extend(float(x) for x in raw_losses)
@@ -512,32 +524,8 @@ class ElasticTrainer:
         for _ in range(n_steps):
             self._maybe_rescale()
             ts = time.perf_counter()
-            batch = data_fn(self.global_batch_size)
-            dev_batch = global_batch(batch, self.plan, self.mesh)
-            first_on_mesh = (
-                bool(self.report.reshards)
-                and self.report.reshards[-1].recompile_s == 0.0
-            )
-            tc = time.perf_counter()
-            h_data.observe(tc - ts)
-            if self._stepper is not None:
-                self.state, metrics = self._stepper.step(self.state, dev_batch)
-                if (self._host_step + 1) % self.sync_every == 0:
-                    self.state = self._stepper.sync(self.state)
-            else:
-                self.state, metrics = self._step_fn(self.state, dev_batch)
-            if first_on_mesh:
-                jax.block_until_ready(metrics["loss"])
-                recompile_s = time.perf_counter() - tc
-                self.report.reshards[-1].recompile_s = recompile_s
-                tracing.tracer().record(
-                    "reshard.recompile", tc, recompile_s,
-                    {"to_workers": self.n_workers},
-                )
-                obs_metrics.default_registry().histogram(
-                    "edl_reshard_recompile_seconds",
-                    "first-step compile on the new mesh",
-                ).observe(recompile_s)
+            with tracing.step_span("train.step", self._host_step):
+                metrics = self._one_step(data_fn, ts, h_data)
             self.report.steps += 1
             self._host_step += 1
             self.report.examples += self.global_batch_size
@@ -545,3 +533,43 @@ class ElasticTrainer:
             raw_losses.append(metrics["loss"])
             self.maybe_checkpoint()
             h_step.observe(time.perf_counter() - ts)
+
+    def _one_step(self, data_fn, ts: float, h_data):
+        """Batch, dispatch and, on the first step of a mesh, the wait
+        for its loss with the compile events of that step counted."""
+        with tracing.span("train.data"):
+            batch = data_fn(self.global_batch_size)
+            dev_batch = global_batch(batch, self.plan, self.mesh)
+        ev = self.report.reshards[-1] if self.report.reshards else None
+        first_on_mesh = ev is not None and ev.recompile_s == 0.0
+        tc = time.perf_counter()
+        h_data.observe(tc - ts)
+        with compilewatch.Window() as built:
+            # on the first step of a mesh this is where the program is
+            # traced, lowered and loaded
+            with tracing.span("train.dispatch"):
+                if self._stepper is not None:
+                    self.state, metrics = self._stepper.step(
+                        self.state, dev_batch
+                    )
+                    if (self._host_step + 1) % self.sync_every == 0:
+                        self.state = self._stepper.sync(self.state)
+                else:
+                    self.state, metrics = self._step_fn(self.state, dev_batch)
+            if first_on_mesh:
+                jax.block_until_ready(metrics["loss"])
+        if first_on_mesh:
+            ev.recompile_s = time.perf_counter() - tc
+            ev.trace_s, ev.lower_s = built.trace_s, built.lower_s
+            ev.load_s, ev.cache_hit = built.load_s, built.cache_hit
+            tracing.tracer().record(
+                "reshard.recompile", tc, ev.recompile_s,
+                {"to_workers": self.n_workers, "trace_s": ev.trace_s,
+                 "lower_s": ev.lower_s, "load_s": ev.load_s,
+                 "cache_hit": ev.cache_hit},
+            )
+            obs_metrics.default_registry().histogram(
+                "edl_reshard_recompile_seconds",
+                "first-step compile on the new mesh",
+            ).observe(ev.recompile_s)
+        return metrics

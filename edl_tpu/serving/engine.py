@@ -118,6 +118,13 @@ from edl_tpu.models import llama
 from edl_tpu.obs import compilewatch
 from edl_tpu.obs import costmodel as _cm
 from edl_tpu.obs import memledger
+# the kernel's module (and with it Pallas) is imported here, at module
+# depth, not first by `llama.attention` inside a prefill program's
+# trace: under the trace's deep Python stack that import (hundreds of
+# enum classes) kept crossing a boundary of CPython's 16 KiB
+# frame-stack chunks, an mmap/munmap a call, +0.8 s on an engine's
+# first prefill (PERF.md section 6, PR 24)
+from edl_tpu.ops import flash_attention as _flash_attention  # noqa: F401
 from edl_tpu.serving import paged as _paged
 from edl_tpu.serving import spec as _spec
 from edl_tpu.serving.metrics import ServingMetrics
@@ -134,8 +141,36 @@ from edl_tpu.utils.logging import kv_logger
 
 log = kv_logger("serving")
 
+compilewatch.install()  # compile telemetry for every program built here
+
 _programs: "OrderedDict" = OrderedDict()
 _PROGRAM_CAP = 128
+
+
+def _named(name: str):
+    """Decorator, under ``jax.jit``: the function takes the name its
+    program is known by. ``jax.jit`` names the XLA module after the
+    function, so this is what the profiler's ``XLA Modules`` line and
+    the compile telemetry (``edl_compile_seconds{program}``) tell the
+    engine's programs apart by."""
+
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return rename
+
+
+@jax.named_scope("head")
+def _first_token(logits, key, temperature, sampling: bool):
+    """The token a final prefill piece emits, from its last position's
+    logits [1, V]: greedy, or drawn at ``temperature``. Part of the
+    model's ``head`` phase in a profile."""
+    if sampling:
+        t0 = jax.random.categorical(key, logits / temperature, axis=-1)
+    else:
+        t0 = jnp.argmax(logits, axis=-1)
+    return t0.astype(jnp.int32)[0]
 
 
 def _memo(key, make):
@@ -165,6 +200,7 @@ def _block_program(
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 6, 7))
+        @_named("edl_serve_block")
         def run(params, tok, pos, active, rem, eosv, kc, vc, key, temperature):
             return llama.decode_horizon_slots(
                 params, tok, pos, active, rem, eosv, kc, vc, cfg,
@@ -172,9 +208,7 @@ def _block_program(
                 sampling=sampling,
             )
 
-        # each memo key IS a distinct program — the compile watch times
-        # its first call and flags post-warmup compiles (obs.recompile)
-        return compilewatch.wrap(run, "serve.block")
+        return run
 
     return _memo(("block", cfg, b, s, horizon, sampling), make)
 
@@ -194,16 +228,13 @@ def _prefill_program(cfg: llama.LlamaConfig, tb: int, sampling: bool):
 
     def make():
         @partial(jax.jit, donate_argnums=(6, 7, 8, 9, 10, 11, 12))
+        @_named(f"edl_serve_prefill_{tb}")
         def run(params, tokens, last, slot, max_new, eos,
                 tok, pos, active, rem, eosv, kc, vc, key, temperature):
             logits, ks, vs = llama.prefill_padded(params, tokens, last, cfg)
             kc = jax.lax.dynamic_update_slice(kc, ks, (0, slot, 0, 0, 0))
             vc = jax.lax.dynamic_update_slice(vc, vs, (0, slot, 0, 0, 0))
-            if sampling:
-                t0 = jax.random.categorical(key, logits / temperature, axis=-1)
-            else:
-                t0 = jnp.argmax(logits, axis=-1)
-            t0 = t0.astype(jnp.int32)[0]
+            t0 = _first_token(logits, key, temperature, sampling)
             tok = tok.at[slot].set(t0)
             pos = pos.at[slot].set(last + 1)
             hit = (eos >= 0) & (t0 == eos)
@@ -212,7 +243,7 @@ def _prefill_program(cfg: llama.LlamaConfig, tb: int, sampling: bool):
             eosv = eosv.at[slot].set(eos)
             return t0, tok, pos, active, rem, eosv, kc, vc
 
-        return compilewatch.wrap(run, "serve.prefill")
+        return run
 
     return _memo(("prefill", cfg, tb, sampling), make)
 
@@ -229,6 +260,7 @@ def _block_program_paged(
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 7, 8))
+        @_named("edl_serve_block_paged")
         def run(params, tok, pos, active, rem, eosv, table, kc, vc,
                 key, temperature):
             return llama.decode_horizon_slots_paged(
@@ -237,7 +269,7 @@ def _block_program_paged(
                 temperature=temperature, sampling=sampling,
             )
 
-        return compilewatch.wrap(run, "serve.block")
+        return run
 
     return _memo(("block-paged", cfg, b, nb, m, bs, horizon, sampling), make)
 
@@ -253,17 +285,14 @@ def _prefill_paged_program(cfg: llama.LlamaConfig, tb: int, bs: int,
 
     def make():
         @partial(jax.jit, donate_argnums=(7, 8, 9, 10, 11, 12, 13))
+        @_named(f"edl_serve_prefill_paged_{tb}")
         def run(params, tokens, start, last, slot, max_new, eos,
                 tok, pos, active, rem, eosv, kc, vc, table,
                 key, temperature):
             logits, kc, vc = llama.prefill_paged(
                 params, tokens, start, last, table, kc, vc, cfg, bs
             )
-            if sampling:
-                t0 = jax.random.categorical(key, logits / temperature, axis=-1)
-            else:
-                t0 = jnp.argmax(logits, axis=-1)
-            t0 = t0.astype(jnp.int32)[0]
+            t0 = _first_token(logits, key, temperature, sampling)
             tok = tok.at[slot].set(t0)
             pos = pos.at[slot].set(start + last + 1)
             hit = (eos >= 0) & (t0 == eos)
@@ -272,7 +301,7 @@ def _prefill_paged_program(cfg: llama.LlamaConfig, tb: int, bs: int,
             eosv = eosv.at[slot].set(eos)
             return t0, tok, pos, active, rem, eosv, kc, vc
 
-        return compilewatch.wrap(run, "serve.prefill")
+        return run
 
     return _memo(("prefill-paged", cfg, tb, bs, sampling), make)
 
@@ -286,6 +315,7 @@ def _prefill_chunk_program(cfg: llama.LlamaConfig, c: int, bs: int):
 
     def make():
         @partial(jax.jit, donate_argnums=(3, 4))
+        @_named(f"edl_serve_prefill_chunk_{c}")
         def run(params, tokens, start, kc, vc, table):
             _, kc, vc = llama.prefill_paged(
                 params, tokens, start, jnp.int32(c - 1), table, kc, vc,
@@ -293,7 +323,7 @@ def _prefill_chunk_program(cfg: llama.LlamaConfig, c: int, bs: int):
             )
             return kc, vc
 
-        return compilewatch.wrap(run, "serve.prefill")
+        return run
 
     return _memo(("prefill-chunk", cfg, c, bs), make)
 
@@ -306,6 +336,7 @@ def _copy_block_program(cfg: llama.LlamaConfig, nb: int, bs: int):
 
     def make():
         @partial(jax.jit, donate_argnums=(0, 1))
+        @_named("edl_serve_block_copy")
         def run(kc, vc, src, dst):
             kb = jax.lax.dynamic_slice_in_dim(kc, src, 1, axis=1)
             vb = jax.lax.dynamic_slice_in_dim(vc, src, 1, axis=1)
@@ -313,7 +344,7 @@ def _copy_block_program(cfg: llama.LlamaConfig, nb: int, bs: int):
             vc = jax.lax.dynamic_update_slice_in_dim(vc, vb, dst, axis=1)
             return kc, vc
 
-        return compilewatch.wrap(run, "serve.block_copy")
+        return run
 
     return _memo(("blockcopy", cfg, nb, bs), make)
 
@@ -329,12 +360,13 @@ def _verify_program(cfg: llama.LlamaConfig, b: int, s: int, d: int):
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 3, 4, 5, 7, 8))
+        @_named("edl_serve_verify")
         def run(params, tok, draft, pos, active, rem, eosv, kc, vc):
             return llama.verify_step_slots(
                 params, tok, draft, pos, active, rem, eosv, kc, vc, cfg
             )
 
-        return compilewatch.wrap(run, "serve.verify")
+        return run
 
     return _memo(("verify", cfg, b, s, d), make)
 
@@ -348,13 +380,14 @@ def _verify_program_paged(
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 3, 4, 5, 8, 9))
+        @_named("edl_serve_verify_paged")
         def run(params, tok, draft, pos, active, rem, eosv, table, kc, vc):
             return llama.verify_step_slots_paged(
                 params, tok, draft, pos, active, rem, eosv, table, kc, vc,
                 cfg, block_size=bs,
             )
 
-        return compilewatch.wrap(run, "serve.verify")
+        return run
 
     return _memo(("verify-paged", cfg, b, nb, m, bs, d), make)
 
@@ -379,6 +412,7 @@ def _block_program_paged_q(
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 7, 8, 9, 10))
+        @_named("edl_serve_block_paged_q")
         def run(params, tok, pos, active, rem, eosv, table, kc, vc, ks, vs,
                 key, temperature):
             return llama.decode_horizon_slots_paged(
@@ -388,7 +422,7 @@ def _block_program_paged_q(
                 kv_quant=kv_quant, ks=ks, vs=vs,
             )
 
-        return compilewatch.wrap(run, "serve.block")
+        return run
 
     return _memo(
         ("block-paged-q", kv_quant, cfg, b, nb, m, bs, horizon, sampling),
@@ -403,6 +437,7 @@ def _prefill_paged_program_q(
 
     def make():
         @partial(jax.jit, donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
+        @_named(f"edl_serve_prefill_paged_q_{tb}")
         def run(params, tokens, start, last, slot, max_new, eos,
                 tok, pos, active, rem, eosv, kc, vc, ks, vs, table,
                 key, temperature):
@@ -410,11 +445,7 @@ def _prefill_paged_program_q(
                 params, tokens, start, last, table, kc, vc, cfg, bs,
                 kv_quant=kv_quant, ks=ks, vs=vs,
             )
-            if sampling:
-                t0 = jax.random.categorical(key, logits / temperature, axis=-1)
-            else:
-                t0 = jnp.argmax(logits, axis=-1)
-            t0 = t0.astype(jnp.int32)[0]
+            t0 = _first_token(logits, key, temperature, sampling)
             tok = tok.at[slot].set(t0)
             pos = pos.at[slot].set(start + last + 1)
             hit = (eos >= 0) & (t0 == eos)
@@ -423,7 +454,7 @@ def _prefill_paged_program_q(
             eosv = eosv.at[slot].set(eos)
             return t0, tok, pos, active, rem, eosv, kc, vc, ks, vs
 
-        return compilewatch.wrap(run, "serve.prefill")
+        return run
 
     return _memo(("prefill-paged-q", kv_quant, cfg, tb, bs, sampling), make)
 
@@ -435,6 +466,7 @@ def _prefill_chunk_program_q(
 
     def make():
         @partial(jax.jit, donate_argnums=(3, 4, 5, 6))
+        @_named(f"edl_serve_prefill_chunk_q_{c}")
         def run(params, tokens, start, kc, vc, ks, vs, table):
             _, kc, vc, ks, vs = llama.prefill_paged(
                 params, tokens, start, jnp.int32(c - 1), table, kc, vc,
@@ -442,7 +474,7 @@ def _prefill_chunk_program_q(
             )
             return kc, vc, ks, vs
 
-        return compilewatch.wrap(run, "serve.prefill")
+        return run
 
     return _memo(("prefill-chunk-q", kv_quant, cfg, c, bs), make)
 
@@ -457,6 +489,7 @@ def _copy_block_program_q(
 
     def make():
         @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+        @_named("edl_serve_block_copy_q")
         def run(kc, vc, ks, vs, src, dst):
             kb = jax.lax.dynamic_slice_in_dim(kc, src, 1, axis=1)
             vb = jax.lax.dynamic_slice_in_dim(vc, src, 1, axis=1)
@@ -468,7 +501,7 @@ def _copy_block_program_q(
             vs = jax.lax.dynamic_update_slice_in_dim(vs, vsb, dst, axis=1)
             return kc, vc, ks, vs
 
-        return compilewatch.wrap(run, "serve.block_copy")
+        return run
 
     return _memo(("blockcopy-q", kv_quant, cfg, nb, bs), make)
 
@@ -481,6 +514,7 @@ def _verify_program_paged_q(
 
     def make():
         @partial(jax.jit, donate_argnums=(1, 3, 4, 5, 8, 9, 10, 11))
+        @_named("edl_serve_verify_paged_q")
         def run(params, tok, draft, pos, active, rem, eosv, table,
                 kc, vc, ks, vs):
             return llama.verify_step_slots_paged(
@@ -488,7 +522,7 @@ def _verify_program_paged_q(
                 cfg, block_size=bs, kv_quant=kv_quant, ks=ks, vs=vs,
             )
 
-        return compilewatch.wrap(run, "serve.verify")
+        return run
 
     return _memo(("verify-paged-q", kv_quant, cfg, b, nb, m, bs, d), make)
 
@@ -1033,7 +1067,10 @@ class ContinuousBatchingEngine:
         and live requests replayed — the engine object stays usable and
         no accepted request is silently lost."""
         try:
-            return self._step_inner()
+            # parent of admit / prefill / dispatch / drain: its self
+            # time is the iteration's host bookkeeping
+            with tracing.span("serving.step"):
+                return self._step_inner()
         except Exception as e:
             self._recover(e)
             return 0
@@ -1047,7 +1084,12 @@ class ContinuousBatchingEngine:
                 # in-flight block may have finished one — sync now so
                 # the freed slot admits this boundary, not next
                 emitted += self._drain_all()
-            emitted += self._admit()
+            with tracing.span(
+                "serving.admit", queue_depth=self.queue.depth
+            ) as admit:
+                was = self._admit_seq
+                emitted += self._admit()
+                admit["admitted"] = self._admit_seq - was
         if self._paged:
             # one bounded prefill chunk per prefilling slot per step,
             # interleaved with the decode block below — a long prompt
@@ -1272,6 +1314,7 @@ class ContinuousBatchingEngine:
         # same correlation key as /events?rid= (block spans are shared
         # across requests; per-request identity is the attr, not the
         # span).
+        # one list a block: the drain of this block reads it again
         rids = [s.rid for s in self._slots if s is not None]
         with tracing.span("serving.dispatch", horizon=self.horizon,
                           rids=rids):
@@ -1318,7 +1361,7 @@ class ContinuousBatchingEngine:
             if s is not None and s.pf_next is None
         }
         self._inflight.append(
-            (toks, self.clock(), members, self._block_cost, None)
+            (toks, self.clock(), members, self._block_cost, None, rids)
         )
 
     def _dispatch_verify(self, drafts: Dict[int, List[int]]) -> None:
@@ -1401,7 +1444,7 @@ class ContinuousBatchingEngine:
             if s is not None and s.pf_next is None
         }
         self._inflight.append(
-            (toks, self.clock(), members, self._verify_cost, drafted)
+            (toks, self.clock(), members, self._verify_cost, drafted, rids)
         )
 
     def _drain_one(self) -> int:
@@ -1411,11 +1454,10 @@ class ContinuousBatchingEngine:
         read -1 and terminate the row's replay — the device freezes a
         row at exactly the step the host would finish it, so the two
         views never disagree."""
-        with tracing.span(
-            "serving.drain",
-            rids=[s.rid for s in self._slots if s is not None],
-        ):
-            blk, t_dispatch, members, cost, drafted = (
+        # rids: the list the block's dispatch built (the requests that
+        # ride the block being synced)
+        with tracing.span("serving.drain", rids=self._inflight[0][5]):
+            blk, t_dispatch, members, cost, drafted, _ = (
                 self._inflight.popleft()
             )
             # chaos site: the popped block is lost on a crash here —
@@ -1554,6 +1596,14 @@ class ContinuousBatchingEngine:
             # queue wait ends at the pop — from here the clock charges
             # the prefill phase (the decomposition's first boundary)
             self.metrics.on_pop(req.rid)
+            # the same boundary as a span: the scheduler's own wait,
+            # submit to pop. The engine's clock is not the tracer's, so
+            # the span is placed by its length, ending now
+            wait = self.clock() - req.submit_s
+            tracing.tracer().record(
+                "serving.queue", time.perf_counter() - wait, wait,
+                {"rid": req.rid},
+            )
             slot = free.pop(0)
             # from here to the bookkeeping commit the request exists
             # only in this local — publish it so a prefill crash

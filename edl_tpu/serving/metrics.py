@@ -339,7 +339,12 @@ class ServingMetrics:
         block's drain overlaps the NEXT block's device work, so this
         is end-to-end block latency as the host observed it, not pure
         device time (that is what makes it the right number for SLO
-        accounting)."""
+        accounting). It SPANS TWO STEPS of the double buffer: block k
+        is dispatched in step k and drained in step k+1, after block
+        k+1 went out, so a steady engine reads about twice its step
+        period here (36 ms against an 18 ms step on the chip, PERF.md).
+        The device's own time for a block is the ``edl_serve_block``
+        module in a profiler trace."""
         self.block_hist.observe(seconds)
         self._r_block.observe(seconds)
 

@@ -24,6 +24,8 @@ from edl_tpu.obs import metrics as obs_metrics
 from edl_tpu.parallel.mesh import MeshPlan
 from edl_tpu.parallel import sharding as shd
 
+compilewatch.install()  # compile telemetry for every program built here
+
 
 def _record_dispatch(dt_s: float, n_steps: int = 1) -> None:
     """Step-factory telemetry choke point: every compiled update path
@@ -111,13 +113,11 @@ def _apply_update(loss_fn, tx, state: TrainState, batch):
     """One optimizer update — the single source of the update rule,
     shared by the per-step and scan-fused step factories."""
     loss, grads = jax.value_and_grad(loss_fn)(state.params, batch)
-    updates, new_opt = tx.update(grads, state.opt_state, state.params)
+    with jax.named_scope("optimizer"):
+        updates, new_opt = tx.update(grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
     return (
-        TrainState(
-            step=state.step + 1,
-            params=optax.apply_updates(state.params, updates),
-            opt_state=new_opt,
-        ),
+        TrainState(step=state.step + 1, params=params, opt_state=new_opt),
         loss,
     )
 
@@ -143,7 +143,9 @@ def make_train_step(
     reference's pserver push/pull protocol).
     """
 
-    def _step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
+    def edl_train_step(
+        state: TrainState, batch
+    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
         new_state, loss = _apply_update(loss_fn, tx, state, batch)
         return new_state, {"loss": loss}
 
@@ -160,20 +162,17 @@ def make_train_step(
             )
             metric_sh = NamedSharding(mesh, P())
             cell.append(
-                # compile watch: the first call (where jit actually
-                # traces + compiles) lands in edl_compile_seconds and,
-                # post-warmup, on the flight-recorder timeline — a
+                # the function's name is the program's: its build
+                # lands in edl_compile_seconds{program="edl_train_step"}
+                # and, post-warmup, on the flight-recorder timeline — a
                 # steady-state loop that recompiles (the reshard
                 # recompile aside, which re-enters here by design) is
                 # paying seconds someone should see
-                compilewatch.wrap(
-                    jax.jit(
-                        _step,
-                        in_shardings=(state_sh, batch_sh),
-                        out_shardings=(state_sh, {"loss": metric_sh}),
-                        donate_argnums=(0,) if donate else (),
-                    ),
-                    "train.step",
+                jax.jit(
+                    edl_train_step,
+                    in_shardings=(state_sh, batch_sh),
+                    out_shardings=(state_sh, {"loss": metric_sh}),
+                    donate_argnums=(0,) if donate else (),
                 )
             )
         t = time.perf_counter()
@@ -209,7 +208,7 @@ def make_train_multistep(
     last. Semantically identical to K calls of :func:`make_train_step`.
     """
 
-    def _multi(state: TrainState, batches):
+    def edl_train_step_multi(state: TrainState, batches):
         state, losses = jax.lax.scan(
             lambda st, b: _apply_update(loss_fn, tx, st, b), state, batches
         )
@@ -226,17 +225,14 @@ def make_train_multistep(
             batch_sh = jax.tree_util.tree_map(lambda _: stacked, batches)
             metric_sh = NamedSharding(mesh, P())
             cell.append(
-                compilewatch.wrap(
-                    jax.jit(
-                        _multi,
-                        in_shardings=(state_sh, batch_sh),
-                        out_shardings=(
-                            state_sh,
-                            {"loss": metric_sh, "losses": metric_sh},
-                        ),
-                        donate_argnums=(0,) if donate else (),
+                jax.jit(
+                    edl_train_step_multi,
+                    in_shardings=(state_sh, batch_sh),
+                    out_shardings=(
+                        state_sh,
+                        {"loss": metric_sh, "losses": metric_sh},
                     ),
-                    "train.multistep",
+                    donate_argnums=(0,) if donate else (),
                 )
             )
         t = time.perf_counter()
@@ -344,7 +340,7 @@ class LocalSyncStepper:
                 else state.opt_state,
             )
 
-        def _lstep(state: TrainState, batch):
+        def edl_train_step_localsync(state: TrainState, batch):
             # [B, ...] -> [dp, B/dp, ...]; the global batch's dp shards
             # become the per-group local batches (layout-preserving).
             bt = jax.tree_util.tree_map(
@@ -375,14 +371,11 @@ class LocalSyncStepper:
             out_shardings=grouped,
             donate_argnums=don,
         )
-        self._step = compilewatch.wrap(
-            jax.jit(
-                _lstep,
-                in_shardings=(grouped, batch_sh),
-                out_shardings=(grouped, {"loss": replicated}),
-                donate_argnums=don,
-            ),
-            "train.localsync",
+        self._step = jax.jit(
+            edl_train_step_localsync,
+            in_shardings=(grouped, batch_sh),
+            out_shardings=(grouped, {"loss": replicated}),
+            donate_argnums=don,
         )
 
     def localize(self, state: TrainState) -> TrainState:

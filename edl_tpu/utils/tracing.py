@@ -1,11 +1,22 @@
-"""Tracing — span recording + JAX profiler hooks.
+"""Tracing — span recording, on the profiler's clock too.
 
 The reference has no tracing at all (SURVEY §5: closest is log15 caller
 stacks); here the north-star metric is rescale-stall seconds, so the
 elastic runtime emits timed spans (reshard phases, checkpoint I/O,
 recompiles) into a process-wide tracer that can be dumped as
-chrome://tracing / Perfetto JSON. ``jax_profile`` additionally wraps a
-block in the XLA-level profiler (TensorBoard trace) when available.
+chrome://tracing / Perfetto JSON.
+
+One primitive, two places. Every span is written to the ring (on
+``time.perf_counter``) and, in a process that has imported JAX, is also
+a ``jax.profiler.TraceAnnotation`` named ``"edl." + name`` that carries
+the span's ``seq``: under a profiler session (``jax.profiler.trace`` /
+``start_trace``) the span sits in the ``.xplane.pb`` beside the
+device's events, and outside one the annotation is a flag check. A span
+found in both gives the offset between ``perf_counter`` and the
+profiler's nanoseconds, and the lowest ``seq`` in a trace is the moment
+its session began on the program's own clock (:func:`clock_offset_ns`).
+JAX is never imported from here: a process without it (``edl`` CLI,
+monitor, coordinator) records to the ring alone.
 
 Usage:
     from edl_tpu.utils import tracing
@@ -17,8 +28,10 @@ Usage:
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -37,7 +50,9 @@ class Span:
     dur_s: float
     attrs: Dict[str, Any] = field(default_factory=dict)
     thread: int = 0
-    seq: int = 0  # per-tracer monotonic id (survives ring eviction)
+    # per-tracer monotonic id, taken when the span OPENS (survives ring
+    # eviction); the profiler annotation of the span carries the same
+    seq: int = 0
 
 
 # distributed-trace context hooks (installed by edl_tpu.obs.disttrace
@@ -53,6 +68,35 @@ _ctx_exit = None
 def set_span_context_hooks(enter, exit) -> None:
     global _ctx_enter, _ctx_exit
     _ctx_enter, _ctx_exit = enter, exit
+
+
+ANNOTATION_PREFIX = "edl."
+_profiler = None  # jax.profiler, once the process has imported jax
+
+
+def _annotation(name: str, seq: int, step_num: Optional[int] = None):
+    """The span as a profiler annotation, or None in a process that has
+    not imported JAX (this module never does)."""
+    global _profiler
+    if _profiler is None:
+        jax = sys.modules.get("jax")
+        if jax is None or not hasattr(jax, "profiler"):
+            return None
+        _profiler = jax.profiler
+    if step_num is None:
+        return _profiler.TraceAnnotation(ANNOTATION_PREFIX + name, seq=seq)
+    return _profiler.StepTraceAnnotation(
+        ANNOTATION_PREFIX + name, step_num=step_num, seq=seq
+    )
+
+
+def clock_offset_ns(span: "Span", annotation_start_ns: int,
+                    t0: float) -> float:
+    """What to add to ``perf_counter() * 1e9`` to get the profiler's
+    nanoseconds, from one span found in both the ring (``span``, of a
+    tracer whose timebase is ``t0``) and a trace (its annotation's
+    start)."""
+    return annotation_start_ns - (t0 + span.start_s) * 1e9
 
 
 class Tracer:
@@ -78,44 +122,81 @@ class Tracer:
         self._t0 = time.perf_counter()
         self.t0_wall = time.time()
         self.t0 = self._t0  # public timebase (flight-recorder merge)
-        self._seq = 0  # monotonic span id; never reset (paging cursor)
+        # span ids, taken at open; never reset
+        self._ids = itertools.count(1)
+        # spans ever written to the ring, in the order they closed:
+        # the /trace paging cursor (an id taken at open cannot be one,
+        # a parent closes after children that were already shipped)
+        self._closed = 0
         self.max_spans = max_spans
         self.enabled = True
         self.dropped = 0  # spans evicted after the ring filled
         self._listeners: List[Callable[[Span], None]] = []
 
+    def span(self, name: str, **attrs: Any):
+        """Time the block. Yields the span's attribute dict, so what is
+        only known at the end can still be recorded
+        (``with span("serving.admit") as a: ...; a["admitted"] = n``)."""
+        return self._span(name, None, attrs)
+
+    def step_span(self, name: str, step_num: int, **attrs: Any):
+        """:meth:`span` for one step of a loop: its annotation is a
+        ``StepTraceAnnotation``, which the profiler's tools group the
+        device's work by."""
+        return self._span(name, step_num, attrs)
+
     @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[None]:
+    def _span(self, name: str, step_num: Optional[int],
+              attrs: Dict[str, Any]) -> Iterator[Dict[str, Any]]:
         if not self.enabled:
-            yield
+            yield attrs
             return
+        seq = next(self._ids)
         state = ctx_attrs = None
         if _ctx_enter is not None:
             # the span body runs inside its OWN child trace context:
             # nested spans parent here, and flight events emitted
             # within carry these ids (how /trace and /events agree)
             state, ctx_attrs = _ctx_enter()
+        note = _annotation(name, seq, step_num)
+        # the two clocks are read back to back: the pair is what joins
+        # them (clock_offset_ns)
         start = time.perf_counter()
+        if note is not None:
+            note.__enter__()
         try:
-            yield
+            yield attrs
         finally:
+            if note is not None:
+                note.__exit__(None, None, None)
+            dur = time.perf_counter() - start
             if _ctx_exit is not None:
                 _ctx_exit(state)
             if ctx_attrs:
                 attrs = {**attrs, **ctx_attrs}
-            self.record(name, start, time.perf_counter() - start, attrs)
+            self._store(Span(name, start - self._t0, dur, dict(attrs),
+                             threading.get_ident(), seq))
 
     def record(self, name: str, start_s: float, dur_s: float,
                attrs: Optional[Dict[str, Any]] = None) -> None:
-        """``start_s`` is absolute time.perf_counter(); stored relative to
-        tracer start so chrome-trace timestamps line up across threads."""
+        """A span timed by the caller, after the fact. ``start_s`` is
+        absolute time.perf_counter(); stored relative to tracer start so
+        chrome-trace timestamps line up across threads. Its annotation
+        is a mark at the moment of recording (the profiler takes no
+        past events): it places the span's ``seq`` in the trace."""
         if not self.enabled:
             return
-        span = Span(name, start_s - self._t0, dur_s, dict(attrs or {}),
-                    threading.get_ident())
+        seq = next(self._ids)
+        note = _annotation(name, seq)
+        if note is not None:
+            with note:
+                pass
+        self._store(Span(name, start_s - self._t0, dur_s,
+                         dict(attrs or {}), threading.get_ident(), seq))
+
+    def _store(self, span: Span) -> None:
         with self._lock:
-            self._seq += 1
-            span.seq = self._seq
+            self._closed += 1
             if len(self._spans) >= self.max_spans:
                 # ring semantics: evict the OLDEST, keep the new span
                 if self.dropped == 0:
@@ -192,6 +273,18 @@ class Tracer:
         with self._lock:
             return list(self._spans), self.dropped
 
+    def _page(self, since: int, last_n: Optional[int]):
+        """(spans, dropped, cursor): the spans that closed after the
+        first ``since`` ever stored, newest ``last_n`` kept, and the
+        cursor that resumes after the last of them."""
+        with self._lock:
+            spans, dropped, closed = (
+                list(self._spans), self.dropped, self._closed)
+        spans = spans[max(0, since - (closed - len(spans))):]
+        if last_n is not None:
+            spans = spans[-max(int(last_n), 0):]
+        return spans, dropped, (closed if spans else since)
+
     def to_chrome_doc(
         self, since_seq: int = 0, last_n: Optional[int] = None
     ) -> Dict[str, Any]:
@@ -201,15 +294,14 @@ class Tracer:
         exporter's ``/trace`` and written by :meth:`dump`.
 
         ``since_seq``/``last_n`` bound the window (the ``/events``
-        paging mirror): only spans with ``seq > since_seq`` ship,
-        newest ``last_n`` kept. The metadata event carries ``max_seq``
-        so an incremental puller knows its next cursor — a fleet
-        cadence tick fetches the delta, not the whole ring."""
-        spans, dropped = self._snapshot()
-        if since_seq:
-            spans = [s for s in spans if s.seq > since_seq]
-        if last_n is not None:
-            spans = spans[-max(int(last_n), 0):]
+        paging mirror): ``since_seq`` is a cursor over spans in the
+        order they CLOSED (the ring's order) — only spans stored after
+        the first ``since_seq`` ship, newest ``last_n`` kept. The
+        metadata event carries ``max_seq``, the next cursor, so a fleet
+        cadence tick fetches the delta, not the whole ring. (An event's
+        own ``seq`` is its id from when it opened: the key it shares
+        with its profiler annotation, not a cursor.)"""
+        spans, dropped, cursor = self._page(since_seq, last_n)
         events = [
             {
                 "name": s.name,
@@ -233,7 +325,7 @@ class Tracer:
                     "dropped": dropped,
                     "max_spans": self.max_spans,
                     "spans": len(events),
-                    "max_seq": max((s.seq for s in spans), default=since_seq),
+                    "max_seq": cursor,
                 },
             }
         )
@@ -265,28 +357,13 @@ def span(name: str, **attrs: Any):
     return _global.span(name, **attrs)
 
 
+def step_span(name: str, step_num: int, **attrs: Any):
+    return _global.step_span(name, step_num, **attrs)
+
+
 def dump(path: str) -> None:
     _global.dump(path)
 
 
 def summary() -> Dict[str, Dict[str, float]]:
     return _global.summary()
-
-
-@contextlib.contextmanager
-def jax_profile(logdir: str) -> Iterator[None]:
-    """XLA-level profile of the block (TensorBoard trace viewer). No-op
-    when jax.profiler is unavailable (e.g. stripped builds)."""
-    try:
-        import jax
-
-        ctx = jax.profiler.trace(logdir)
-        ctx.__enter__()  # may raise too (nested trace, unwritable logdir)
-    except Exception as e:  # pragma: no cover
-        log.warn("jax profiler unavailable", error=str(e))
-        ctx = None
-    try:
-        yield
-    finally:
-        if ctx is not None:
-            ctx.__exit__(None, None, None)

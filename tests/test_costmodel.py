@@ -8,8 +8,8 @@
   decode-horizon block (tolerance-gated; skipped when the build's
   cost_analysis is unavailable);
 * device-peak table semantics + env overrides;
-* the EfficiencyMeter gauges and compile-watch behavior (first-call
-  timing, obs.recompile only after warmup);
+* the EfficiencyMeter gauges (the compile watch's own tests are in
+  tests/test_program_names.py);
 * the ElasticTrainer live-MFU wiring (flops_per_example ->
   edl_mfu{phase="train"}).
 """
@@ -287,44 +287,6 @@ def test_efficiency_snapshot_flattens_gauges():
     snap = cm.efficiency_snapshot(reg)
     assert snap["mfu_decode"] == pytest.approx(0.1)
     assert snap["bw_util_decode"] == pytest.approx(0.1)
-
-
-# ---------------------------------------------------------------------------
-# compile watch
-
-
-def test_compilewatch_times_first_call_only_and_flags_recompiles():
-    reg = om.reset_default_registry()
-    rec = flight.default_recorder()
-    rec.clear()
-    calls = []
-
-    def fn(x):
-        calls.append(x)
-        return x + 1
-
-    w = compilewatch.wrap(fn, "test.prog")
-    assert w(1) == 2 and w(2) == 3 and w(3) == 4
-    hist = reg.get("edl_compile_seconds")
-    assert hist.stats(program="test.prog")["count"] == 1
-    assert reg.get("edl_compiles_total").value(program="test.prog") == 1
-    # warmup not yet declared over: no recompile events
-    kinds = [r["kind"] for r in rec.records()]
-    assert "obs.recompile" not in kinds
-    # a NEW program compiled after mark_warm lands on the timeline
-    compilewatch.mark_warm()
-    w2 = compilewatch.wrap(fn, "test.prog2")
-    w2(1)
-    evs = [r for r in rec.records() if r["kind"] == "obs.recompile"]
-    assert len(evs) == 1
-    assert evs[0]["attrs"]["program"] == "test.prog2"
-    assert evs[0]["severity"] == "warn"
-    # already-compiled programs stay silent
-    w(4)
-    assert len(
-        [r for r in rec.records() if r["kind"] == "obs.recompile"]
-    ) == 1
-    om.reset_default_registry()
 
 
 # ---------------------------------------------------------------------------

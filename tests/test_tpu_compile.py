@@ -104,7 +104,7 @@ def test_fused_decode_block_compiles_for_v5e_and_donates_the_cache(v5e):
     kc = _sds(
         (cfg.n_layers, b, s, cfg.n_kv_heads, cfg.head_dim), cfg.dtype, one
     )
-    program = engine._block_program(cfg, b, s, 8, False).__wrapped__
+    program = engine._block_program(cfg, b, s, 8, False)
     compiled = program.lower(
         params, i32, i32, _sds((b,), jnp.bool_, one), i32, i32, kc, kc,
         _sds((2,), jnp.uint32, one), _sds((), jnp.float32, one),

@@ -151,3 +151,145 @@ def test_force_checkpoint(tmp_path, cpu_devices):
     path = t.maybe_checkpoint(force=True)
     assert path and os.path.isdir(path)
     assert t.maybe_checkpoint(force=True) is None  # same step: no rewrite
+
+
+# ---------------------------------------------------------------------------
+# one primitive on the profiler's clock
+
+
+def _xplane_annotations(logdir):
+    """{seq: (name, start ns, end ns)} of the edl.* annotations of the
+    newest trace under ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(tracing.ANNOTATION_PREFIX):
+                    stats = dict(ev.stats)
+                    start = int(ev.start_ns)
+                    out[int(stats["seq"])] = (
+                        ev.name, start, start + int(ev.duration_ns), stats)
+    return out
+
+
+def test_span_is_in_the_ring_and_in_the_profile_with_one_seq(tmp_path):
+    import time
+
+    tr = tracing.tracer()
+    with tracing.span("before.session"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("first", rid="a"):
+            time.sleep(0.002)
+        time.sleep(0.005)
+        with tracing.span("second"):
+            time.sleep(0.002)
+        with tracing.step_span("a.step", 7):
+            pass
+        tr.record("after.the.fact", time.perf_counter() - 0.5, 0.5)
+    finally:
+        jax.profiler.stop_trace()
+    ring = {s.name: s for s in tr.spans()}
+    notes = _xplane_annotations(str(tmp_path))
+    # every span opened under the session is in both, under one seq
+    for name in ("first", "second", "a.step", "after.the.fact"):
+        assert notes[ring[name].seq][0] == "edl." + name
+    assert notes[ring["a.step"].seq][3]["step_num"] == 7
+    # the lowest seq in the trace: spans below it predate the session
+    assert min(notes) == ring["first"].seq > ring["before.session"].seq
+    # one joined span gives the offset between the two clocks; it
+    # places another span's start within a millisecond
+    first, second = ring["first"], ring["second"]
+    offset = tracing.clock_offset_ns(first, notes[first.seq][1], tr.t0)
+    predicted = (tr.t0 + second.start_s) * 1e9 + offset
+    assert abs(predicted - notes[second.seq][1]) < 1e6
+    # and the annotation lasted as long as the span
+    assert abs((notes[first.seq][2] - notes[first.seq][1])
+               - first.dur_s * 1e9) < 1e6
+
+
+def test_seq_is_taken_at_open_and_paging_follows_the_ring():
+    tr = tracing.Tracer()
+    with tr.span("parent") as attrs:
+        with tr.span("child"):
+            pass
+        # a puller comes by while the parent is still open
+        doc = tr.to_chrome_doc()
+        meta = next(e for e in doc["traceEvents"] if e["ph"] == "M")
+        cursor = meta["args"]["max_seq"]
+        attrs["found"] = 1  # known only at the end
+    parent, child = tr.spans("parent")[0], tr.spans("child")[0]
+    assert parent.seq < child.seq  # ids in opening order
+    assert parent.attrs == {"found": 1}
+    # the parent closed after the cursor was handed out: the next page
+    # still has it
+    page = tr.to_chrome_doc(since_seq=cursor)
+    assert [e["name"] for e in page["traceEvents"] if e["ph"] == "X"] == [
+        "parent"]
+
+
+def test_tracing_imports_and_records_with_jax_blocked():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from edl_tpu.utils import tracing\n"
+        "with tracing.span('a', k=1):\n    pass\n"
+        "with tracing.step_span('b', 3):\n    pass\n"
+        "tracing.tracer().record('c', 0.0, 0.1)\n"
+        "assert [s.name for s in tracing.tracer().spans()] == list('abc')\n"
+        "assert not hasattr(tracing, 'jax_profile')\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=root)
+
+
+def test_disabled_tracer_opens_no_annotation(tmp_path):
+    tr = tracing.Tracer()
+    tr.enabled = False
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("never"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert tr.spans() == []
+    assert not any(n[0] == "edl.never"
+                   for n in _xplane_annotations(str(tmp_path)).values())
+
+
+def test_train_loop_spans_nest_and_first_step_of_a_mesh_is_taken_apart(
+        cpu_devices):
+    tr = _trainer(devices=cpu_devices[:4])
+    tr.start(linreg.init_params(jax.random.PRNGKey(0)), n_workers=4)
+    tr.train_steps(_data_fn(64), 2)
+    tr.request_rescale(2)
+    tr.train_steps(_data_fn(64), 2)
+    spans = tracing.tracer().spans()
+    names = [s.name for s in spans]
+    assert names.count("train.step") == 4
+    assert names.count("train.data") == names.count("train.dispatch") == 4
+    assert names.count("train.host_block") == 2
+    step = next(s for s in spans if s.name == "train.step")
+    for child in ("train.data", "train.dispatch"):
+        c = next(s for s in spans if s.name == child)
+        assert step.start_s <= c.start_s
+        assert c.start_s + c.dur_s <= step.start_s + step.dur_s + 1e-6
+    # 4 -> 2: the first step there is re-traced, lowered and compiled,
+    # and the event says how long each took
+    ev = tr.report.reshards[-1]
+    assert (ev.from_workers, ev.to_workers) == (4, 2)
+    assert ev.trace_s > 0 and ev.lower_s > 0 and ev.load_s > 0
+    assert ev.trace_s + ev.lower_s + ev.load_s <= ev.recompile_s
+    assert ev.cache_hit is False  # conftest: no persistent cache
+    rec = tracing.tracer().spans("reshard.recompile")[-1]
+    assert rec.attrs["trace_s"] == ev.trace_s
+    assert rec.attrs["load_s"] == ev.load_s and rec.attrs["to_workers"] == 2
