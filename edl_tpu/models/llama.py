@@ -622,7 +622,15 @@ def _decode_step(params: Dict, tok: jnp.ndarray, pos, kc, vc, cfg: LlamaConfig):
     per-layer cache leaves, int8 KV, and a pallas single-query flash
     kernel — XLA's dense cached attention is already efficient once
     the restack is gone. Unrolling costs O(L) compile once per
-    (cfg, shape) — the memoized ``generate`` program."""
+    (cfg, shape) — the memoized ``generate`` program.
+
+    That kernel verdict holds HERE: one ``pos`` for every row and a
+    cache of a few MB a layer, nearly all of it live. The slot path
+    (:func:`decode_step_slots`) has a ``pos`` per slot, 59-90% of its
+    padded cache dead at 7B widths, and XLA copied each ``kc[i]`` out
+    before reading it; there ``ops.decode_attention`` reads the live
+    blocks straight from the stacked cache (PERF.md section 6, PR 25).
+    This function keeps the dense form."""
     b = tok.shape[0]
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     groups = h // kv
@@ -694,6 +702,7 @@ def decode_step_slots(
     kc: jnp.ndarray,
     vc: jnp.ndarray,
     cfg: LlamaConfig,
+    live: Optional[jnp.ndarray] = None,
 ):
     """One continuous-batching decode step over B independent KV slots.
     tok [B] int32 (each slot's previous token); pos [B] int32 (the
@@ -710,35 +719,70 @@ def decode_step_slots(
     the uniform-position path. Rows the caller considers inactive
     should be fed (tok=0, pos=0) and their outputs ignored: they
     re-write slot position 0 each step, which the next prefill-insert
-    overwrites before it is ever unmasked."""
+    overwrites before it is ever unmasked.
+
+    Under ``cfg.use_flash`` the attention of a layer is
+    ``ops.decode_attention``: one kernel that reads each slot's live
+    prefix (positions ``<= pos[row]``, so an inactive row costs one
+    block) straight out of the stacked cache. ``live`` [B] bool marks
+    the rows whose logits the caller will use: the kernel reads the
+    others at position 0 alone, whatever ``pos`` they froze at (a
+    finished request's prefix is nobody's to read; the row's write
+    still lands at its ``pos``, past everything that request read).
+    The dense lines (:func:`slot_attention_dense`) read all ``S``
+    padded positions of ``kc[i]`` and are the ``use_flash=False``
+    path, which ``live`` does not touch."""
     b = tok.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     groups = h // kvh
-    s = kc.shape[2]
     rows = jnp.arange(b)
+    if cfg.use_flash:
+        from edl_tpu.ops.decode_attention import decode_attention
+        from edl_tpu.ops.flash_attention import _INTERPRET
+
+        read_to = pos if live is None else jnp.where(live, pos, 0)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tok[:, None], axis=0).astype(cfg.dtype)
     for i in range(cfg.n_layers):
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
-        dt = x.dtype
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
             q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
             kc = kc.at[i, rows, pos].set(knew[:, 0])
             vc = vc.at[i, rows, pos].set(vnew[:, 0])
-            kci, vci = kc[i], vc[i]  # static-index slices of the carry
-            qg = q.reshape(b, 1, kvh, groups, hd)
-            scores = jnp.einsum("btkgd,bskd->bkgts", qg, kci) / np.sqrt(hd)
-            mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
-            scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
-            o = jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(b, 1, h * hd)
-            x = x + _matw(o, lp["wo"])
+            qg = q.reshape(b, kvh, groups, hd)
+            if cfg.use_flash:
+                # the kernel or its error, as in ``attention``: each
+                # slot's live prefix, read out of the stacked cache
+                # (never ``kc[i]``: XLA copies that slice out first)
+                o = decode_attention(
+                    qg, kc, vc, read_to, jnp.int32(i),
+                    interpret=_INTERPRET.get(),
+                )
+            else:
+                o = slot_attention_dense(qg, kc[i], vc[i], pos)
+            x = x + _matw(o.reshape(b, 1, h * hd), lp["wo"])
         x = _mlp(cfg, x, lp)
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
     return logits, kc, vc
+
+
+def slot_attention_dense(qg, kci, vci, pos):
+    """The dense form of one layer's slot attention: qg [B, KV, groups,
+    hd] against ALL ``S`` positions of kci / vci [B, S, KV, hd], masked
+    to ``<= pos[row]`` afterwards. The ``use_flash=False`` path, and
+    what ``ops.decode_attention`` is tested against."""
+    b, kvh, groups, hd = qg.shape
+    s = kci.shape[1]
+    scores = jnp.einsum(
+        "btkgd,bskd->bkgts", qg[:, None], kci
+    ) / np.sqrt(hd)
+    mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
+    scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(qg.dtype)
+    return jnp.einsum("bkgts,bskd->btkgd", probs, vci).reshape(qg.shape)
 
 
 def decode_horizon_slots(
@@ -774,7 +818,11 @@ def decode_horizon_slots(
     rewrite at the frozen ``pos`` is idempotent (same token, same
     position, same visible cache ⇒ bit-identical K/V) and strictly
     row-local, so active rows decode exactly as if the frozen row had
-    been evicted. Greedy output is therefore token-identical to
+    been evicted. (Under ``use_flash`` a frozen row's attention reads
+    position 0 alone, ``live=active`` below, so what it rewrites at its
+    frozen ``pos`` differs past layer 0; that position lies past
+    everything its finished request read, and the next prefill into
+    the slot resets it.) Greedy output is therefore token-identical to
     stepping :func:`decode_step_slots` one position at a time, which
     is itself per-row identical to sequential :func:`generate` — the
     contract ``tests/test_serving.py`` pins at H ∈ {1, 4, 16}.
@@ -790,7 +838,9 @@ def decode_horizon_slots(
 
     def step(carry, k):
         tok, pos, active, rem, kc, vc = carry
-        logits, kc, vc = decode_step_slots(params, tok, pos, kc, vc, cfg)
+        logits, kc, vc = decode_step_slots(
+            params, tok, pos, kc, vc, cfg, live=active
+        )
         with jax.named_scope("head"):
             if sampling:
                 nxt = jax.random.categorical(

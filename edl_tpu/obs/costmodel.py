@@ -36,7 +36,10 @@ FLOPs conventions (matching the published bench numbers exactly):
   _decode_step``/``decode_step_slots`` einsum over ``s = max_len`` by
   construction), so the per-step cost model uses the FULL padded
   length, not the average occupancy — this is program cost, the right
-  roofline denominator for what the chip actually executes.
+  roofline denominator for what the chip actually executes. The slot
+  path under ``use_flash`` reads each slot's live blocks alone
+  (``ops/decode_attention.py``); its engine passes the positions a slot
+  is read to, averaged over the slots, as ``s_pad``.
 
 Bytes conventions: a decode step must move every parameter byte (the
 weight stream — the defining cost of small-batch decode) plus the full
@@ -376,7 +379,9 @@ class CostModel:
     def decode_block(self, b: int, horizon: int, s_pad: int) -> Cost:
         """One fused horizon block as dispatched: ``horizon`` steps of
         ``b`` rows (frozen rows still compute — program cost) at the
-        full padded context."""
+        context the program reads: the full padded one, or, for a
+        program that reads live blocks only, the mean positions read a
+        row (a float is fine)."""
         step_bytes = decode_step_bytes(
             self.cfg, self.param_bytes, b, s_pad, self.kv_bytes_per_el,
             self.kv_block_size,

@@ -125,6 +125,7 @@ from edl_tpu.obs import memledger
 # frame-stack chunks, an mmap/munmap a call, +0.8 s on an engine's
 # first prefill (PERF.md section 6, PR 24)
 from edl_tpu.ops import flash_attention as _flash_attention  # noqa: F401
+from edl_tpu.ops import decode_attention as _decode_attention
 from edl_tpu.serving import paged as _paged
 from edl_tpu.serving import spec as _spec
 from edl_tpu.serving.metrics import ServingMetrics
@@ -801,10 +802,21 @@ class ContinuousBatchingEngine:
         self._eff = _cm.EfficiencyMeter(
             self._cost.peak, registry=self.metrics.registry
         )
-        # constant per engine: every block runs max_slots rows for
-        # `horizon` steps over the full padded cache (program cost)
+        # every block runs max_slots rows for `horizon` steps. The paged
+        # programs and the dense contiguous one read the full padded
+        # cache: a constant cost. The contiguous `use_flash` program
+        # (`edl_decode_attn`) reads each slot's live S-blocks, so its
+        # blocks are priced one by one at dispatch (`_kv_read_share`);
+        # a dense read is one block of `max_len` a slot
         self._block_cost = self._cost.decode_block(
             max_slots, horizon, max_len
+        )
+        self._attn_block = (
+            _decode_attention.block_positions(
+                cfg.n_kv_heads, cfg.head_dim,
+                jnp.dtype(cfg.dtype).itemsize, max_len,
+            )
+            if cfg.use_flash and not self._paged else max_len
         )
         # speculative draft–verify (spec_k > 0): each verify dispatch
         # scores spec_k host-drafted tokens + the pending token in one
@@ -1316,8 +1328,14 @@ class ContinuousBatchingEngine:
         # span).
         # one list a block: the drain of this block reads it again
         rids = [s.rid for s in self._slots if s is not None]
-        with tracing.span("serving.dispatch", horizon=self.horizon,
-                          rids=rids):
+        attrs = {"horizon": self.horizon, "rids": rids}
+        cost = self._block_cost
+        if not self._paged:
+            share = attrs["kv_read_share"] = self._kv_read_share()
+            cost = self._cost.decode_block(
+                self.max_slots, self.horizon, share * self.max_len
+            )
+        with tracing.span("serving.dispatch", **attrs):
             if self._paged and self._ks is not None:
                 (toks, self._dtok, self._dpos, self._dact, self._drem,
                  self._kc, self._vc, self._ks, self._vs) = self._decode(
@@ -1361,8 +1379,23 @@ class ContinuousBatchingEngine:
             if s is not None and s.pf_next is None
         }
         self._inflight.append(
-            (toks, self.clock(), members, self._block_cost, None, rids)
+            (toks, self.clock(), members, cost, None, rids)
         )
+
+    def _kv_read_share(self) -> float:
+        """S-blocks of the contiguous cache the block about to be
+        dispatched fetches, over the blocks of the padded cache, from
+        the host's slot table: a slot holding ``len(prompt) +
+        len(generated)`` tokens is read up to the block that holds its
+        last one, an idle slot (fed ``pos = 0``) costs one block. 1.0
+        for the dense program, whose one block a slot is ``max_len``."""
+        blk = self._attn_block
+        fetched = sum(
+            1 if s is None else
+            -(-min(len(s.prompt) + len(s.generated), self.max_len) // blk)
+            for s in self._slots
+        )
+        return fetched / (self.max_slots * (self.max_len // blk))
 
     def _dispatch_verify(self, drafts: Dict[int, List[int]]) -> None:
         """One speculative verify dispatch: assemble the [B, D] draft

@@ -139,3 +139,84 @@ def test_flash_under_a_mesh_runs_per_shard(v5e):
     assert "tpu_custom_call" in compiled.as_text()
     with pytest.raises(NotImplementedError, match="shard_map"):
         jax.jit(lambda p, t: llama.forward(p, t, cfg)).lower(params, tokens)
+
+
+# -- the ragged decode attention (ops/decode_attention.py) -------------------
+
+# the two serving cells of BENCHMARK.json: DeepSeek-7B at 12 layers (MHA,
+# 16 slots) and Mistral-7B at 16 (GQA-8, groups 4, 32 slots), 2048 long
+DECODE_CELLS = {
+    "deepseek7b-L12": (12, 16, 2048, 32, 1),
+    "mistral7b-L16": (16, 32, 2048, 8, 4),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+def test_decode_attention_kernel_compiles_for_v5e(v5e, cell):
+    """The kernel alone at a cell's real cache: it compiles (tiling,
+    VMEM, the dynamic grid), takes the stacked cache as it is stored (the
+    [S, KV, hd] -> [S * KV, hd] view is a bitcast, nothing cache-sized
+    is a temporary) and is there under its name."""
+    from edl_tpu.ops.decode_attention import decode_attention
+
+    n_layers, b, s, kvh, groups = DECODE_CELLS[cell]
+    one = SingleDeviceSharding(v5e[0])
+    kc = _sds((n_layers, b, s, kvh, 128), jnp.bfloat16, one)
+    compiled = jax.jit(decode_attention).lower(
+        _sds((b, kvh, groups, 128), jnp.bfloat16, one), kc, kc,
+        _sds((b,), jnp.int32, one), _sds((), jnp.int32, one),
+    ).compile()
+    text = compiled.as_text()
+    assert "edl_decode_attn" in text and "tpu_custom_call" in text
+    one_layer = b * s * kvh * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 16
+
+
+def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
+    """``edl_serve_block`` at serve-open's shape (Mistral-7B widths, 16
+    layers, 32 slots x 2048, horizon 1, ``use_flash``): every layer's
+    attention is ``edl_decode_attn``, the cache updates in place, and no
+    operation produces a layer's worth of cache (the 32 ``slice``s of
+    ``bf16[32,2048,8,128]`` that were 42.5% of this program's time)."""
+    import re
+
+    from edl_tpu.serving import engine
+
+    one = SingleDeviceSharding(v5e[0])
+    cfg = llama.LlamaConfig(
+        vocab=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        d_ff=14336, rope_theta=1e6, norm_eps=1e-5, dtype=jnp.bfloat16,
+        use_flash=True, remat=False,
+    )
+    params = jax.eval_shape(
+        lambda: jax.tree_util.tree_map(
+            lambda x: x.astype(jnp.bfloat16),
+            llama.init_params(jax.random.PRNGKey(0), cfg),
+        )
+    )
+    params = jax.tree_util.tree_map(
+        lambda x: _sds(x.shape, x.dtype, one), params
+    )
+    b, s = 32, 2048
+    i32 = _sds((b,), jnp.int32, one)
+    kc = _sds((cfg.n_layers, b, s, cfg.n_kv_heads, cfg.head_dim),
+              cfg.dtype, one)
+    compiled = engine._block_program(cfg, b, s, 1, False).lower(
+        params, i32, i32, _sds((b,), jnp.bool_, one), i32, i32, kc, kc,
+        _sds((2,), jnp.uint32, one), _sds((), jnp.float32, one),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == cfg.n_layers
+    assert "edl_decode_attn" in text
+    # result shapes of every instruction: `%name = bf16[..]{layout} op(`
+    made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text)
+    layer = "bf16[32,2048,8,128]"
+    assert not [op for shape, op in made if shape == layer], (
+        "a layer of the cache is materialised")
+    whole = "bf16[16,32,2048,8,128]"
+    assert not [op for shape, op in made
+                if shape == whole and op in ("copy", "slice", "transpose")]
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * kc.size * 2
+    assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
+    assert mem.temp_size_in_bytes < cache_bytes // 8
