@@ -1,10 +1,11 @@
 """What every kind of cell shares: finding a cell's files by the names in
 ``BENCHMARK.json``, the chip gate, the compile cache, weights made on
-the device from the seed, the compile count of a window, and the
-numbers compared for ``correct``."""
+the device from the seed in the layout the cell's family gives, the
+compile count of a window, and the numbers compared for ``correct``."""
 
 from __future__ import annotations
 
+import functools
 import importlib
 import importlib.util
 import json
@@ -15,14 +16,6 @@ from typing import Any, Dict, List, Optional
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
-
-# tiny widths for --rehearse (CPU tests): the same keys as a published
-# config, so every code path reads them the same way
-REHEARSAL_CONFIG = {
-    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "num_hidden_layers": 2, "vocab_size": 256,
-    "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
-}
 
 
 class NoChip(RuntimeError):
@@ -71,6 +64,31 @@ class Cell:
             getattr(self, part)[field] = value
         self.kind = self.spec["kind"]
         self.limits = self.spec["limits"]
+        family = self.config.get("family")
+        if not family:
+            raise KeyError(f"{conf['file']} names no \"family\": the module "
+                           f"under benchmark/families/ that knows its shape")
+        self.family_file = os.path.join(here, "families", family + ".py")
+        if not os.path.exists(self.family_file):
+            raise FileNotFoundError(
+                f"{conf['file']} names the family {family!r}, and there is "
+                f"no {os.path.relpath(self.family_file, root)}")
+
+    @functools.cached_property
+    def family(self):
+        """The configuration's family module: parameter tree, program
+        config, engine and trainer hooks, reference, control and needed
+        bytes (benchmark/families/decoder.py says what each is). Loaded
+        at its first use, which in a run is the top of the kind's
+        set-up: after the chip gate, so the program's imports it brings
+        count in ``setup_s`` as they always did."""
+        return load_module(self.family_file)
+
+    @property
+    def layout(self) -> Dict:
+        """{path: (shape, std or None, stacked?)} of the parameter tree
+        at this cell's configuration."""
+        return self.family.param_layout(self.config)
 
     def reports(self, metric: Dict) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
@@ -87,8 +105,9 @@ class Cell:
                     else m["moves"] in e2e)]
 
     def for_rehearsal(self) -> None:
-        """Swap in tiny widths and the traffic file's rehearsal sizes."""
-        self.config = dict(REHEARSAL_CONFIG)
+        """Swap in the family's tiny widths and the traffic file's
+        rehearsal sizes."""
+        self.config = self.family.rehearsal_config()
         self.traffic.update(self.traffic.get("rehearse", {}))
         self.spec.update(self.spec.get("rehearse", {}))
         self.limits = self.spec["limits"]
@@ -212,48 +231,24 @@ def seed_key(seed: int):
         jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
 
 
-def param_layout(config: Dict) -> Dict:
-    """{path: (shape, std or None for a norm weight, stacked?)} of the
-    decoder's parameter tree, in the layout the program's model code
-    takes (layer weights stacked on a leading axis)."""
-    from benchmark.reference.decoder import dims
-
-    d, h, kv, hd, ff, L, V = dims(config)
-    out = {
-        ("embed",): ((V, d), 0.02, False),
-        ("ln_f",): ((d,), None, False),
-        ("lm_head",): ((d, V), d ** -0.5, False),
-    }
-    for name, shape, std in (
-        ("ln1", (d,), None), ("ln2", (d,), None),
-        ("wq", (d, h * hd), d ** -0.5), ("wk", (d, kv * hd), d ** -0.5),
-        ("wv", (d, kv * hd), d ** -0.5),
-        ("wo", (h * hd, d), (h * hd) ** -0.5),
-        ("w1", (d, ff), d ** -0.5), ("w3", (d, ff), d ** -0.5),
-        ("w2", (ff, d), ff ** -0.5),
-    ):
-        out[("layers", name)] = ((L,) + shape, std, True)
-    return out
-
-
-def layout_tree(config: Dict, leaf) -> Dict:
+def layout_tree(layout: Dict, leaf) -> Dict:
     """The parameter tree with ``leaf(path, shape, std, stacked)`` at
-    every path of :func:`param_layout`."""
-    tree: Dict = {"layers": {}}
-    for path, spec in param_layout(config).items():
+    every path of ``layout`` (a family's ``param_layout``): nested
+    dicts as deep as the paths are long."""
+    tree: Dict = {}
+    for path, spec in layout.items():
         node = tree
         for p in path[:-1]:
-            node = node[p]
+            node = node.setdefault(p, {})
         node[path[-1]] = leaf(path, *spec)
     return tree
 
 
-def initial_leaf(key, config: Dict, path, dtype):
+def initial_leaf(key, layout: Dict, path, dtype):
     """The seed's draw of one leaf; traced inside a jitted program."""
     import jax
     import jax.numpy as jnp
 
-    layout = param_layout(config)
     shape, std, stacked = layout[tuple(path)]
     if std is None:
         return jnp.ones(shape, dtype)
@@ -270,31 +265,15 @@ def initial_leaf(key, config: Dict, path, dtype):
                        jax.random.split(key, shape[0]))
 
 
-def make_params(seed: int, config: Dict, dtype, shardings=None):
+def make_params(seed: int, layout: Dict, dtype, shardings=None):
     """The whole tree in one jitted call, on the device, in ``dtype``."""
     import jax
 
     def build(key):
         return layout_tree(
-            config, lambda path, *_: initial_leaf(key, config, path, dtype))
+            layout, lambda path, *_: initial_leaf(key, layout, path, dtype))
 
     return jax.jit(build, out_shardings=shardings)(seed_key(seed))
-
-
-def model_config(config: Dict, *, training: bool, int8: bool = False):
-    """The program's LlamaConfig for a published config."""
-    import jax.numpy as jnp
-
-    from benchmark.reference.decoder import dims
-    from edl_tpu.models import llama
-
-    d, h, kv, _, ff, L, V = dims(config)
-    return llama.LlamaConfig(
-        vocab=V, d_model=d, n_layers=L, n_heads=h, n_kv_heads=kv, d_ff=ff,
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]), dtype=jnp.bfloat16,
-        use_flash=True, remat=training, int8_mxu=int8 and training,
-    )
 
 
 # -- correct ----------------------------------------------------------------

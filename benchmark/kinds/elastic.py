@@ -153,7 +153,7 @@ class Kind(train.Kind):
             return NamedSharding(mesh, P(*spec))
 
         tree = harness.layout_tree(
-            self.cell.config, lambda path, shape, *_: place(shape))
+            self.cell.layout, lambda path, shape, *_: place(shape))
         return tree, NamedSharding(mesh, P("g"))
 
     def check(self, compared) -> None:

@@ -1,5 +1,6 @@
-"""Kind ``serve``: one ``ContinuousBatchingEngine`` with ``edl serve``'s
-defaults (contiguous KV, horizon 1), driven by one thread.
+"""Kind ``serve``: the engine the cell's family builds (for the decoder
+one ``ContinuousBatchingEngine`` with ``edl serve``'s defaults: contiguous
+KV, horizon 1), driven by one thread.
 
 The traffic file's ``loop`` says how: ``open`` submits each request when
 it is due and times it from then, whatever the server is doing;
@@ -20,10 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark import harness
-from benchmark.reference import decoder
 from benchmark.traffic import generate
 from edl_tpu.obs.metrics import MetricsRegistry
-from edl_tpu.serving.engine import ContinuousBatchingEngine
 from edl_tpu.serving.metrics import ServingMetrics
 from edl_tpu.serving.scheduler import AdmissionError
 
@@ -71,23 +70,19 @@ class Kind:
     # -- set-up -------------------------------------------------------------
 
     def setup(self) -> None:
-        from edl_tpu.models import llama
-
-        ctx, eng = self.ctx, self.cell.spec["engine"]
-        self.model_cfg = harness.model_config(
+        ctx, family = self.ctx, self.cell.family
+        self.model_cfg = family.program_config(
             self.cell.config, training=False)
         self.params = harness.make_params(
-            ctx.seed, self.cell.config, jnp.bfloat16)
+            ctx.seed, self.cell.layout, jnp.bfloat16)
         served = self.params
         if ctx.control:
-            # one program, so no float32 copy of a leaf is ever whole;
             # the bf16 tree goes, and check() draws it again
-            served = jax.jit(llama.quantize_params_int8)(self.params)
+            served = family.control_params(self.params)
             self.params = None
         self.metrics = RecordingMetrics()
-        self.engine = ContinuousBatchingEngine(
-            served, self.model_cfg, max_slots=int(eng["max_slots"]),
-            max_len=int(eng["max_len"]), metrics=self.metrics)
+        self.engine = family.engine(
+            served, self.model_cfg, self.cell.spec["engine"], self.metrics)
         self.clock = self.engine.clock
         self.warm()
 
@@ -283,21 +278,22 @@ class Kind:
         compared.add("engine_recoveries",
                      float(self.counters["recoveries"]), 0.0)
         max_len = int(self.cell.spec["engine"]["max_len"])
-        config = self.cell.config
+        config, reference_logits = (
+            self.cell.config, self.cell.family.reference_logits)
 
         @jax.jit
         def gaps(params, tokens, served):
             # how far each served token's logit lies under the
             # reference's best at its position; ``served`` is -1 where
             # the position holds no served token
-            lg = decoder.logits_row(params, tokens, config)
+            lg = reference_logits(params, tokens, config)
             at = jnp.take_along_axis(
                 lg, jnp.maximum(served, 0)[:, None], 1)[:, 0]
             return jnp.where(served >= 0, jnp.max(lg, axis=-1) - at, 0.0)
 
         if self.params is None:
             self.params = harness.make_params(
-                self.ctx.seed, config, jnp.bfloat16)
+                self.ctx.seed, self.cell.layout, jnp.bfloat16)
         t0 = time.perf_counter()
         all_gaps: List[float] = []
         picked = self.sample()

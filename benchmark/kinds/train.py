@@ -19,7 +19,6 @@ import numpy as np
 import optax
 
 from benchmark import harness
-from benchmark.reference import decoder
 
 
 class Kind:
@@ -67,40 +66,40 @@ class Kind:
 
     def make_trainer(self):
         from edl_tpu.api.job import MeshSpec
-        from edl_tpu.models import llama
         from edl_tpu.runtime.elastic import ElasticTrainer
 
-        cfg = harness.model_config(
-            self.cell.config, training=True, int8=self.ctx.control)
+        family = self.cell.family
+        cfg = family.program_config(
+            self.cell.config, training=True, control=self.ctx.control)
         self.model_cfg = cfg
         return ElasticTrainer(
             None,
             optax.adafactor(self.lr),
             mesh_spec=MeshSpec(**self.cell.spec.get("mesh", {})),
             per_chip_batch=self.per_chip_batch,
-            param_pspecs=lambda plan: llama.param_pspecs(cfg, plan),
-            make_loss=lambda plan, mesh: llama.make_loss_fn(cfg, plan, mesh),
+            param_pspecs=lambda plan: family.param_pspecs(cfg, plan),
+            make_loss=lambda plan, mesh: family.make_loss(cfg, plan, mesh),
             devices=self.ctx.devices,
         )
 
     def param_shardings(self):
         """Where the trainer will keep each parameter on its first mesh,
         so the weights are made in place and never whole on one chip."""
-        from edl_tpu.models import llama
         from edl_tpu.parallel import sharding as shd
         from edl_tpu.parallel.mesh import MeshPlan
 
         tr = self.trainer
         plan = MeshPlan.from_spec(tr.mesh_spec, len(tr.pool))
         mesh = plan.build(tr.pool)
-        return shd.named(llama.param_pspecs(self.model_cfg, plan), mesh)
+        return shd.named(
+            self.cell.family.param_pspecs(self.model_cfg, plan), mesh)
 
     def setup(self) -> None:
         ctx = self.ctx
         self.trainer = self.make_trainer()
         t0 = time.perf_counter()
         params = harness.make_params(
-            ctx.seed, self.cell.config, jnp.float32, self.param_shardings())
+            ctx.seed, self.cell.layout, jnp.float32, self.param_shardings())
         jax.block_until_ready(params)
         t1 = time.perf_counter()
         self.trainer.start(params, n_workers=len(ctx.devices))
@@ -131,13 +130,13 @@ class Kind:
         """Sum of squares of (params - the seed's initial draw) per leaf,
         in one program: each initial leaf is drawn again inside it, used
         and dropped, so no second copy of the tree is held."""
-        config = self.cell.config
+        layout = self.cell.layout
 
         def diff(key, params):
             return {
                 jax.tree_util.keystr(path): jnp.sum(jnp.square(
                     leaf - harness.initial_leaf(
-                        key, config, [k.key for k in path], jnp.float32)))
+                        key, layout, [k.key for k in path], jnp.float32)))
                 for path, leaf in
                 jax.tree_util.tree_flatten_with_path(params)[0]}
 
@@ -202,8 +201,8 @@ class Kind:
         ]
         t0 = time.perf_counter()
         params = harness.make_params(
-            ctx.seed, self.cell.config, jnp.float32, p_sh)
-        losses, grad_sumsq, params = decoder.train_steps(
+            ctx.seed, self.cell.layout, jnp.float32, p_sh)
+        losses, grad_sumsq, params = self.cell.family.reference_train_steps(
             params, batches, self.cell.config, self.lr, p_sh, t_sh)
         change = self.change_sumsq(params)
         del params

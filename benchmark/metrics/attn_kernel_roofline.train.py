@@ -3,7 +3,7 @@ traced over (time in the Mosaic kernels x the chip's bf16 peak). Bound:
 compute. The forward that remat runs a second time is in the time and
 not in the FLOPs, as for any utilization here."""
 
-from benchmark.reduce import needed, peaks
+from benchmark.reduce import peaks
 
 
 def read(run):
@@ -13,5 +13,6 @@ def read(run):
     seq = run["cell"].traffic["seq"]
     tokens = tr["modules_run"] * run["counters"]["tokens_per_step_per_chip"]
     flops, _ = peaks.peak(run["device"]["kind"])
+    needed = run["cell"].family.needed
     need = needed.attention_train_flops_per_token(run["config"], seq) * tokens
     return 100.0 * need / (tr["mosaic_s"] * flops)

@@ -8,7 +8,7 @@ program wastes more."""
 
 import statistics
 
-from benchmark.reduce import needed, peaks
+from benchmark.reduce import peaks
 
 
 def read(run):
@@ -16,6 +16,6 @@ def read(run):
     if not gaps or run["device"]["platform"] != "tpu":
         return None
     _, bw = peaks.peak(run["device"]["kind"])
-    need = needed.decode_step_bytes(
+    need = run["cell"].family.needed.decode_step_bytes(
         run["config"], run["counters"]["resident_tokens_mean"])
     return 100.0 * need / (statistics.median(gaps) * bw)

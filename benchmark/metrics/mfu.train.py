@@ -2,7 +2,7 @@
 peak: an end-to-end utilization (recomputed work is not counted), not a
 kernel's share."""
 
-from benchmark.reduce import needed, peaks
+from benchmark.reduce import peaks
 
 
 def read(run):
@@ -11,4 +11,5 @@ def read(run):
         return None
     seq = run["cell"].traffic["seq"]
     flops, _ = peaks.peak(run["device"]["kind"])
+    needed = run["cell"].family.needed
     return 100.0 * needed.train_flops_per_token(run["config"], seq) * rate / flops

@@ -1,4 +1,8 @@
-"""Operations and bytes the algorithm needs, from shapes alone.
+"""Operations and bytes a dense decoder needs, from shapes alone: the
+``needed`` of the family ``decoder`` (benchmark/families/decoder.py), and
+reached by the readers only through ``run["cell"].family.needed``, so
+that a family whose step reads the top-k of its experts, or whose cache
+is a window in some layers, prices its own step.
 
 These are the numerators of every utilization the benchmark reports.
 They count what the mathematics requires, not what a program happens to
@@ -11,7 +15,14 @@ not copied.
 
 from typing import Dict
 
-from benchmark.reference.decoder import dims
+
+def dims(config: Dict):
+    """(d, heads, kv heads, head dim, ff, layers, vocab) of a published
+    ``config.json`` (the reference reads the same keys by itself)."""
+    d, h = config["hidden_size"], config["num_attention_heads"]
+    return (d, h, config["num_key_value_heads"], d // h,
+            config["intermediate_size"], config["num_hidden_layers"],
+            config["vocab_size"])
 
 
 def matmul_params(config: Dict) -> int:

@@ -12,9 +12,11 @@ import numpy as np
 import pytest
 
 from benchmark import harness
+from benchmark.families import decoder as family
 from benchmark.reference import decoder
 
-CONFIG = dict(harness.REHEARSAL_CONFIG)
+CONFIG = family.rehearsal_config()
+LAYOUT = family.param_layout(CONFIG)
 
 
 def limits(cell):
@@ -27,7 +29,7 @@ def training_readings(seed, dtype):
     """(worst loss gap, gradient norm gap, change norm gap) of the
     reference run with operands rounded to ``dtype`` against itself."""
     def follow(rounded):
-        params = harness.make_params(seed, CONFIG, jnp.float32)
+        params = harness.make_params(seed, LAYOUT, jnp.float32)
         start = jax.tree_util.tree_map(lambda a: a + 0, params)
         rng = np.random.default_rng(seed)
         batches = [jnp.asarray(rng.integers(0, 256, (1, 2, 129), dtype=np.int32))
@@ -63,7 +65,7 @@ def test_training_control_fails_and_the_stated_precision_passes(seed):
 def served_gaps(seed, dtype):
     """At each position of one sequence, how far the token that the
     lower precision puts first lies under the reference's best."""
-    params = harness.make_params(seed, CONFIG, jnp.float32)
+    params = harness.make_params(seed, LAYOUT, jnp.float32)
     tokens = jnp.asarray(
         np.random.default_rng(seed).integers(0, 256, (64,), dtype=np.int32))
     ref = decoder.logits_row(params, tokens, CONFIG)

@@ -1,6 +1,9 @@
 """BENCHMARK.json and the files it names: everything loads, names only
-what is declared, and a cell, a configuration and a per-layer metric are
-added as new files, with no edit to a file that is there."""
+what is declared, every configuration keeps the widths its source
+publishes (benchmark/published/) and cuts only what its family allows,
+and a cell, a configuration, a per-layer metric and a whole family are
+added as new files (tests/benchmark/later_pr/), with no edit to a file
+that is there."""
 
 import glob
 import json
@@ -14,18 +17,45 @@ from benchmark import harness
 
 BENCH = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-WIDTHS = ("hidden_size", "intermediate_size", "num_attention_heads",
-          "num_key_value_heads", "vocab_size", "rope_theta", "rms_norm_eps")
-PUBLISHED = {
-    "mistralai/Mistral-7B-v0.3": dict(
-        hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
-        num_key_value_heads=8, vocab_size=32768, rope_theta=1e6,
-        rms_norm_eps=1e-5, layers=32),
-    "deepseek-ai/deepseek-llm-7b-base": dict(
-        hidden_size=4096, intermediate_size=11008, num_attention_heads=32,
-        num_key_value_heads=32, vocab_size=102400, rope_theta=1e4,
-        rms_norm_eps=1e-6, layers=30),
-}
+LATER_PR = os.path.join(os.path.dirname(__file__), "later_pr")
+
+
+def published_for(source, root=harness.ROOT):
+    """The one file under benchmark/published/ that records ``source``."""
+    found = [p for p in glob.glob(
+        os.path.join(root, "benchmark", "published", "*.json"))
+        if harness.load_json(p)["source"] == source]
+    assert len(found) == 1, (source, found)
+    return harness.load_json(found[0])
+
+
+def size(value):
+    """A list (a pattern with one entry a layer) is as large as it is
+    long."""
+    return len(value) if isinstance(value, list) else value
+
+
+def check_configuration(entry, bench, root=harness.ROOT):
+    """A configuration keeps its source's widths, and what it cuts is
+    what its family allows, no further than its floor, and listed."""
+    assert NAME.match(entry["name"]) and len(entry["why"]) <= 200
+    conf = harness.load_json(os.path.join(root, entry["file"]))
+    assert conf["source"] == entry["source"]
+    pub = published_for(entry["source"], root)
+    family = harness.load_module(os.path.join(
+        root, "benchmark", "families", conf["family"] + ".py"))
+    for key in family.widths:
+        assert conf[key] == pub[key], key
+    assert entry["reduced"] == sorted(conf["reduced"])
+    assert set(entry["reduced"]) <= set(family.reducible)
+    for key, floor in family.reducible.items():
+        if key in entry["reduced"]:
+            assert conf["published"][key] == pub[key], key
+            assert floor <= size(conf[key]) < size(pub[key]), key
+        else:
+            assert conf[key] == pub[key], (key, "cut and not listed")
+    assert "assumed" in conf
+    assert any(w["config"] == entry["name"] for w in bench["workloads"])
 
 
 def test_top_level_keys_and_limits():
@@ -42,17 +72,20 @@ def test_top_level_keys_and_limits():
 
 @pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
 def test_configuration_keeps_published_widths(entry):
-    assert NAME.match(entry["name"]) and len(entry["why"]) <= 200
-    conf = harness.load_json(os.path.join(harness.ROOT, entry["file"]))
-    pub = next(v for k, v in PUBLISHED.items() if k in entry["source"])
-    assert conf["source"] == entry["source"]
-    for key in WIDTHS:
-        assert conf[key] == pub[key], key
-    assert entry["reduced"] == sorted(conf["reduced"]) == ["num_hidden_layers"]
-    assert conf["published"]["num_hidden_layers"] == pub["layers"]
-    assert conf["num_hidden_layers"] < pub["layers"]
-    assert "assumed" in conf
-    assert any(w["config"] == entry["name"] for w in BENCH["workloads"])
+    check_configuration(entry, BENCH)
+
+
+def test_the_two_sources_that_are_there_are_cut_in_depth_alone():
+    """What the closed table of PR 23 asserted beyond the checks above,
+    of the files that took its place."""
+    layers = {"mistralai/Mistral-7B-v0.3": 32,
+              "deepseek-ai/deepseek-llm-7b-base": 30}
+    for entry in BENCH["configs"]:
+        repo = next((k for k in layers if k in entry["source"]), None)
+        if repo is not None:
+            assert entry["reduced"] == ["num_hidden_layers"]
+            assert published_for(entry["source"])["num_hidden_layers"] \
+                == layers[repo]
 
 
 @pytest.mark.parametrize("entry", BENCH["workloads"], ids=lambda w: w["name"])
@@ -88,72 +121,148 @@ def test_per_layer_metric_has_a_reader_and_names_declared_things(metric):
 
 
 def test_every_file_is_named_and_used():
-    named = {os.path.basename(c["file"]) for c in BENCH["configs"]}
-    assert {os.path.basename(p) for p in glob.glob(
-        os.path.join(harness.ROOT, "benchmark", "configs", "*.json"))} == named
-    cells = {w["name"] + ".json" for w in BENCH["workloads"]}
-    assert {os.path.basename(p) for p in glob.glob(
-        os.path.join(harness.ROOT, "benchmark", "workloads", "*.json"))} == cells
-    readers = {m["name"] + ".py" for m in BENCH["per_layer"]}
-    assert {os.path.basename(p) for p in glob.glob(
-        os.path.join(harness.ROOT, "benchmark", "metrics", "*.py"))} == readers
+    def files(directory, pattern):
+        return {os.path.basename(p) for p in glob.glob(
+            os.path.join(harness.ROOT, "benchmark", directory, pattern))}
+
+    confs = [harness.load_json(os.path.join(harness.ROOT, c["file"]))
+             for c in BENCH["configs"]]
+    assert files("configs", "*.json") == {
+        os.path.basename(c["file"]) for c in BENCH["configs"]}
+    assert files("workloads", "*.json") == {
+        w["name"] + ".json" for w in BENCH["workloads"]}
+    assert files("metrics", "*.py") == {
+        m["name"] + ".py" for m in BENCH["per_layer"]}
+    assert files("families", "*.py") - {"__init__.py"} == {
+        c["family"] + ".py" for c in confs}
+    sources = {c["source"] for c in BENCH["configs"]}
+    assert {harness.load_json(os.path.join(
+        harness.ROOT, "benchmark", "published", f))["source"]
+        for f in files("published", "*.json")} == sources
+    assert len(files("published", "*.json")) == len(sources)
 
 
-def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files(tmp_path):
-    """What a later PR does: copy nothing, edit nothing, add files and
-    entries. The harness finds all three by name."""
+def later_pr_tree(tmp_path):
+    """A copy of benchmark/ with a later PR's files laid over it and its
+    entries appended to a copy of BENCHMARK.json. Returns (root, the new
+    BENCHMARK.json, {path: bytes} of every file that was there)."""
     root = str(tmp_path)
     shutil.copytree(os.path.join(harness.ROOT, "benchmark"),
                     os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
     before = {p: open(p, "rb").read() for p in glob.glob(
         os.path.join(root, "benchmark", "**", "*.*"), recursive=True)}
-    conf = harness.load_json(os.path.join(
-        root, "benchmark", "configs", "deepseek7b-L12.json"))
-    conf["num_hidden_layers"] = 6
-    with open(os.path.join(root, "benchmark", "configs", "deepseek7b-L6.json"), "w") as f:
-        json.dump(conf, f)
-    with open(os.path.join(root, "benchmark", "traffic", "decode-short.json"), "w") as f:
-        json.dump({"what": "short answers", "loop": "closed", "clients": 4,
-                   "cycle": 8,
-                   "prompt": {"median": 64, "sigma": 0.5, "lo": 16, "hi": 128},
-                   "output": {"median": 32, "sigma": 0.5, "lo": 8, "hi": 64}}, f)
-    with open(os.path.join(root, "benchmark", "workloads", "deepseek7b.decode-short.json"), "w") as f:
-        json.dump({"kind": "serve", "why": "a later PR's cell",
-                   "engine": {"max_slots": 4, "max_len": 256},
-                   "check_requests": 2,
-                   "limits": {"served_token_gap_max": 1.0,
-                              "served_token_gap_mean": 1.0}}, f)
-    with open(os.path.join(root, "benchmark", "metrics", "steps_per_token.py"), "w") as f:
-        f.write("def read(run):\n"
-                "    c = run['counters']\n"
-                "    return c['engine_steps'] / c['tokens'] if c.get('tokens') else None\n")
+    added = glob.glob(os.path.join(LATER_PR, "benchmark", "**", "*.*"),
+                      recursive=True)
+    for p in added:
+        to = os.path.join(root, os.path.relpath(p, LATER_PR))
+        assert not os.path.exists(to), f"{to} is there: a later PR adds"
+        shutil.copy(p, to)
     bench = json.loads(json.dumps(BENCH))
-    bench["configs"].append({
-        "name": "deepseek7b-L6", "source": conf["source"],
-        "file": "benchmark/configs/deepseek7b-L6.json",
-        "reduced": ["num_hidden_layers"], "why": "a later PR's configuration"})
-    bench["workloads"].append({
-        "name": "deepseek7b.decode-short", "config": "deepseek7b-L6",
-        "traffic": "decode-short", "chips": 1, "why": "a later PR's cell"})
+    entries = harness.load_json(os.path.join(LATER_PR, "entries.json"))
+    for section, more in entries.items():
+        bench[section].extend(more)
     for m in bench["end_to_end"]:
         if m["name"] == "serve_tokens_per_s":
-            m["workloads"].append("deepseek7b.decode-short")
-    bench["per_layer"].append({
-        "name": "steps_per_token", "unit": "steps/token", "better": "lower",
-        "source": "program_counter", "layer": "serving engine",
-        "moves": "serve_tokens_per_s",
-        "workloads": ["deepseek7b.decode-short"]})
+            m["workloads"] += [w["name"] for w in entries["workloads"]]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
+    return root, bench, before
 
+
+def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files(tmp_path):
+    """What a later PR does: copy nothing, edit nothing, add files and
+    entries. The harness finds all three by name."""
+    root, bench, before = later_pr_tree(tmp_path)
     cell = harness.Cell("deepseek7b.decode-short", root=root)
     assert cell.config["num_hidden_layers"] == 6 and cell.kind == "serve"
+    assert cell.family.__file__ == os.path.join(
+        root, "benchmark", "families", "decoder.py")
     assert [m["name"] for m in cell.per_layer()] == ["steps_per_token"]
     run = {"counters": {"engine_steps": 30, "tokens": 60}}
     assert harness.read_per_layer(cell, run) == {
         "steps_per_token": {"value": 0.5, "unit": "steps/token"}}
     # a reader that finds nothing to read is left out of the line
     assert harness.read_per_layer(cell, {"counters": {}}) == {}
+    check_configuration(bench["configs"][-2], bench, root)
     for p, data in before.items():
         assert open(p, "rb").read() == data, f"{p} was edited"
+
+
+def test_a_family_is_added_as_new_files(tmp_path):
+    """The proof a later ``model_config`` PR rests on: a family whose
+    parameter tree is not the decoder's, its published file, a
+    configuration reduced in two keys and a cell on it load, rehearse
+    and are priced by the family's own ``needed``, with no edit to a
+    file that was there."""
+    import jax
+    import jax.numpy as jnp
+
+    root, bench, before = later_pr_tree(tmp_path)
+    entry = next(c for c in bench["configs"] if c["name"] == "sliced24-L6-V8k")
+    assert entry["reduced"] == ["num_hidden_layers", "vocab_size"]
+    check_configuration(entry, bench, root)
+
+    cell = harness.Cell("sliced24.decode-short", root=root)
+    family = cell.family
+    assert family.__file__ == os.path.join(
+        root, "benchmark", "families", "sliced.py")
+    assert cell.config["vocab_size"] == 8192 and "deployment" in cell.config
+    assert cell.layout[("global", "mix")] == ((2, 512, 512), 512 ** -0.5, True)
+    assert family.program_config(cell.config, training=False).layers == (4, 2)
+
+    # a reader that asks the cell's family prices the family's own step
+    run = {"cell": cell, "config": cell.config,
+           "counters": {"engine_steps": 30, "tokens": 60,
+                        "resident_tokens_mean": 100.0}}
+    need = 2 * (4 * 2 * 512 * 2048 + 2 * 512 * 512 + 512 * 8192) \
+        + 100 * 2 * 512 * 2
+    assert harness.read_per_layer(cell, run) == {
+        "steps_per_token": {"value": 0.5, "unit": "steps/token"},
+        "needed_step_mb": {"value": need / 1e6, "unit": "MB"}}
+    decoder_need = harness.Cell("deepseek7b.decode-short", root=root) \
+        .family.needed.decode_step_bytes(cell.config | {
+            "num_attention_heads": 4, "num_key_value_heads": 4}, 100.0)
+    assert decoder_need != need
+
+    # the rehearsal swaps in the new family's tiny widths, and the
+    # generic machinery builds its tree: three levels deep, two stacked
+    # groups of different leading lengths
+    cell.for_rehearsal()
+    assert cell.config == family.rehearsal_config()
+    params = harness.make_params(5, cell.layout, jnp.float32)
+    shapes = {jax.tree_util.keystr(p): leaf.shape for p, leaf in
+              jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert shapes == {
+        "['embed']": (128, 32), "['head']['norm']": (32,),
+        "['head']['out']": (32, 128), "['global']['mix']": (3, 32, 32),
+        "['local']['mlp']['up']": (3, 32, 64),
+        "['local']['mlp']['down']": (3, 64, 32), "['local']['norm']": (3, 32)}
+    assert bool(jnp.all(params["local"]["norm"] == 1.0))
+    up = params["local"]["mlp"]["up"]
+    assert not bool(jnp.all(up[0] == up[1])), "each layer has its own draw"
+    assert float(jnp.std(up)) == pytest.approx(32 ** -0.5, rel=0.05)
+    again = harness.make_params(5, cell.layout, jnp.float32)
+    assert bool(jnp.all(again["global"]["mix"] == params["global"]["mix"]))
+    # one leaf drawn alone is the tree's (what change_sumsq relies on)
+    alone = jax.jit(lambda k: harness.initial_leaf(
+        k, cell.layout, ["global", "mix"], jnp.float32))(harness.seed_key(5))
+    assert bool(jnp.all(alone == params["global"]["mix"]))
+
+    for p, data in before.items():
+        assert open(p, "rb").read() == data, f"{p} was edited"
+
+
+def test_a_configuration_without_a_family_is_an_error_that_says_so(tmp_path):
+    root, bench, _ = later_pr_tree(tmp_path)
+    path = os.path.join(root, "benchmark", "configs", "sliced24-L6-V8k.json")
+    conf = harness.load_json(path)
+    with open(path, "w") as f:
+        json.dump(dict(conf, family="absent"), f)
+    with pytest.raises(FileNotFoundError, match="benchmark/families/absent.py"):
+        harness.Cell("sliced24.decode-short", root=root)
+    del conf["family"]
+    with open(path, "w") as f:
+        json.dump(conf, f)
+    with pytest.raises(KeyError, match="names no .family."):
+        harness.Cell("sliced24.decode-short", root=root)

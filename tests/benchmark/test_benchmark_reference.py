@@ -9,9 +9,11 @@ import numpy as np
 import pytest
 
 from benchmark import harness
+from benchmark.families import decoder as family
 from benchmark.reference import decoder
 
-CONFIG = dict(harness.REHEARSAL_CONFIG)
+CONFIG = family.rehearsal_config()
+LAYOUT = family.param_layout(CONFIG)
 # float32 against float32 at highest precision: what separates them is
 # the order of summation. bfloat16 activations miss this by two orders.
 LOGIT_TOLERANCE = 2e-4
@@ -19,7 +21,7 @@ LOGIT_TOLERANCE = 2e-4
 
 @pytest.fixture(scope="module")
 def params():
-    return harness.make_params(11, CONFIG, jnp.float32)
+    return harness.make_params(11, LAYOUT, jnp.float32)
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +33,7 @@ def program_logits(params, row, dtype):
     from edl_tpu.models import llama
 
     cfg = dataclasses.replace(
-        harness.model_config(CONFIG, training=False), dtype=dtype,
+        family.program_config(CONFIG, training=False), dtype=dtype,
         use_flash=False)
     with jax.default_matmul_precision("highest"):
         return llama.forward(params, row[None], cfg)[0]
@@ -55,7 +57,7 @@ def test_reference_loss_is_the_programs_loss_in_float32(params, tokens):
     from edl_tpu.models import llama
 
     cfg = dataclasses.replace(
-        harness.model_config(CONFIG, training=False), dtype=jnp.float32,
+        family.program_config(CONFIG, training=False), dtype=jnp.float32,
         use_flash=False)
     rows = tokens.reshape(4, 41)
     with jax.default_matmul_precision("highest"):
@@ -74,8 +76,8 @@ def test_layer_at_a_time_gradient_is_the_gradient(params, tokens):
 
 
 def test_weights_are_the_seeds(params):
-    again = harness.make_params(11, CONFIG, jnp.float32)
-    other = harness.make_params(2 ** 31 + 11, CONFIG, jnp.float32)
+    again = harness.make_params(11, LAYOUT, jnp.float32)
+    other = harness.make_params(2 ** 31 + 11, LAYOUT, jnp.float32)
     assert bool(jnp.all(again["layers"]["w1"] == params["layers"]["w1"]))
     assert not bool(jnp.all(other["embed"] == params["embed"]))
 
