@@ -888,11 +888,22 @@ def run_job_status(args) -> int:
         cl.close()
 
 
-def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool):
-    """Load a published llama export for a decoding consumer — shared
-    by ``edl generate`` and ``edl serve``. ``mesh_arg`` (MeshPlan
+# export family -> (model module, config class): what ``edl serve`` can
+# put behind its engine. ``edl generate`` decodes the dense decoder alone.
+_SERVED_FAMILIES = {
+    "llama": ("edl_tpu.models.llama", "LlamaConfig"),
+    "deepseek_v3": ("edl_tpu.models.deepseek_v3", "DeepseekV3Config"),
+}
+
+
+def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool,
+                        families=("llama",)):
+    """Load a published export for a decoding consumer — shared by
+    ``edl generate`` and ``edl serve`` (which also takes the
+    ``deepseek_v3`` family: ``families``). ``mesh_arg`` (MeshPlan
     grammar) loads the params SHARDED with the training layout so
-    exports bigger than one chip's HBM serve at all; ``int8`` quantizes
+    exports bigger than one chip's HBM serve at all (the dense decoder
+    alone); ``int8`` quantizes
     to the weight-only records. Returns (params, cfg) or (None, errmsg)
     — the caller prints errmsg and exits 1. Imports jax lazily so the
     device-free CLI verbs never pull it in."""
@@ -906,23 +917,30 @@ def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool):
     if doc is None:
         return None, f"no published export under {export_dir}"
     model = doc.get("model") or {}
-    if model.get("family") != "llama":
+    family = model.get("family")
+    if family not in families:
         return None, (
             f"export has no llama architecture record "
             f"(model={model or None}); re-export with model_meta "
             f"(LlamaConfig.to_meta())"
         )
+    if mesh_arg and family != "llama":
+        return None, f"--mesh shards the dense decoder alone, not {family}"
     if int8 and mesh_arg:
         # the int8 records carry no pspecs; sharded serving keeps the
         # training layout instead of re-deriving one for q8/s8 — and
         # the check must precede the (multi-GB) load it would waste
         return None, "--int8 and --mesh are mutually exclusive"
+    import importlib
+
     import jax
 
-    from edl_tpu.models import llama
     from edl_tpu.utils import jaxcache
 
     jaxcache.configure()
+    module, config = _SERVED_FAMILIES[family]
+    module = importlib.import_module(module)
+    config = getattr(module, config)
 
     if mesh_arg:
         from edl_tpu.parallel.mesh import MeshPlan
@@ -939,8 +957,8 @@ def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool):
             params, doc = load_export_sharded(
                 export_dir,
                 mesh,
-                lambda d: llama.param_pspecs(
-                    llama.LlamaConfig.from_meta(d["model"]), plan
+                lambda d: module.param_pspecs(
+                    config.from_meta(d["model"]), plan
                 ),
             )
         except ValueError as e:  # raced into a non-llama export
@@ -949,13 +967,13 @@ def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool):
     else:
         params, doc = load_export(export_dir)
     try:
-        cfg = llama.LlamaConfig.from_meta(doc.get("model") or {})
+        cfg = config.from_meta(doc.get("model") or {})
     except ValueError as e:
         return None, f"export changed mid-load: {e}"
     if int8:
         # weight-only int8: halves decode's weight-bandwidth bill
-        # (models/llama.py quantize_params_int8; bench decode_int8_*)
-        params = jax.jit(llama.quantize_params_int8)(params)
+        # (the model's quantize_params_int8; bench decode_int8_*)
+        params = jax.jit(module.quantize_params_int8)(params)
     return params, cfg
 
 
@@ -1147,7 +1165,8 @@ def run_serve(args) -> int:
         print(f"bad request feed: {e}", file=sys.stderr)
         return 1
     params, cfg_or_err = _load_llama_serving(
-        args.export_dir, args.mesh, args.int8
+        args.export_dir, args.mesh, args.int8,
+        families=tuple(_SERVED_FAMILIES),
     )
     if params is None:
         print(cfg_or_err, file=sys.stderr)

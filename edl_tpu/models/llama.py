@@ -125,6 +125,36 @@ class LlamaConfig:
             use_flash=bool(meta.get("use_flash", False)),
         )
 
+    # -- what ``serving/engine.py`` asks a config it serves from the
+    # contiguous cache (its docstring gives the contract); here the
+    # answers are the programs the engine always ran
+
+    def serve_cache_spec(self, slots: int, max_len: int):
+        """Two arrays, keys and values: [L, slots, max_len, KV, hd]."""
+        shape = (self.n_layers, slots, max_len, self.n_kv_heads, self.head_dim)
+        return ((shape, self.dtype), (shape, self.dtype))
+
+    def serve_prefill(self, params: Dict, tokens, last):
+        logits, ks, vs = prefill_padded(params, tokens, last, self)
+        return logits, (ks, vs)
+
+    def serve_decode_block(self, params, tok, pos, active, rem, eosv, cache,
+                           **kw):
+        toks, tok, pos, active, rem, kc, vc = decode_horizon_slots(
+            params, tok, pos, active, rem, eosv, *cache, self, **kw)
+        return toks, tok, pos, active, rem, (kc, vc), {}
+
+    def serve_attn_block(self, max_len: int) -> int:
+        """Positions of one S-block ``edl_decode_attn`` fetches; the
+        dense read is one block of ``max_len`` a slot."""
+        if not self.use_flash:
+            return max_len
+        from edl_tpu.ops.decode_attention import block_positions
+
+        return block_positions(
+            self.n_kv_heads, self.head_dim, jnp.dtype(self.dtype).itemsize,
+            max_len)
+
     @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
         return cls()
@@ -836,11 +866,33 @@ def decode_horizon_slots(
     ``sampling`` (static) draws from ``logits / temperature`` with a
     per-step key split from ``key``; greedy ignores both."""
 
-    def step(carry, k):
-        tok, pos, active, rem, kc, vc = carry
+    def step(tok, pos, cache, active):
         logits, kc, vc = decode_step_slots(
-            params, tok, pos, kc, vc, cfg, live=active
+            params, tok, pos, *cache, cfg, live=active
         )
+        return logits, (kc, vc), ()
+
+    toks, tok, pos, active, rem, (kc, vc), _ = horizon_scan(
+        step, tok, pos, active, rem, eosv, (kc, vc), horizon,
+        key=key, temperature=temperature, sampling=sampling,
+    )
+    return toks, tok, pos, active, rem, kc, vc
+
+
+def horizon_scan(
+    step_fn, tok, pos, active, rem, eosv, cache, horizon: int,
+    key=None, temperature=None, sampling: bool = False,
+):
+    """The scan of :func:`decode_horizon_slots`, for any model's slot
+    step: ``step_fn(tok, pos, cache, active) -> (logits [B, V], cache,
+    extra)`` is run ``horizon`` times; the token choice, the freezing
+    of finished rows and the carries are the same for every model.
+    Returns ``(toks [B, horizon], tok, pos, active, rem, cache,
+    extras)`` with ``extras`` the steps' ``extra`` stacked."""
+
+    def step(carry, k):
+        tok, pos, active, rem, cache = carry
+        logits, cache, extra = step_fn(tok, pos, cache, active)
         with jax.named_scope("head"):
             if sampling:
                 nxt = jax.random.categorical(
@@ -854,15 +906,15 @@ def decode_horizon_slots(
         rem = jnp.where(active, rem - 1, rem)
         hit = active & (eosv >= 0) & (nxt == eosv)
         active = active & ~hit & (rem > 0)
-        return (nxt, pos, active, rem, kc, vc), out
+        return (nxt, pos, active, rem, cache), (out, extra)
 
     keys = jax.random.split(
         key if key is not None else jax.random.PRNGKey(0), horizon
     )
-    (tok, pos, active, rem, kc, vc), outs = jax.lax.scan(
-        step, (tok, pos, active, rem, kc, vc), keys
+    (tok, pos, active, rem, cache), (outs, extras) = jax.lax.scan(
+        step, (tok, pos, active, rem, cache), keys
     )
-    return jnp.swapaxes(outs, 0, 1), tok, pos, active, rem, kc, vc
+    return jnp.swapaxes(outs, 0, 1), tok, pos, active, rem, cache, extras
 
 
 # -- inference: paged KV cache (block tables) --------------------------------

@@ -2,9 +2,10 @@
 
 The reference has no MoE anywhere (SURVEY §2.5: "Expert parallelism:
 NO — optional"); this makes EP a full model family rather than just a
-layer: a Llama-style decoder whose SwiGLU FFN is replaced by a top-k
-routed expert FFN (parallel/moe.py), with the expert dimension of
-every expert weight sharded over the ``ep`` mesh axis so the
+layer: a Llama-style decoder whose FFN is a top-k routed expert FFN
+(``parallel.moe.moe_ffn``: two-matrix ReLU experts, not SwiGLU; queues
+bound by a capacity factor, overflow dropped), with the expert
+dimension of every expert weight sharded over the ``ep`` mesh axis so the
 dispatch/combine einsums lower to all-to-all-style collectives over
 ICI. Attention, RoPE, rmsnorm, and the flash kernel are shared with
 models/llama.py — one implementation of the hot path.

@@ -146,14 +146,39 @@ def detect_peak(device: Any = None) -> DevicePeak:
 
 # ---------------------------------------------------------------------------
 # FLOPs / params / bytes — transformer (llama + MoE via duck typing)
+#
+# A config whose layer is not the dense decoder's prices itself: it has
+# methods ``matmul_params()`` (parameters a token multiplies: the
+# ACTIVATED experts), ``n_params()``, ``attn_width()`` (h * hd of the
+# attention products) and ``cache_numbers_per_token()``, and the
+# functions below ask those first (``models/deepseek_v3.py``). The
+# ``hasattr(cfg, "n_experts")`` lines remain for ``models/moe.py``,
+# whose config has no such methods.
+
+
+def _own(cfg, name: str):
+    """``cfg.<name>()`` where the config prices itself, else None."""
+    method = getattr(cfg, name, None)
+    return method() if callable(method) else None
 
 
 def _dims(cfg):
     hd = getattr(cfg, "head_dim", None)
     if hd is None:
         hd = cfg.d_model // cfg.n_heads
-    return cfg.d_model, cfg.n_heads, cfg.n_kv_heads, hd, cfg.d_ff, \
-        cfg.n_layers, cfg.vocab
+    return cfg.d_model, cfg.n_heads, getattr(cfg, "n_kv_heads", cfg.n_heads), \
+        hd, cfg.d_ff, cfg.n_layers, cfg.vocab
+
+
+def _attn_width(cfg) -> float:
+    d, h, kv, hd, ff, L, V = _dims(cfg)
+    return _own(cfg, "attn_width") or h * hd
+
+
+def _cache_numbers(cfg) -> float:
+    """Numbers one position holds in the cache, all layers."""
+    d, h, kv, hd, ff, L, V = _dims(cfg)
+    return _own(cfg, "cache_numbers_per_token") or 2.0 * L * kv * hd
 
 
 def matmul_params(cfg) -> float:
@@ -161,6 +186,9 @@ def matmul_params(cfg) -> float:
     excluded, lm_head included) — the ``N`` of the 6N/2N rules. MoE
     configs count the ACTIVATED expert width (top_k experts) plus the
     router — model FLOPs are per-token work actually done."""
+    own = _own(cfg, "matmul_params")
+    if own is not None:
+        return own
     d, h, kv, hd, ff, L, V = _dims(cfg)
     ff_ways = getattr(cfg, "top_k", None) if hasattr(cfg, "n_experts") else None
     per_layer = (
@@ -177,6 +205,9 @@ def matmul_params(cfg) -> float:
 def n_params(cfg) -> float:
     """Total parameter count (for state sizing — MoE counts ALL
     experts here, unlike :func:`matmul_params`)."""
+    own = _own(cfg, "n_params")
+    if own is not None:
+        return own
     d, h, kv, hd, ff, L, V = _dims(cfg)
     experts = getattr(cfg, "n_experts", 1) if hasattr(cfg, "n_experts") else 1
     per_layer = (
@@ -190,8 +221,7 @@ def n_params(cfg) -> float:
 
 
 def attn_flops_per_token_train(cfg, seq: int) -> float:
-    d, h, kv, hd, ff, L, V = _dims(cfg)
-    return 12.0 * L * (seq / 2.0) * (h * hd)
+    return 12.0 * cfg.n_layers * (seq / 2.0) * _attn_width(cfg)
 
 
 def train_flops_per_token(cfg, seq: int) -> float:
@@ -205,8 +235,8 @@ def train_flops_per_token(cfg, seq: int) -> float:
 def fwd_flops_per_token(cfg, seq: int) -> float:
     """Forward-only model FLOPs per token at sequence length ``seq``
     (causal: average context seq/2) — the prefill numerator."""
-    d, h, kv, hd, ff, L, V = _dims(cfg)
-    return 2.0 * matmul_params(cfg) + 4.0 * L * (seq / 2.0) * (h * hd)
+    return 2.0 * matmul_params(cfg) \
+        + 4.0 * cfg.n_layers * (seq / 2.0) * _attn_width(cfg)
 
 
 def prefill_flops(cfg, t: int) -> float:
@@ -219,8 +249,8 @@ def decode_flops_per_token(cfg, s_ctx: int) -> float:
     The serving programs compute masked-dense attention over the FULL
     padded cache, so callers should pass the padded length — this is
     the cost of the program as compiled, not of the useful context."""
-    d, h, kv, hd, ff, L, V = _dims(cfg)
-    return 2.0 * matmul_params(cfg) + 4.0 * L * s_ctx * (h * hd)
+    return 2.0 * matmul_params(cfg) \
+        + 4.0 * cfg.n_layers * s_ctx * _attn_width(cfg)
 
 
 def param_bytes(cfg, bytes_per_param: int = 2) -> float:
@@ -233,8 +263,7 @@ def kv_cache_bytes(
 ) -> float:
     """The [L, slots, max_len, KV, hd] K + V cache pair.
     ``bytes_per_el`` may be fractional (packed int4 KV = 0.5)."""
-    d, h, kv, hd, ff, L, V = _dims(cfg)
-    return 2.0 * L * slots * max_len * kv * hd * bytes_per_el
+    return _cache_numbers(cfg) * slots * max_len * bytes_per_el
 
 
 def kv_pool_bytes(
@@ -242,8 +271,7 @@ def kv_pool_bytes(
 ) -> float:
     """The paged [L, n_blocks, block_size, KV, hd] K + V pool pair
     (includes the reserved scratch block — it occupies real HBM)."""
-    d, h, kv, hd, ff, L, V = _dims(cfg)
-    return 2.0 * L * n_blocks * block_size * kv * hd * bytes_per_el
+    return _cache_numbers(cfg) * n_blocks * block_size * bytes_per_el
 
 
 def kv_quant_bytes_per_el(kv_quant: str) -> float:

@@ -77,6 +77,45 @@ def block_positions(kvh: int, hd: int, itemsize: int, s: int) -> int:
     return blk
 
 
+def _online_block(
+    q, k, v, bias, live_cols, scale, m_ref, l_ref, acc_ref, o_ref, last: bool
+):
+    """One S-block of a slot's online softmax (base-2, float32): scores
+    of the query rows ``q`` against the block's rows ``k``, values
+    ``v``; on the slot's ``last`` block columns ``>= live_cols`` are
+    masked and the output is written, else the running state is."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [H, columns], base-2
+    if bias is not None:
+        s = s + bias
+    if last:
+        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < live_cols, s, NEG_INF)
+    m_prev = m_ref[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp2(s - m_new)
+    alpha = jnp.exp2(m_prev - m_new)
+    l_new = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if last:
+        o_ref[...] = (acc / l_new).astype(o_ref.dtype)
+    else:
+        m_ref[:], l_ref[:], acc_ref[:] = m_new, l_new, acc
+
+
+def _init_state(si, m_ref, l_ref, acc_ref):
+    @pl.when(si == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
 def _kernel(
     slot_ref, blk_ref, pos_ref, layer_ref, q_ref, bias_ref, k_ref, v_ref,
     o_ref, m_ref, l_ref, acc_ref, *, block_s: int, kvh: int, sm_scale: float,
@@ -85,43 +124,43 @@ def _kernel(
     t = pl.program_id(0)
     si = blk_ref[t]  # this step's S-block of its slot
     pos = pos_ref[slot_ref[t]]
-
-    @pl.when(si == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    _init_state(si, m_ref, l_ref, acc_ref)
 
     def _block(last: bool):
-        q = q_ref[...]  # [H, hd]
-        k = k_ref[...]  # [block_s * KV, hd]
-        v = v_ref[...]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * (sm_scale * LOG2E) + bias_ref[...]  # [H, block_s * KV], base-2
-        if last:
-            # column c is position si * block_s + c // KV: live while
-            # that is <= pos
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols < (pos - si * block_s + 1) * kvh, s, NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp2(s - m_new)
-        alpha = jnp.exp2(m_prev - m_new)
-        l_new = l_ref[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        # q [H, hd]; k, v [block_s * KV, hd]. Column c is position
+        # si * block_s + c // KV: live while that is <= pos
+        _online_block(
+            q_ref[...], k_ref[...], v_ref[...], bias_ref[...],
+            (pos - si * block_s + 1) * kvh, sm_scale * LOG2E,
+            m_ref, l_ref, acc_ref, o_ref, last,
         )
-        if last:
-            o_ref[...] = (acc / l_new).astype(o_ref.dtype)
-        else:
-            m_ref[:], l_ref[:], acc_ref[:] = m_new, l_new, acc
 
     # the position mask's iota/compare/select runs on the one block that
     # holds ``pos``, which is also the slot's last step; the blocks
     # below it are live whole
+    pl.when(si < pos // block_s)(lambda: _block(False))
+    pl.when(si == pos // block_s)(lambda: _block(True))
+
+
+def _latent_kernel(
+    slot_ref, blk_ref, pos_ref, layer_ref, q_ref, c_ref,
+    o_ref, m_ref, l_ref, acc_ref, *, block_s: int, rank: int, sm_scale: float,
+):
+    """:func:`_kernel` for a latent cache: one operand, read once, is
+    the keys (all its columns) and the values (its first ``rank``)."""
+    del layer_ref
+    t = pl.program_id(0)
+    si = blk_ref[t]
+    pos = pos_ref[slot_ref[t]]
+    _init_state(si, m_ref, l_ref, acc_ref)
+
+    def _block(last: bool):
+        c = c_ref[...]  # [block_s, rank + rope]
+        _online_block(
+            q_ref[...], c, c[:, :rank], None, pos - si * block_s + 1,
+            sm_scale * LOG2E, m_ref, l_ref, acc_ref, o_ref, last,
+        )
+
     pl.when(si < pos // block_s)(lambda: _block(False))
     pl.when(si == pos // block_s)(lambda: _block(True))
 
@@ -234,3 +273,100 @@ def decode_attention(
         vc.reshape(n_layers, b, s * kvh, hd),
     )
     return out.reshape(b, kvh, groups, hd)
+
+
+# one S-block of a latent cache. All of a slot's query heads are the 32
+# rows of one matmul against a block, so a step's fixed cost weighs
+# more than in the per-head kernel and the block is larger: on v5e, 96
+# slots x 4096 x 640 bf16, all full / at 600-3800 positions, blocks of
+# 64, 128, 256, 512, 1024 positions take 3134 / 1794, 1840 / 1072, 1178
+# / 715, 838 / 536 and 714 / 500 us (PERF.md section 6, PR 29)
+LATENT_BLOCK_BYTES = 1280 << 10
+
+
+def latent_block_positions(width: int, itemsize: int, s: int) -> int:
+    """Positions in one S-block of :func:`decode_attention_latent` for a
+    cache of ``[.., s, width]``: the power of two whose block is
+    ``LATENT_BLOCK_BYTES`` (1024 at 640 bf16 columns), halved until it
+    divides ``s``. The engine's ``kv_read_share`` counts in these."""
+    blk = 1 << (max(LATENT_BLOCK_BYTES // (width * itemsize), 8).bit_length()
+                - 1)
+    while blk > 1 and s % blk:
+        blk //= 2
+    return blk
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rank", "sm_scale", "block_s", "interpret")
+)
+def decode_attention_latent(
+    q: jnp.ndarray,
+    cache: jnp.ndarray,
+    pos: jnp.ndarray,
+    layer,
+    *,
+    rank: int,
+    sm_scale: float,
+    block_s: int | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Single-query attention in the latent space (the absorbed form of
+    multi-head latent attention): every query head of a slot against
+    the ONE latent row a position holds.
+
+    q [B, H, W], each head's query already carried into the cache's
+    space (``q_nope @ W_uk`` beside ``q_rope``); cache [L, B, S, W], the
+    stacked latent cache, ``W = rank + rope`` columns a position:
+    scores take all ``W``, values are the first ``rank``, so a block is
+    fetched once and serves as both; pos [B], each slot's last live
+    position, inclusive; layer, a traced int32 scalar; ``sm_scale`` the
+    model's (of the EXPANDED head width, not of ``W``). Returns
+    [B, H, rank] in q's dtype, for the caller to expand (``@ W_uv``).
+
+    Grid, scalar prefetch and softmax are :func:`decode_attention`'s:
+    the list of live (slot, S-block) pairs, base-2 online softmax in
+    float32. There is no head bias: all heads read all columns."""
+    b, h, width = q.shape
+    n_layers, _, s, _ = cache.shape
+    if block_s is None:
+        block_s = latent_block_positions(width, cache.dtype.itemsize, s)
+    if s % block_s:
+        raise ValueError(f"block_s={block_s} must divide the cache length {s}")
+    kernel = functools.partial(
+        _latent_kernel, block_s=block_s, rank=rank, sm_scale=sm_scale
+    )
+    pos = jnp.clip(pos.astype(jnp.int32), 0, s - 1)
+    steps, slot_of, block_of = live_blocks(pos, block_s, s // block_s)
+
+    def slot_map(t, slot_ref, *_):
+        return (slot_ref[t], 0, 0)
+
+    def cache_map(t, slot_ref, blk_ref, pos_ref, layer_ref):
+        return (layer_ref[0], slot_ref[t], blk_ref[t], 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(steps,),
+            in_specs=[
+                pl.BlockSpec((None, h, width), slot_map),
+                pl.BlockSpec((None, None, block_s, width), cache_map),
+            ],
+            out_specs=pl.BlockSpec((None, h, rank), slot_map),
+            scratch_shapes=[
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="edl_decode_attn_latent",
+    )(
+        slot_of, block_of, pos,
+        jnp.reshape(layer, (1,)).astype(jnp.int32), q, cache,
+    )

@@ -190,8 +190,12 @@ def _fwd_call(
     qb, kb, vb, groups, block_q, block_k, causal, interpret, with_lse
 ):
     """Forward pallas call in flattened [B*H, T, d] layout → out or
-    (out, lse): lse is produced only when saving residuals for grad."""
+    (out, lse): lse is produced only when saving residuals for grad.
+    ``vb`` may have a width of its own (``dv``: a latent-attention
+    prefill has 192-wide queries and keys and 128-wide values); the
+    output is then ``[B*H, T, dv]``."""
     bh, t, d = qb.shape
+    dv = vb.shape[-1]
     kernel = functools.partial(
         _flash_kernel,
         block_q=block_q,
@@ -200,8 +204,8 @@ def _fwd_call(
         sm_scale=1.0 / np.sqrt(d),
         with_lse=with_lse,
     )
-    o_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    o_shape = jax.ShapeDtypeStruct((bh, t, d), qb.dtype)
+    o_spec = pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0))
+    o_shape = jax.ShapeDtypeStruct((bh, t, dv), qb.dtype)
     lse_spec = pl.BlockSpec(
         (1, block_q, LANES), lambda bh, qi, ki: (bh, qi, 0)
     )
@@ -216,7 +220,7 @@ def _fwd_call(
                 (1, block_k, d), lambda bh, qi, ki, g=groups: (bh // g, ki, 0)
             ),
             pl.BlockSpec(
-                (1, block_k, d), lambda bh, qi, ki, g=groups: (bh // g, ki, 0)
+                (1, block_k, dv), lambda bh, qi, ki, g=groups: (bh // g, ki, 0)
             ),
         ],
         out_specs=[o_spec, lse_spec] if with_lse else o_spec,
@@ -224,7 +228,7 @@ def _fwd_call(
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max
             pltpu.VMEM((block_q, 1), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
         name="edl_flash_fwd",
@@ -369,6 +373,11 @@ def _flash(qb, kb, vb, groups, block_q, block_k, causal, interpret):
 
 
 def _flash_fwd(qb, kb, vb, groups, block_q, block_k, causal, interpret):
+    if vb.shape[-1] != qb.shape[-1]:
+        raise NotImplementedError(
+            "flash backward needs values as wide as queries and keys; "
+            f"got {vb.shape[-1]} and {qb.shape[-1]} (forward only)"
+        )
     out, lse = _fwd_call(
         qb, kb, vb, groups, block_q, block_k, causal, interpret,
         with_lse=True,
@@ -475,7 +484,8 @@ def flash_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """q [B, T, H, d], k/v [B, T, KV, d] with H % KV == 0 (GQA) →
-    [B, T, H, d]. T must divide by the (clamped) block sizes — check
+    [B, T, H, d]; v may be [B, T, KV, dv] of another width, forward
+    only, and the result is then [B, T, H, dv]. T must divide by the (clamped) block sizes — check
     with :func:`flash_supported`, or pad upstream. Block defaults
     (512, 1024) measured fastest for train fwd+bwd on v5e at T=2048,
     d=128 (the kernel is VPU-bound; wider kv blocks amortize the
@@ -498,9 +508,10 @@ def flash_attention(
 
     qb = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kb = k.transpose(0, 2, 1, 3).reshape(b * hk, t, d)
-    vb = v.transpose(0, 2, 1, 3).reshape(b * hk, t, d)
+    dv = v.shape[3]
+    vb = v.transpose(0, 2, 1, 3).reshape(b * hk, t, dv)
     out = _flash(qb, kb, vb, groups, block_q, block_k, causal, interpret)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
 def _fit_block(block: int, t: int) -> int:

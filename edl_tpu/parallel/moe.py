@@ -130,3 +130,112 @@ def moe_ffn(
     aux = jnp.sum(assign_frac * prob_frac) * n_experts
 
     return y.reshape(b, t, d), aux
+
+
+# -- dropless routed experts (serving; models/deepseek_v3.py) ----------------
+#
+# ``moe_ffn`` above bounds every expert's queue (capacity 1.25) and drops
+# what overflows, which no published checkpoint's arithmetic does. The
+# functions below never drop: every (token, chosen expert) pair is a row,
+# rows are sorted by expert, and each expert multiplies its own
+# contiguous run of rows (``jax.lax.ragged_dot``: grouped matmuls whose
+# group sizes are data). The weights of an expert nobody chose are not
+# multiplied, and on a bandwidth-bound decode step not read.
+
+
+def route_sigmoid_topk(
+    x: jnp.ndarray,
+    router: jnp.ndarray,
+    bias: jnp.ndarray,
+    k: int,
+    scale: float,
+    normalize: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``deepseek_v3`` router with one group: x [N, d], router
+    [d, E], bias [E] -> (idx [N, k] int32, w [N, k] float32).
+
+    Scores are ``sigmoid(x @ router)`` in float32 over ALL ``E`` experts
+    whatever share of them the caller holds. The choice is the top ``k``
+    of ``score + bias`` (``e_score_correction_bias``: it steers load and
+    is no part of the output); the weights are the UNCORRECTED scores of
+    the chosen, divided by their sum when ``normalize``, times
+    ``scale`` (``routed_scaling_factor``)."""
+    with jax.named_scope("moe.router"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        ))
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if normalize:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), w * scale
+
+
+def _ragged(rows: jnp.ndarray, w, sizes: jnp.ndarray, expert_of: jnp.ndarray):
+    """``rows [M, a] @ w[expert_of[m]]`` over runs of rows sorted by
+    expert. ``w`` is ``[E, a, b]`` or its weight-only int8 record
+    ``{"q8" [E, a, b], "s8" [E, b]}`` (``llama._matw``'s discipline:
+    the column scale multiplies the product, in float32)."""
+    if isinstance(w, dict):
+        out = jax.lax.ragged_dot(rows, w["q8"].astype(rows.dtype), sizes)
+        return (out.astype(jnp.float32) * w["s8"][expert_of]).astype(rows.dtype)
+    return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
+
+
+def moe_dropless(
+    x: jnp.ndarray,
+    idx: jnp.ndarray,
+    w: jnp.ndarray,
+    w1,
+    w3,
+    w2,
+    first: int = 0,
+) -> jnp.ndarray:
+    """SwiGLU experts over routed rows, no token dropped: x [N, d], idx
+    / w [N, k] from :func:`route_sigmoid_topk`; w1 / w3 [E_held, d, f]
+    and w2 [E_held, f, d] are the experts ``first .. first + E_held`` of
+    those the router scored (default: all of them). Returns [N, d], the
+    weighted sum of the HELD experts' outputs for each token: the shares
+    of disjoint ranges add up to the whole layer's routed term.
+
+    Rows are the N * k (token, choice) pairs, sorted by expert (stable:
+    a token's rows keep their order); a pair whose expert is not held
+    sorts behind every group, belongs to none, and is given weight 0."""
+    n, k = idx.shape
+    held = (w1["q8"] if isinstance(w1, dict) else w1).shape[0]
+    with jax.named_scope("moe.experts"):
+        local = idx.reshape(-1) - first
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held)
+        order = jnp.argsort(key, stable=True)
+        expert_of = jnp.minimum(key[order], held - 1)
+        sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        rows = x[order // k]
+        h = jax.nn.silu(_ragged(rows, w1, sizes, expert_of)) * _ragged(
+            rows, w3, sizes, expert_of
+        )
+        out = _ragged(h, w2, sizes, expert_of)
+        # back to (token, choice) order: a gather, where a scatter-add
+        # over tokens would serialise on the TPU
+        out = out[jnp.argsort(order)].reshape(n, k, -1)
+        wk = jnp.where(mine.reshape(n, k), w, 0.0)
+        # a row of no group holds whatever the grouped matmul left there
+        out = jnp.where(mine.reshape(n, k, 1), out.astype(jnp.float32), 0.0)
+        return jnp.sum(out * wk[..., None], axis=1).astype(x.dtype)
+
+
+def expert_load(idx: jnp.ndarray, n_experts: int, rows=None):
+    """What a step's routing did to the experts, as two float32
+    scalars: the share of the ``n_experts`` with at least one row, and
+    the busiest expert's rows over the mean. ``rows`` [N] bool leaves
+    tokens out (a frozen serving slot computes and is nobody's)."""
+    hit = idx[..., None] == jnp.arange(n_experts, dtype=idx.dtype)
+    if rows is not None:
+        hit = hit & rows[:, None, None]
+    load = jnp.sum(hit, axis=(0, 1), dtype=jnp.float32)
+    mean = jnp.maximum(jnp.mean(load), 1e-9)
+    return jnp.mean((load > 0).astype(jnp.float32)), jnp.max(load) / mean

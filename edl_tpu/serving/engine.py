@@ -125,7 +125,7 @@ from edl_tpu.obs import memledger
 # frame-stack chunks, an mmap/munmap a call, +0.8 s on an engine's
 # first prefill (PERF.md section 6, PR 24)
 from edl_tpu.ops import flash_attention as _flash_attention  # noqa: F401
-from edl_tpu.ops import decode_attention as _decode_attention
+from edl_tpu.ops import decode_attention as _decode_attention  # noqa: F401
 from edl_tpu.serving import paged as _paged
 from edl_tpu.serving import spec as _spec
 from edl_tpu.serving.metrics import ServingMetrics
@@ -189,52 +189,82 @@ def _memo(key, make):
     return fn
 
 
-def _block_program(
-    cfg: llama.LlamaConfig, b: int, s: int, horizon: int, sampling: bool
-):
-    """(params, tok, pos, active, rem, eosv, kc, vc, key, temperature)
-    -> (toks [B, H], tok, pos, active, rem, kc, vc). One fused horizon
-    of H decode steps — the single program every membership composition
-    runs. kc/vc AND the consumed slot-state vectors are donated: the
-    cache updates in place and the returned carries are the only live
-    references."""
+# What the engine asks of the config of a model it serves from the
+# contiguous cache (``LlamaConfig`` answers with what this engine
+# always ran; the paged, quantized, chunked and verify programs below
+# are that model's alone) --
+# ``cfg.serve_cache_spec(slots, max_len)``: the ((shape, dtype), ...) of
+# the cache's arrays, each ``[L, slots, max_len, ...]``;
+# ``cfg.serve_prefill(params, tokens [1, Tb], last)`` -> (logits [1, V],
+# one ``[L, 1, Tb, ...]`` array of rows per cache array);
+# ``cfg.serve_decode_block(params, tok, pos, active, rem, eosv, cache,
+# horizon=, key=, temperature=, sampling=)`` -> (toks [B, H], tok, pos,
+# active, rem, cache, counters), ``counters`` a dict of device scalars
+# about the block (may be empty) that the engine drains with the
+# block's tokens onto its ``serving.dispatch`` span;
+# ``cfg.serve_attn_block(max_len)``: positions of one S-block its decode
+# attention fetches.
+_SEAM = ("serve_cache_spec", "serve_prefill", "serve_decode_block",
+         "serve_attn_block")
+
+
+def _block_program(cfg, b: int, s: int, horizon: int, sampling: bool):
+    """(params, tok, pos, active, rem, eosv, *cache, key, temperature)
+    -> (toks [B, H], tok, pos, active, rem, *cache, counters). One
+    fused horizon of H decode steps — the single program every
+    membership composition runs — as the config's model runs it. The
+    cache arrays (kc, vc for the dense decoder) AND the consumed
+    slot-state vectors are donated: the cache updates in place and the
+    returned carries are the only live references."""
 
     def make():
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 6, 7))
+        n = len(cfg.serve_cache_spec(b, s))
+
+        @partial(jax.jit, donate_argnums=(1, 2, 3, 4) + tuple(range(6, 6 + n)))
         @_named("edl_serve_block")
-        def run(params, tok, pos, active, rem, eosv, kc, vc, key, temperature):
-            return llama.decode_horizon_slots(
-                params, tok, pos, active, rem, eosv, kc, vc, cfg,
-                horizon=horizon, key=key, temperature=temperature,
-                sampling=sampling,
+        def run(params, tok, pos, active, rem, eosv, *rest):
+            *cache, key, temperature = rest
+            toks, tok, pos, active, rem, cache, counters = (
+                cfg.serve_decode_block(
+                    params, tok, pos, active, rem, eosv, tuple(cache),
+                    horizon=horizon, key=key, temperature=temperature,
+                    sampling=sampling,
+                )
             )
+            return (toks, tok, pos, active, rem, *cache, counters)
 
         return run
 
     return _memo(("block", cfg, b, s, horizon, sampling), make)
 
 
-def _prefill_program(cfg: llama.LlamaConfig, tb: int, sampling: bool):
+def _prefill_program(cfg, tb: int, sampling: bool):
     """(params, tokens [1, Tb], last, slot, max_new, eos, tok, pos,
-    active, rem, eosv, kc, vc, key, temperature) -> (first_tok, tok,
-    pos, active, rem, eosv, kc, vc): prefill one padded prompt, scatter
-    its K/V into cache row ``slot``, emit the first generated token,
+    active, rem, eosv, *cache, key, temperature) -> (first_tok, tok,
+    pos, active, rem, eosv, *cache): prefill one padded prompt, scatter
+    its rows into cache row ``slot``, emit the first generated token,
     and reset the slot's device-side decode state (position, budget,
     stop token, active mask — EOS-on-first-token and max_new == 1
     deactivate on device exactly like the host bookkeeping) — one
     dispatch per admission. ``last``/``slot``/``max_new``/``eos`` are
     traced, so one program serves every (length, slot, budget) inside
-    the bucket. kc/vc and the slot-state vectors are donated, same
+    the bucket. The cache and the slot-state vectors are donated, same
     contract as the block program."""
 
     def make():
-        @partial(jax.jit, donate_argnums=(6, 7, 8, 9, 10, 11, 12))
+        n = len(cfg.serve_cache_spec(1, tb))
+
+        @partial(jax.jit, donate_argnums=tuple(range(6, 11 + n)))
         @_named(f"edl_serve_prefill_{tb}")
         def run(params, tokens, last, slot, max_new, eos,
-                tok, pos, active, rem, eosv, kc, vc, key, temperature):
-            logits, ks, vs = llama.prefill_padded(params, tokens, last, cfg)
-            kc = jax.lax.dynamic_update_slice(kc, ks, (0, slot, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, vs, (0, slot, 0, 0, 0))
+                tok, pos, active, rem, eosv, *rest):
+            *cache, key, temperature = rest
+            logits, rows = cfg.serve_prefill(params, tokens, last)
+            cache = [
+                jax.lax.dynamic_update_slice(
+                    c, r, (0, slot) + (0,) * (c.ndim - 2))
+                for c, r in zip(cache, rows)
+            ]
             t0 = _first_token(logits, key, temperature, sampling)
             tok = tok.at[slot].set(t0)
             pos = pos.at[slot].set(last + 1)
@@ -242,7 +272,7 @@ def _prefill_program(cfg: llama.LlamaConfig, tb: int, sampling: bool):
             active = active.at[slot].set(~hit & (max_new > 1))
             rem = rem.at[slot].set(jnp.maximum(max_new - 1, 0))
             eosv = eosv.at[slot].set(eos)
-            return t0, tok, pos, active, rem, eosv, kc, vc
+            return (t0, tok, pos, active, rem, eosv, *cache)
 
         return run
 
@@ -622,13 +652,19 @@ class RequestResult:
 
 
 class ContinuousBatchingEngine:
-    """In-process continuous-batching server over a llama param tree.
+    """In-process continuous-batching server over a model's param tree.
 
-    ``params`` is anything ``llama.generate`` accepts: a dense export
-    tree (``load_export``), a sharded one (``load_export_sharded``), or
-    the weight-only int8 records (``quantize_params_int8``). The KV
-    cache is [L, max_slots, max_len, KV, hd] in ``cfg.dtype`` — sized
-    once, donated through every dispatch, updated in place.
+    ``cfg`` is the model's (it answers ``_SEAM``): ``llama.LlamaConfig``
+    the dense decoder, ``deepseek_v3.DeepseekV3Config`` the latent-
+    attention expert model. For the dense decoder ``params`` is
+    anything ``llama.generate`` accepts: a dense export tree
+    (``load_export``), a sharded one (``load_export_sharded``), or the
+    weight-only int8 records (``quantize_params_int8``), and the KV
+    cache is [L, max_slots, max_len, KV, hd] in ``cfg.dtype`` (another
+    model's is what its ``serve_cache_spec`` says) — sized once,
+    donated through every dispatch, updated in place. The paged,
+    quantized-cache, prefix, chunked-prefill and speculative options
+    are the dense decoder's: another model is refused with them.
 
     ``horizon`` is the fused block depth: one device dispatch runs H
     decode steps with per-slot termination on device. H=1 reproduces
@@ -694,6 +730,21 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     f"spec_ngram must be >= 1, got {spec_ngram}"
                 )
+        missing = [f for f in _SEAM if not hasattr(cfg, f)]
+        if missing:
+            raise TypeError(
+                f"{type(cfg).__name__} cannot be served: it lacks "
+                f"{', '.join(missing)}"
+            )
+        if not isinstance(cfg, llama.LlamaConfig) and (
+            block_size or prefix_cache or prefill_chunk
+            or kv_quant != "off" or spec_k
+        ):
+            raise ValueError(
+                f"{type(cfg).__name__} is served from the contiguous cache "
+                "alone: block_size, prefix_cache, prefill_chunk, kv_quant "
+                "and spec_k are the dense decoder's (llama.LlamaConfig)"
+            )
         # paged KV mode (block_size > 0): the cache is a pool of
         # fixed-size blocks addressed through per-slot block tables —
         # HBM scales with RESIDENT tokens, not slots x max_len, and
@@ -757,7 +808,10 @@ class ContinuousBatchingEngine:
         self.max_slots = max_slots
         self.max_len = max_len
         self.horizon = horizon
-        self.queue = queue or RequestQueue(max_total_len=max_len, clock=clock)
+        # the default queue holds at least a request a slot: a full
+        # server's callers may all send at once
+        self.queue = queue or RequestQueue(
+            max_total_len=max_len, max_depth=max(64, max_slots), clock=clock)
         if self.queue.max_total_len > max_len:
             raise ValueError(
                 f"queue admits up to {self.queue.max_total_len} total "
@@ -812,11 +866,8 @@ class ContinuousBatchingEngine:
             max_slots, horizon, max_len
         )
         self._attn_block = (
-            _decode_attention.block_positions(
-                cfg.n_kv_heads, cfg.head_dim,
-                jnp.dtype(cfg.dtype).itemsize, max_len,
-            )
-            if cfg.use_flash and not self._paged else max_len
+            max_len if self._paged
+            else cfg.serve_attn_block(max_len)
         )
         # speculative draft–verify (spec_k > 0): each verify dispatch
         # scores spec_k host-drafted tokens + the pending token in one
@@ -869,14 +920,28 @@ class ContinuousBatchingEngine:
             max_len=max_len,
             horizon=horizon,
             cache_mb=round(
-                (self._kc.nbytes + self._vc.nbytes + self._kv_scale_nbytes())
-                / 2**20, 1),
+                (self._cache_nbytes() + self._kv_scale_nbytes()) / 2**20, 1),
             paged=self._paged,
             block_size=self.block_size,
             pool_blocks=self.pool_blocks,
             kv_quant=self.kv_quant,
             sampling=self._sampling,
         )
+
+    # the cache's arrays, donated and rebound together; the dense
+    # decoder's two are known by name to its paged / verify paths
+    def _set_cache(self, i: int, value) -> None:
+        cache = list(self._cache)
+        cache[i] = value
+        self._cache = tuple(cache)
+
+    _kc = property(lambda self: self._cache[0],
+                   lambda self, v: self._set_cache(0, v))
+    _vc = property(lambda self: self._cache[1],
+                   lambda self, v: self._set_cache(1, v))
+
+    def _cache_nbytes(self) -> int:
+        return sum(c.nbytes for c in self._cache)
 
     def _kv_scale_nbytes(self) -> int:
         """Bytes held by the quantized pool's scale planes (0 when
@@ -899,7 +964,8 @@ class ContinuousBatchingEngine:
         self._dact = jnp.zeros(max_slots, bool)
         self._drem = jnp.zeros(max_slots, jnp.int32)
         self._deos = jnp.full((max_slots,), -1, jnp.int32)
-        L, kvh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        if self._paged:
+            L, kvh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
         self._ks: Optional[jnp.ndarray] = None
         self._vs: Optional[jnp.ndarray] = None
         if self._paged:
@@ -916,14 +982,14 @@ class ContinuousBatchingEngine:
                 # zero block — the recovery realloc is self-consistent.
                 hdp = llama.kvq_packed_head_dim(self.kv_quant, hd)
                 shape = (L, self.pool_blocks, self.block_size, kvh, hdp)
-                self._kc = jnp.zeros(shape, jnp.int8)
-                self._vc = jnp.zeros(shape, jnp.int8)
+                self._cache = (jnp.zeros(shape, jnp.int8),
+                               jnp.zeros(shape, jnp.int8))
                 self._ks = jnp.zeros((L, self.pool_blocks, kvh), jnp.float32)
                 self._vs = jnp.zeros((L, self.pool_blocks, kvh), jnp.float32)
             else:
                 shape = (L, self.pool_blocks, self.block_size, kvh, hd)
-                self._kc = jnp.zeros(shape, cfg.dtype)
-                self._vc = jnp.zeros(shape, cfg.dtype)
+                self._cache = (jnp.zeros(shape, cfg.dtype),
+                               jnp.zeros(shape, cfg.dtype))
             self._balloc = _paged.BlockAllocator(
                 self.pool_blocks, self.block_size
             )
@@ -935,9 +1001,10 @@ class ContinuousBatchingEngine:
                 [_paged.SCRATCH] * self._m for _ in range(max_slots)
             ]
         else:
-            shape = (L, max_slots, max_len, kvh, hd)
-            self._kc = jnp.zeros(shape, cfg.dtype)
-            self._vc = jnp.zeros(shape, cfg.dtype)
+            self._cache = tuple(
+                jnp.zeros(shape, dtype) for shape, dtype in
+                cfg.serve_cache_spec(max_slots, max_len)
+            )
         # lanes whose slot was evicted while the DEVICE row was still
         # active (deadline evictions are host-bookkeeping only): blocks
         # dispatched before the eviction still carry the old request's
@@ -961,7 +1028,7 @@ class ContinuousBatchingEngine:
         # so discarded in-flight time is not charged
         self._ledger.register(
             self._ledger_owner, "kv",
-            self._kc.nbytes + self._vc.nbytes + self._kv_scale_nbytes(),
+            self._cache_nbytes() + self._kv_scale_nbytes(),
             "kv",
         )
         if self._paged:
@@ -1314,8 +1381,7 @@ class ContinuousBatchingEngine:
             # the table is a TRACED operand snapshot: alloc/share/free
             # between dispatches are host bookkeeping, never a retrace
             table = jnp.asarray(tbl)
-        old = (self._dtok, self._dpos, self._dact, self._drem,
-               self._kc, self._vc)
+        old = (self._dtok, self._dpos, self._dact, self._drem) + self._cache
         if self._ks is not None:
             old = old + (self._ks, self._vs)
         # span measures the ENQUEUE cost only (the dispatch is async);
@@ -1335,7 +1401,8 @@ class ContinuousBatchingEngine:
             cost = self._cost.decode_block(
                 self.max_slots, self.horizon, share * self.max_len
             )
-        with tracing.span("serving.dispatch", **attrs):
+        counters = None
+        with tracing.span("serving.dispatch", **attrs) as attrs:
             if self._paged and self._ks is not None:
                 (toks, self._dtok, self._dpos, self._dact, self._drem,
                  self._kc, self._vc, self._ks, self._vs) = self._decode(
@@ -1352,11 +1419,11 @@ class ContinuousBatchingEngine:
                 )
             else:
                 (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc) = self._decode(
+                 *cache, counters) = self._decode(
                     self.params, old[0], old[1], old[2], old[3],
-                    self._deos, old[4], old[5],
-                    self._next_key(), self._temp(),
+                    self._deos, *old[4:], self._next_key(), self._temp(),
                 )
+                self._cache = tuple(cache)
         self.metrics.on_dispatch("decode")
         # deliberate read of the donated refs: is_deleted() PROBES that
         # donation actually happened (the runtime half of this invariant)
@@ -1378,8 +1445,11 @@ class ContinuousBatchingEngine:
             i: s.rid for i, s in enumerate(self._slots)
             if s is not None and s.pf_next is None
         }
+        # what the model counted on the device during the block comes
+        # back with its tokens, onto this dispatch's span
         self._inflight.append(
-            (toks, self.clock(), members, cost, None, rids)
+            (toks, self.clock(), members, cost, None, rids,
+             (counters, attrs) if counters else None)
         )
 
     def _kv_read_share(self) -> float:
@@ -1477,7 +1547,8 @@ class ContinuousBatchingEngine:
             if s is not None and s.pf_next is None
         }
         self._inflight.append(
-            (toks, self.clock(), members, self._verify_cost, drafted, rids)
+            (toks, self.clock(), members, self._verify_cost, drafted, rids,
+             None)
         )
 
     def _drain_one(self) -> int:
@@ -1490,13 +1561,18 @@ class ContinuousBatchingEngine:
         # rids: the list the block's dispatch built (the requests that
         # ride the block being synced)
         with tracing.span("serving.drain", rids=self._inflight[0][5]):
-            blk, t_dispatch, members, cost, drafted, _ = (
+            blk, t_dispatch, members, cost, drafted, _, counted = (
                 self._inflight.popleft()
             )
             # chaos site: the popped block is lost on a crash here —
             # its tokens exist only on device, recovery must regenerate
             faults.fault_point("serve.drain")
             out = np.asarray(blk)
+            if counted is not None:
+                # the block's program has ended: these are ready too
+                counters, dispatch_attrs = counted
+                dispatch_attrs.update(
+                    (k, float(v)) for k, v in counters.items())
         # dispatch -> drained wall time: the decode-phase granule of
         # the latency decomposition (end-to-end as the host saw it)
         now = self.clock()
@@ -1736,7 +1812,7 @@ class ContinuousBatchingEngine:
         t_pf = self.clock()
         prefill = _prefill_program(self.cfg, tb, self._sampling)
         old = (self._dtok, self._dpos, self._dact, self._drem,
-               self._deos, self._kc, self._vc)
+               self._deos) + self._cache
         # request trace root, DERIVED from the rid: the prefill span
         # and the serve.prefill event share trace id
         # derived_trace_id("rid", rid) without any id exchange, so a
@@ -1747,17 +1823,18 @@ class ContinuousBatchingEngine:
         )
         with rid_root, tracing.span("serving.prefill", bucket=tb, rid=rid):
             (tok0, self._dtok, self._dpos, self._dact, self._drem,
-             self._deos, self._kc, self._vc) = prefill(
+             self._deos, *cache) = prefill(
                 self.params,
                 jnp.asarray(toks),
                 jnp.int32(t0 - 1),
                 jnp.int32(slot),
                 jnp.int32(max_new),
                 jnp.int32(-1 if eos_id is None else eos_id),
-                old[0], old[1], old[2], old[3], old[4], old[5], old[6],
+                *old,
                 self._next_key(),
                 self._temp(),
             )
+            self._cache = tuple(cache)
             self.metrics.on_dispatch("prefill")
             # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
             self._assert_donated(*old)

@@ -136,7 +136,10 @@ class Tracer:
     def span(self, name: str, **attrs: Any):
         """Time the block. Yields the span's attribute dict, so what is
         only known at the end can still be recorded
-        (``with span("serving.admit") as a: ...; a["admitted"] = n``)."""
+        (``with span("serving.admit") as a: ...; a["admitted"] = n``).
+        The stored span keeps that very dict: what the device reports
+        after the span has closed can still be written into it (the
+        serving engine's block counters, drained a step later)."""
         return self._span(name, None, attrs)
 
     def step_span(self, name: str, step_num: int, **attrs: Any):
@@ -173,8 +176,8 @@ class Tracer:
             if _ctx_exit is not None:
                 _ctx_exit(state)
             if ctx_attrs:
-                attrs = {**attrs, **ctx_attrs}
-            self._store(Span(name, start - self._t0, dur, dict(attrs),
+                attrs.update(ctx_attrs)
+            self._store(Span(name, start - self._t0, dur, attrs,
                              threading.get_ident(), seq))
 
     def record(self, name: str, start_s: float, dur_s: float,

@@ -220,3 +220,91 @@ def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
     cache_bytes = 2 * kc.size * 2
     assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
     assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
+# -- the latent-attention expert model (kanana2.decode-wide's shapes) --------
+
+
+@pytest.mark.parametrize("block_s", [None, 512])
+def test_latent_decode_kernel_compiles_for_v5e(v5e, block_s):
+    """``edl_decode_attn_latent`` at 96 slots x 4096 positions x 640
+    columns, 8 layers: one operand, no temporary the size of a layer."""
+    from edl_tpu.ops.decode_attention import decode_attention_latent
+
+    one = SingleDeviceSharding(v5e[0])
+    b, s, w = 96, 4096, 640
+    compiled = jax.jit(
+        lambda q, c, p, l: decode_attention_latent(
+            q, c, p, l, rank=512, sm_scale=192 ** -0.5, block_s=block_s)
+    ).lower(
+        _sds((b, 32, w), jnp.bfloat16, one),
+        _sds((8, b, s, w), jnp.bfloat16, one),
+        _sds((b,), jnp.int32, one), _sds((), jnp.int32, one),
+    ).compile()
+    text = compiled.as_text()
+    assert "edl_decode_attn_latent" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < b * s * w * 2 // 16
+
+
+def test_flash_forward_compiles_with_192_wide_keys_and_128_wide_values(v5e):
+    one = SingleDeviceSharding(v5e[0])
+    qk = _sds((1, 2048, 32, 192), jnp.bfloat16, one)
+    v = _sds((1, 2048, 32, 128), jnp.bfloat16, one)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, block_q=512, block_k=1024)
+    ).lower(qk, qk, v).compile()
+    assert "edl_flash_fwd" in compiled.as_text()
+
+
+def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
+    """``edl_serve_block`` of ``kanana2.decode-wide`` (one dense + seven
+    expert layers at published widths, 96 slots x 4096, one step a
+    dispatch): weights and cache are 14.2 GB of the chip's 15.75,
+    the latent cache aliases its output,
+    nothing the size of the cache or of a layer of it is made (at 576
+    columns the compiler kept the cache positions-minor and transposed
+    all of it around every kernel), each layer's attention is
+    ``edl_decode_attn_latent`` and each expert layer's three grouped
+    matmuls read the experts where they lie. The temporaries are
+    21 MB."""
+    import re
+
+    from benchmark import harness
+    from benchmark.families import mla_moe as family
+    from edl_tpu.serving import engine
+
+    one = SingleDeviceSharding(v5e[0])
+    config = harness.load_json(os.path.join(
+        harness.ROOT, "benchmark", "configs", "kanana2-30b-a3b-L8.json"))
+    cfg = family.program_config(config, training=False)
+    params = harness.layout_tree(
+        family.param_layout(config),
+        lambda path, shape, std, stacked: _sds(shape, jnp.bfloat16, one))
+    spec = harness.Cell("kanana2.decode-wide").spec["engine"]
+    b, s, horizon = spec["max_slots"], spec["max_len"], spec["horizon"]
+    assert (b, s, horizon) == (96, 4096, 1)
+    i32 = _sds((b,), jnp.int32, one)
+    cache = _sds((cfg.n_layers, b, s, cfg.cache_width), cfg.dtype, one)
+    compiled = engine._block_program(cfg, b, s, horizon, False).lower(
+        params, i32, i32, _sds((b,), jnp.bool_, one), i32, i32, cache,
+        _sds((2,), jnp.uint32, one), _sds((), jnp.float32, one),
+    ).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= b * s * cfg.n_layers * 640 * 2
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert text.count("edl_decode_attn_latent") >= cfg.n_layers
+    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16", text)) == 3 * 7
+    # (the loop hands its operands on by get-tuple-element: no copy)
+    made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text)
+    whole, layer = "bf16[8,96,4096,640]", "bf16[96,4096,640]"
+    assert not [op for shape, op in made
+                if shape in (whole, layer)
+                and op not in ("fusion", "parameter", "scatter",
+                              "get-tuple-element")], "a copy of the cache"
+    experts = ("bf16[128,2048,768]", "bf16[128,768,2048]")
+    assert not [op for shape, op in made
+                if shape in experts
+                and op not in ("parameter", "get-tuple-element")], \
+        "experts copied"
