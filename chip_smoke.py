@@ -227,6 +227,7 @@ def drop(trainer) -> None:
 
     trainer.state = None
     trainer._step_fn = None
+    trainer._built.clear()  # the step (and its executable) kept per mesh
     gc.collect()
 
 
@@ -649,7 +650,7 @@ def phase_elastic4(args, sz: Sizes) -> dict:
         print(f"  ReshardEvent {ev.from_workers}->{ev.to_workers} "
               f"path={'host' if ev.fallback else 'device'} "
               f"stall_s={ev.stall_s:.3f} recompile_s={ev.recompile_s:.2f} "
-              f"at step {ev.step}", flush=True)
+              f"step_reused={ev.step_reused} at step {ev.step}", flush=True)
     check(len(events) == 2, "two reshards recorded")
     check(all(np.isfinite(x) for x in losses), "loss finite throughout")
     print(f"  device memory at end: {device_memory_line()}", flush=True)

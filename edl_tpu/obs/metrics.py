@@ -525,6 +525,10 @@ def ensure_core_series(reg: Optional[MetricsRegistry] = None) -> MetricsRegistry
     )
     # elastic / reshard (the BASELINE north-star metric, scrapeable)
     r.counter("edl_reshard_total", "elastic reshards", ("path",))
+    r.counter(
+        "edl_reshard_step_reused_total",
+        "reshards back to a mesh the job has had, its built step reused",
+    )
     r.histogram("edl_reshard_stall_seconds", "traffic-stopping reshard window")
     r.histogram("edl_reshard_recompile_seconds", "first-step compile on the new mesh")
     # checkpoint

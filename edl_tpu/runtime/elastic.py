@@ -64,6 +64,12 @@ def _obs_reshard(ev: "ReshardEvent") -> None:
     r.counter("edl_reshard_total", "elastic reshards", ("path",)).inc(
         path="host" if ev.fallback else "device"
     )
+    reused = r.counter(
+        "edl_reshard_step_reused_total",
+        "reshards back to a mesh the job has had, its built step reused",
+    )
+    if ev.step_reused:
+        reused.inc()
 
 
 def _device_reshard(state: TrainState, plan: MeshPlan, mesh, pspecs) -> TrainState:
@@ -99,6 +105,25 @@ class ReshardEvent:
     lower_s: float = 0.0
     load_s: float = 0.0
     cache_hit: bool = False
+    # True when the job has had this mesh before and the step built for
+    # it then was found again (ElasticTrainer._build): nothing is traced,
+    # lowered or loaded, and the three durations above read 0.0
+    step_reused: bool = False
+
+
+@dataclass(frozen=True)
+class _BuiltMesh:
+    """What ``ElasticTrainer._build`` makes for one mesh: all of it a
+    function of the device tuple and the plan alone. ``step_fn`` owns
+    its ``jax.jit`` (and ``stepper`` its four), and with them the loaded
+    executable: while the record lives, a call with the same shardings
+    is found in jit's own cache."""
+
+    plan: MeshPlan
+    mesh: Any
+    pspecs: Any
+    step_fn: Callable
+    stepper: Optional[LocalSyncStepper]
 
 
 @dataclass
@@ -126,7 +151,7 @@ class ElasticTrainer:
     per_chip_batch : per-device batch size — global batch scales with the
         worker count, the reference's elastic-DP throughput semantics
     param_pspecs : optional model-provided PartitionSpec tree, or a
-        callable ``plan -> tree`` re-evaluated at every (re)build so TP
+        callable ``plan -> tree`` evaluated once per distinct mesh so TP
         layouts track the current mesh plan
     devices : device pool override (defaults to ``jax.devices()``)
     """
@@ -149,8 +174,8 @@ class ElasticTrainer:
         hbm_bytes_per_example: Optional[float] = None,
     ):
         self.loss_fn = loss_fn
-        # mesh-aware loss factory ``(plan, mesh) -> loss_fn``, re-invoked
-        # at every (re)build — required for strategies whose program
+        # mesh-aware loss factory ``(plan, mesh) -> loss_fn``, invoked
+        # once per distinct mesh — required for strategies whose program
         # depends on the mesh layout (llama sp ring/Ulysses attention,
         # pp GPipe schedule), mirroring Workload.make_loss in the
         # process runtime. When given, ``loss_fn`` may be None.
@@ -161,6 +186,10 @@ class ElasticTrainer:
         self.per_chip_batch = per_chip_batch
         self.param_pspecs = param_pspecs
         self._pspecs = None  # resolved per-plan in _build
+        # every mesh the job has had, by (devices in order, plan): a
+        # reshard back to one installs what was built for it then. At
+        # most one record per feasible worker count of the pool.
+        self._built: Dict[tuple, _BuiltMesh] = {}
         self.pool = list(devices) if devices is not None else list(jax.devices())
         self.on_reshard = on_reshard
         # periodic checkpointing (the reference's save_inference_model
@@ -266,34 +295,45 @@ class ElasticTrainer:
             ckpt.save(path, to_save, {"n_workers": self.n_workers})
         return path
 
-    def _build(self, n_workers: int) -> None:
+    def _build(self, n_workers: int) -> bool:
+        """Install the mesh for ``n_workers`` and its step; True when the
+        job has had that mesh before and what was built then is reused."""
         n_dev = n_workers * self.chips_per_worker
         if n_dev > len(self.pool):
             raise ValueError(
                 f"{n_workers} workers x {self.chips_per_worker} chips "
                 f"exceed device pool ({len(self.pool)})"
             )
-        self.plan = MeshPlan.from_spec(self.mesh_spec, n_dev)
-        self.mesh = self.plan.build(self.pool[:n_dev])
+        devices = tuple(self.pool[:n_dev])
+        plan = MeshPlan.from_spec(self.mesh_spec, n_dev)
+        key = (devices, plan)
+        built = self._built.get(key)
+        reused = built is not None
+        if not reused:
+            mesh = plan.build(devices)
+            pspecs = (
+                self.param_pspecs(plan)
+                if callable(self.param_pspecs)
+                else self.param_pspecs
+            )
+            loss = (
+                self.make_loss(plan, mesh)
+                if self.make_loss is not None
+                else self.loss_fn
+            )
+            built = self._built[key] = _BuiltMesh(
+                plan,
+                mesh,
+                pspecs,
+                make_train_step(loss, self.tx, plan, mesh, pspecs),
+                LocalSyncStepper(loss, self.tx, plan, mesh)
+                if self.sync_every > 1
+                else None,
+            )
         self.n_workers = n_workers
-        self._pspecs = (
-            self.param_pspecs(self.plan)
-            if callable(self.param_pspecs)
-            else self.param_pspecs
-        )
-        loss = (
-            self.make_loss(self.plan, self.mesh)
-            if self.make_loss is not None
-            else self.loss_fn
-        )
-        self._step_fn = make_train_step(
-            loss, self.tx, self.plan, self.mesh, self._pspecs
-        )
-        self._stepper = (
-            LocalSyncStepper(loss, self.tx, self.plan, self.mesh)
-            if self.sync_every > 1
-            else None
-        )
+        self.plan, self.mesh, self._pspecs = built.plan, built.mesh, built.pspecs
+        self._step_fn, self._stepper = built.step_fn, built.stepper
+        return reused
 
     def _ledger_register(self) -> None:
         """(Re)register the live state's HBM in the memory ledger —
@@ -392,8 +432,9 @@ class ElasticTrainer:
             # the move: the new dp width means a new group count, and the
             # merge is the same one all-reduce a sync boundary costs
             old_state = self.merged_state
-            with tracing.span("reshard.build_mesh", to_workers=target):
-                self._build(target)  # new mesh over new device set
+            with tracing.span("reshard.build_mesh", to_workers=target) as attrs:
+                # new mesh over new device set, or one the job has had
+                attrs["step_reused"] = reused = self._build(target)
             try:
                 # fast path: direct device-to-device reshard (rides ICI on
                 # real hardware; surviving shards move, no host round trip)
@@ -424,6 +465,7 @@ class ElasticTrainer:
             recompile_s=0.0,  # filled after the first step on the new mesh
             step=step_at,
             fallback=used_fallback,
+            step_reused=reused,
         )
         self.report.reshards.append(ev)
         _obs_reshard(ev)
@@ -545,8 +587,8 @@ class ElasticTrainer:
         tc = time.perf_counter()
         h_data.observe(tc - ts)
         with compilewatch.Window() as built:
-            # on the first step of a mesh this is where the program is
-            # traced, lowered and loaded
+            # on the first step of a mesh the job has not had, this is
+            # where the program is traced, lowered and loaded
             with tracing.span("train.dispatch"):
                 if self._stepper is not None:
                     self.state, metrics = self._stepper.step(
@@ -566,7 +608,7 @@ class ElasticTrainer:
                 "reshard.recompile", tc, ev.recompile_s,
                 {"to_workers": self.n_workers, "trace_s": ev.trace_s,
                  "lower_s": ev.lower_s, "load_s": ev.load_s,
-                 "cache_hit": ev.cache_hit},
+                 "cache_hit": ev.cache_hit, "step_reused": ev.step_reused},
             )
             obs_metrics.default_registry().histogram(
                 "edl_reshard_recompile_seconds",

@@ -8,6 +8,7 @@ the loss curve continuing as if nothing happened.
 import jax
 import numpy as np
 import optax
+import pytest
 
 from edl_tpu.api.job import MeshSpec
 from edl_tpu.models import ctr, linreg
@@ -311,3 +312,145 @@ def test_stall_model_staging_aware():
     assert 16.2 < adafactor < 16.3
     raw = ckpt.host_fallback_stall_model(30 * gb, 1, bw)
     assert raw == 30.0
+
+
+# ---------------------------------------------------------------------------
+# a reshard back to a mesh the job has had reuses what was built for it
+
+
+def _linreg_trainer(devices, tx=None, **kw):
+    return ElasticTrainer(
+        linreg.loss_fn, tx or optax.sgd(0.05), per_chip_batch=8,
+        devices=devices, **kw
+    )
+
+
+def _run_schedule(tr, schedule, forget=False, steps=2):
+    """``steps`` steps on each worker count of ``schedule`` in turn;
+    ``forget`` empties the kept records before each rescale, which is
+    what every reshard did before they were kept."""
+    data = linreg_data_fn()
+    tr.start(linreg.init_params(jax.random.PRNGKey(0)), schedule[0])
+    tr.train_steps(data, steps)
+    for workers in schedule[1:]:
+        if forget:
+            tr._built.clear()
+        tr.request_rescale(workers)
+        tr.train_steps(data, steps)
+    return tr.report.losses, shd.to_host(tr.merged_state.params)
+
+
+def _assert_trees_equal(a, b):
+    jax.tree_util.tree_map(np.testing.assert_array_equal, a, b)
+
+
+@pytest.mark.parametrize("sync_every", [1, 2])
+def test_reused_mesh_trains_as_a_rebuilt_one_does(cpu_devices, sync_every):
+    """4 -> 2 -> 4 -> 2 with the records kept against the same schedule
+    with every mesh built afresh: the same losses and the same final
+    parameters, bit for bit — with ``sync_every`` 2 through the kept
+    ``LocalSyncStepper`` too."""
+    schedule = [4, 2, 4, 2]
+    kept = _linreg_trainer(cpu_devices[:4], sync_every=sync_every)
+    fresh = _linreg_trainer(cpu_devices[:4], sync_every=sync_every)
+    losses_kept, params_kept = _run_schedule(kept, schedule, steps=3)
+    losses_fresh, params_fresh = _run_schedule(
+        fresh, schedule, forget=True, steps=3)
+    assert [e.step_reused for e in kept.report.reshards] == [False, True, True]
+    assert [e.step_reused for e in fresh.report.reshards] == [False] * 3
+    assert losses_kept == losses_fresh
+    _assert_trees_equal(params_kept, params_fresh)
+    assert (kept._stepper is not None) == (sync_every > 1)
+    assert len(kept._built) == 2  # one record a distinct mesh
+
+
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_reshard_onto_a_reused_mesh_is_bitexact(cpu_devices, monkeypatch, path):
+    """Parameters and optimizer state come through a reshard onto a mesh
+    the job has had bit for bit, by the device path and by the
+    host-staged fallback alike."""
+    from edl_tpu.runtime import elastic as el
+
+    tr = _linreg_trainer(cpu_devices[:4], tx=optax.adam(1e-2))
+    tr.start(linreg.init_params(jax.random.PRNGKey(1)), 4)
+    data = linreg_data_fn()
+    tr.train_steps(data, 3)
+    tr.request_rescale(2)
+    tr.train_steps(data, 3)
+    before = ckpt.snapshot(tr.state)
+    if path == "host":
+        def _boom(*a, **k):
+            raise RuntimeError("transfer layer down")
+
+        monkeypatch.setattr(el, "_device_reshard", _boom)
+    tr.request_rescale(4)
+    tr._maybe_rescale()
+    ev = tr.report.reshards[-1]
+    assert ev.step_reused is True and ev.fallback is (path == "host")
+    after = ckpt.snapshot(tr.state)
+    _assert_trees_equal(before.params, after.params)
+    _assert_trees_equal(before.opt_state, after.opt_state)
+    # and the kept step takes the state the fallback placed
+    rep = tr.train_steps(data, 2)
+    assert np.isfinite(rep.losses[-1]) and int(tr.state.step) == 8
+    assert tr.report.reshards[-1].recompile_s > 0.0
+
+
+def test_mesh_factories_run_once_per_distinct_mesh(cpu_devices):
+    """``make_loss`` and a callable ``param_pspecs`` are evaluated when a
+    mesh is first built and not on a return to it; a pool replaced by
+    other devices of the same count is another mesh."""
+    calls = {"loss": [], "pspecs": []}
+
+    def make_loss(plan, mesh):
+        calls["loss"].append((plan.describe()["dp"], mesh.devices.size))
+        return linreg.loss_fn
+
+    def pspecs(plan):
+        calls["pspecs"].append(plan.describe()["dp"])
+        return None
+
+    tr = ElasticTrainer(
+        None, optax.sgd(0.05), per_chip_batch=8, devices=cpu_devices[:4],
+        make_loss=make_loss, param_pspecs=pspecs,
+    )
+    _run_schedule(tr, [4, 2, 4, 2, 4])
+    assert calls["loss"] == [(4, 4), (2, 2)]
+    assert calls["pspecs"] == [4, 2]
+    assert [e.step_reused for e in tr.report.reshards] == [
+        False, True, True, True]
+
+    # the same count over other devices: not the old record
+    data = linreg_data_fn()
+    old_mesh = tr.mesh
+    tr.pool = list(cpu_devices[4:8])
+    tr.request_rescale(2)
+    tr.train_steps(data, 2)
+    ev = tr.report.reshards[-1]
+    assert ev.step_reused is False
+    assert calls["loss"][-1] == (2, 2) and len(calls["loss"]) == 3
+    assert list(tr.mesh.devices.flat) == list(cpu_devices[4:6])
+    assert tr.mesh is not old_mesh
+    assert {s.device for s in tr.state.params["w"].addressable_shards} == set(
+        cpu_devices[4:6])
+    # a replaced mesh_spec is another plan over the same devices
+    tr.mesh_spec = MeshSpec(fsdp=2)
+    tr.request_rescale(4)
+    tr.train_steps(data, 2)
+    assert tr.report.reshards[-1].step_reused is False
+    assert tr.plan.describe() == {"dp": 2, "fsdp": 2}
+
+
+def test_reshard_counters_say_how_often_the_step_was_reused(cpu_devices):
+    from edl_tpu.obs import metrics as obs_metrics
+
+    reg = obs_metrics.reset_default_registry()
+    try:
+        tr = _linreg_trainer(cpu_devices[:4])
+        _run_schedule(tr, [4, 2, 4, 2])
+        assert reg.get("edl_reshard_total").value(path="device") == 3
+        assert reg.get("edl_reshard_total").value(path="host") == 0
+        assert reg.get("edl_reshard_step_reused_total").value() == 2
+        assert reg.get("edl_reshard_stall_seconds").stats()["count"] == 3
+    finally:
+        obs_metrics.reset_default_registry()

@@ -372,7 +372,7 @@ def test_llama_elastic_fsdp_reshard(cpu_devices):
         mesh_spec=plan_spec,
         chips_per_worker=2,
         per_chip_batch=4,
-        # plan-aware: re-evaluated at every reshard
+        # plan-aware: evaluated once per distinct mesh
         param_pspecs=lambda plan: llama.param_pspecs(cfg, plan),
     )
     tr.start(llama.init_params(jax.random.PRNGKey(0), cfg), n_workers=2)

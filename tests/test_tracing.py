@@ -293,3 +293,53 @@ def test_train_loop_spans_nest_and_first_step_of_a_mesh_is_taken_apart(
     rec = tracing.tracer().spans("reshard.recompile")[-1]
     assert rec.attrs["trace_s"] == ev.trace_s
     assert rec.attrs["load_s"] == ev.load_s and rec.attrs["to_workers"] == 2
+
+
+def test_first_step_of_a_mesh_the_job_has_had_builds_nothing(cpu_devices):
+    """4 -> 2 -> 4 -> 2: the first visit of the 2-mesh traces, lowers and
+    compiles its step; the returns to the 4-mesh and to the 2-mesh find
+    theirs kept, and JAX reports no trace while their first step runs."""
+    traced = []
+
+    def on_duration(event, secs, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            traced.append(kw.get("fun_name"))
+
+    tr = _trainer(devices=cpu_devices[:4])
+    tr.start(linreg.init_params(jax.random.PRNGKey(0)), n_workers=4)
+    data = _data_fn(64)
+    tr.train_steps(data, 2)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        during_first_step = []
+        for workers in (2, 4, 2):
+            tr.request_rescale(workers)
+            traced.clear()
+            tr.train_steps(data, 1)  # the reshard and the first step
+            during_first_step.append(list(traced))
+            tr.train_steps(data, 1)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    first, *returns = tr.report.reshards
+    assert (first.from_workers, first.to_workers) == (4, 2)
+    assert first.step_reused is False
+    assert first.trace_s > 0 and first.lower_s > 0 and first.load_s > 0
+    assert "edl_train_step" in during_first_step[0]
+    assert [(e.from_workers, e.to_workers) for e in returns] == [(2, 4), (4, 2)]
+    for ev, seen in zip(returns, during_first_step[1:]):
+        assert ev.step_reused is True
+        assert (ev.trace_s, ev.lower_s, ev.load_s) == (0.0, 0.0, 0.0)
+        assert ev.cache_hit is False  # nothing was loaded
+        assert ev.recompile_s > 0.0  # still timed as the mesh's first
+        assert seen == []
+    builds = tracing.tracer().spans("reshard.build_mesh")
+    assert [b.attrs["step_reused"] for b in builds] == [False, True, True]
+    recs = tracing.tracer().spans("reshard.recompile")
+    assert len(recs) == 3
+    for rec, ev in zip(recs, tr.report.reshards):
+        # the four attributes the benchmark's readers take, on a return too
+        assert {"trace_s", "lower_s", "load_s", "cache_hit"} <= set(rec.attrs)
+        assert rec.attrs["trace_s"] == ev.trace_s
+        assert rec.attrs["load_s"] == ev.load_s
+        assert rec.attrs["step_reused"] is ev.step_reused
+        assert rec.dur_s == ev.recompile_s
