@@ -21,12 +21,64 @@ def dotted(node: ast.AST) -> Optional[str]:
     return None
 
 
-def literal_int_tuple(node: ast.AST) -> Optional[Tuple[int, ...]]:
-    """Resolve a donate/static argnums literal: int, or tuple/list of
-    ints. Anything computed returns None (the rule then skips the
-    site rather than guessing)."""
+class Argnums(tuple):
+    """Argument positions of a jitted callee: the literal ones, as a
+    tuple, and ``tail`` — where a run of computed length starts
+    (``tuple(range(6, 6 + n))``: 6; None when there is none). A program
+    that takes a variable-length group of arrays (``*cache``) donates
+    such a run; how far it reaches is only known where it is called."""
+
+    tail: Optional[int]
+
+    def __new__(cls, fixed=(), tail: Optional[int] = None):
+        self = super().__new__(cls, fixed)
+        self.tail = tail
+        return self
+
+    def __bool__(self) -> bool:
+        return len(self) > 0 or self.tail is not None
+
+    def __or__(self, other: "Argnums") -> "Argnums":
+        tails = [t for t in (self.tail, other.tail) if t is not None]
+        return Argnums(sorted(set(self) | set(other)),
+                       min(tails) if tails else None)
+
+
+def _int_range(call: ast.Call) -> Optional[Argnums]:
+    """``range(b)`` / ``range(a, b)`` with a literal start: the whole
+    run when ``b`` is literal too; when ``b`` is a literal plus a name
+    (or a name alone), the literal part and an open tail behind it."""
+    args = call.args
+    if not 1 <= len(args) <= 2 or call.keywords:
+        return None
+    lo = 0
+    if len(args) == 2:
+        if not (isinstance(args[0], ast.Constant)
+                and isinstance(args[0].value, int)):
+            return None
+        lo = args[0].value
+    hi = args[-1]
+    if isinstance(hi, ast.Constant) and isinstance(hi.value, int):
+        return Argnums(range(lo, hi.value))
+    if isinstance(hi, ast.Name):
+        return Argnums((), tail=lo)
+    if (
+        isinstance(hi, ast.BinOp) and isinstance(hi.op, ast.Add)
+        and isinstance(hi.left, ast.Constant)
+        and isinstance(hi.left.value, int)
+        and isinstance(hi.right, ast.Name)
+    ):
+        return Argnums(range(lo, hi.left.value), tail=max(lo, hi.left.value))
+    return None
+
+
+def literal_int_tuple(node: ast.AST) -> Optional[Argnums]:
+    """Resolve a donate/static argnums expression: an int, a tuple/list
+    of ints, ``tuple(range(...))`` (see :func:`_int_range`), or a sum
+    of those. Anything else returns None (the rule then skips the site
+    rather than guessing)."""
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
-        return (node.value,)
+        return Argnums((node.value,))
     if isinstance(node, (ast.Tuple, ast.List)):
         out = []
         for e in node.elts:
@@ -34,11 +86,21 @@ def literal_int_tuple(node: ast.AST) -> Optional[Tuple[int, ...]]:
                 out.append(e.value)
             else:
                 return None
-        return tuple(out)
+        return Argnums(out)
+    if isinstance(node, ast.BinOp) and isinstance(node.op, ast.Add):
+        left = literal_int_tuple(node.left)
+        right = literal_int_tuple(node.right)
+        return None if left is None or right is None else left | right
+    if (
+        isinstance(node, ast.Call) and dotted(node.func) == "tuple"
+        and len(node.args) == 1 and isinstance(node.args[0], ast.Call)
+        and dotted(node.args[0].func) == "range"
+    ):
+        return _int_range(node.args[0])
     return None
 
 
-def jit_call_argnums(call: ast.Call, kw: str) -> Optional[Tuple[int, ...]]:
+def jit_call_argnums(call: ast.Call, kw: str) -> Optional[Argnums]:
     """``donate_argnums``/``static_argnums`` of a ``jax.jit(...)`` or
     ``partial(jax.jit, ...)`` call, if literal."""
     for k in call.keywords:
@@ -57,7 +119,7 @@ def is_jit_call(call: ast.Call) -> bool:
     return False
 
 
-def decorator_donate_argnums(fn: ast.FunctionDef) -> Optional[Tuple[int, ...]]:
+def decorator_donate_argnums(fn: ast.FunctionDef) -> Optional[Argnums]:
     """donate_argnums from ``@partial(jax.jit, donate_argnums=...)`` /
     ``@jax.jit(donate_argnums=...)`` decorators; None when absent or
     unresolvable."""

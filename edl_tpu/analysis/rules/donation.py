@@ -16,11 +16,22 @@ module only — unresolvable callees are skipped, never guessed):
   ``@jax.jit(donate_argnums=...)``, called by name;
 * a local ``f = jax.jit(g, donate_argnums=...)`` binding;
 * a *program factory*: a module function whose body contains a nested
-  def decorated with literal ``donate_argnums`` (the engine's
-  ``_block_program``/``_prefill_program`` memo pattern) — both direct
-  calls of the factory result and ``self.X = factory(...)`` attributes
-  are tracked;
+  def decorated with ``donate_argnums`` (the engine's
+  ``_block_program``/``_prefill_program`` memo pattern) — direct calls
+  of the factory result, ``self.X = factory(...)`` attributes, and
+  factories BOUND to an attribute (``self.X = partial(factory, ...)``,
+  then ``self.X(tb)(...)`` or ``prog = self.X(tb)``) are tracked;
 * ``self.X = jax.jit(..., donate_argnums=...)`` attributes.
+
+``donate_argnums`` may be computed as far as ``_util.literal_int_tuple``
+resolves it: literal positions plus a run of unknown length
+(``(1, 2, 3, 4) + tuple(range(6, 6 + n))``), which is how a program
+donates a cache it takes as ``*cache``. Such a program is called with
+splats (``prog(params, *old[:4], eosv, *old[4:], key)``): how many
+arguments a splat holds cannot be known here, so a splatted name
+counts as donated when the callee donates anything at or behind the
+splat's earliest position — the engine keeps what it donates and what
+it does not in separate tuples.
 
 The dataflow is per-function: donated names (and the bases of
 ``name[i]`` subscript arguments — the engine passes its device-state
@@ -39,8 +50,10 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from edl_tpu.analysis.core import Finding, ModuleCtx, Rule, register
 from edl_tpu.analysis.rules._util import (
+    Argnums,
     decorator_donate_argnums,
     dotted,
+    PARTIAL_NAMES,
     is_jit_call,
     jit_call_argnums,
     self_attr,
@@ -51,10 +64,10 @@ _TaintKey = Tuple[str, str]  # ("n", name) | ("a", self-attr)
 
 def _donating_params(
     fn: ast.FunctionDef,
-    jitted: Dict[str, Tuple[int, ...]],
-    attrs: Dict[str, Tuple[int, ...]],
+    jitted: Dict[str, Argnums],
+    attrs: Dict[str, Argnums],
     offset: int,
-) -> Tuple[int, ...]:
+) -> Argnums:
     """One-level call summary: which of ``fn``'s positional arguments
     (caller-side indices, ``offset``=1 drops ``self``) are passed
     straight to a donate position of a known jitted call in its body —
@@ -79,7 +92,7 @@ def _donating_params(
     for n in ast.walk(fn):
         if not isinstance(n, ast.Call):
             continue
-        nums: Optional[Tuple[int, ...]] = None
+        nums: Optional[Argnums] = None
         f = n.func
         if isinstance(f, ast.Name):
             nums = jitted.get(f.id)
@@ -100,7 +113,7 @@ def _donating_params(
                 and base.id not in rebound
             ):
                 donated.add(params.index(base.id) - offset)
-    return tuple(sorted(i for i in donated if i >= 0))
+    return Argnums(sorted(i for i in donated if i >= 0))
 
 
 class _Taint:
@@ -113,8 +126,8 @@ class _Taint:
 
 def _module_donation_maps(tree: ast.Module):
     """(jitted defs by name, factories by name, per-class attr map)."""
-    jitted: Dict[str, Tuple[int, ...]] = {}
-    factories: Dict[str, Tuple[int, ...]] = {}
+    jitted: Dict[str, Argnums] = {}
+    factories: Dict[str, Argnums] = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.FunctionDef):
             nums = decorator_donate_argnums(node)
@@ -131,43 +144,51 @@ def _module_donation_maps(tree: ast.Module):
                     factories[node.name] = decorator_donate_argnums(sub)
                     break
 
-    attr_donate: Dict[str, Dict[str, Tuple[int, ...]]] = {}
+    # ``self.X`` that IS a donating program, and (under the key
+    # ``X()``) ``self.X`` that is a bound factory: calling it gives one.
+    # A layout may bind either of two programs to one attribute: what
+    # any of them donates counts
+    attr_donate: Dict[str, Dict[str, Argnums]] = {}
     for cls in tree.body:
         if not isinstance(cls, ast.ClassDef):
             continue
-        attrs: Dict[str, Tuple[int, ...]] = {}
+        attrs: Dict[str, Argnums] = {}
         for n in ast.walk(cls):
             if not (isinstance(n, ast.Assign) and isinstance(n.value, ast.Call)):
                 continue
             nums = None
+            bound = ""
             callee = dotted(n.value.func)
             if callee in factories:
                 nums = factories[callee]
             elif is_jit_call(n.value):
                 nums = jit_call_argnums(n.value, "donate_argnums")
+            elif callee in PARTIAL_NAMES and n.value.args:
+                nums = factories.get(dotted(n.value.args[0]))
+                bound = "()"
             if not nums:
                 continue
             for t in n.targets:
                 a = self_attr(t)
                 if a:
-                    attrs[a] = nums
+                    attrs[a + bound] = attrs.get(a + bound, Argnums()) | nums
         if attrs:
             attr_donate[cls.name] = attrs
 
     # one-level helper summaries: `def split(buf): a, b = step(buf); ...`
     # donates its caller's argument even though the jit call is inside
-    helper_fns: Dict[str, Tuple[int, ...]] = {}
+    helper_fns: Dict[str, Argnums] = {}
     for node in tree.body:
         if isinstance(node, ast.FunctionDef) and node.name not in jitted:
             nums = _donating_params(node, jitted, {}, offset=0)
             if nums:
                 helper_fns[node.name] = nums
-    helper_methods: Dict[str, Dict[str, Tuple[int, ...]]] = {}
+    helper_methods: Dict[str, Dict[str, Argnums]] = {}
     for cls in tree.body:
         if not isinstance(cls, ast.ClassDef):
             continue
         attrs = attr_donate.get(cls.name, {})
-        meths: Dict[str, Tuple[int, ...]] = {}
+        meths: Dict[str, Argnums] = {}
         for m in cls.body:
             if isinstance(m, ast.FunctionDef) and m.name not in jitted:
                 nums = _donating_params(m, jitted, attrs, offset=1)
@@ -249,7 +270,7 @@ class _FnFlow:
         for child in ast.iter_child_nodes(node):
             self.eval(child)
 
-    def _callee_argnums(self, call: ast.Call) -> Tuple[Optional[Tuple[int, ...]], str]:
+    def _callee_argnums(self, call: ast.Call) -> Tuple[Optional[Argnums], str]:
         f = call.func
         if isinstance(f, ast.Name):
             if f.id in self.jitted:
@@ -257,6 +278,9 @@ class _FnFlow:
             if f.id in self.helper_fns:
                 return self.helper_fns[f.id], f.id
             return None, ""
+        if isinstance(f, ast.Call):  # factory(...)(args), self.X(...)(args)
+            nums = self._made_by(f)
+            return nums, (dotted(f.func) or "") + "(...)" if nums else ""
         a = self_attr(f)
         if a is not None:
             if a in self.attrs:
@@ -264,6 +288,26 @@ class _FnFlow:
             if a in self.helper_methods:
                 return self.helper_methods[a], f"self.{a}"
         return None, ""
+
+    def _made_by(self, v: ast.Call) -> Optional[Argnums]:
+        """What the program that ``v`` builds donates: ``v`` a call of
+        a factory, or of a ``self`` attribute a factory is bound to."""
+        callee = dotted(v.func)
+        if callee in self.factories:
+            return self.factories[callee]
+        a = self_attr(v.func)
+        return self.attrs.get(a + "()") if a is not None else None
+
+    @staticmethod
+    def _key_of(a: ast.AST) -> Optional[_TaintKey]:
+        """The name an argument stands for: itself, or the base of a
+        subscript or slice of it."""
+        if isinstance(a, ast.Subscript):
+            a = a.value
+        if isinstance(a, ast.Name):
+            return ("n", a.id)
+        sa = self_attr(a)
+        return ("a", sa) if sa is not None else None
 
     def _eval_call(self, call: ast.Call) -> None:
         nums, callee = self._callee_argnums(call)
@@ -274,28 +318,22 @@ class _FnFlow:
             self.eval(kw.value)
         if not nums:
             return
-        # positional donation only; a *args splat makes positions
-        # unknowable, so skip tainting rather than mis-indexing
-        if any(isinstance(a, ast.Starred) for a in call.args):
-            return
-        for i in nums:
-            if i >= len(call.args):
-                continue
-            a = call.args[i]
+        # positional donation only. Up to the first splat a position
+        # is exact; a splat holds an unknown number of arguments, so
+        # it counts as donated when anything at or behind its earliest
+        # position is, and a plain argument behind a splat is skipped
+        # rather than mis-indexed
+        exact, lo = True, 0  # lo: plain arguments so far
+        for a in call.args:
             key: Optional[_TaintKey] = None
-            if isinstance(a, ast.Name):
-                key = ("n", a.id)
+            if isinstance(a, ast.Starred):
+                exact = False
+                if nums.tail is not None or any(n >= lo for n in nums):
+                    key = self._key_of(a.value)
             else:
-                sa = self_attr(a)
-                if sa is not None:
-                    key = ("a", sa)
-                elif isinstance(a, ast.Subscript):
-                    if isinstance(a.value, ast.Name):
-                        key = ("n", a.value.id)
-                    else:
-                        sb = self_attr(a.value)
-                        if sb is not None:
-                            key = ("a", sb)
+                if exact and lo in nums:
+                    key = self._key_of(a)
+                lo += 1
             if key is not None:
                 self.taint[key] = _Taint(call.lineno, callee)
 
@@ -330,9 +368,7 @@ class _FnFlow:
         if is_jit_call(v):
             nums = jit_call_argnums(v, "donate_argnums")
         else:
-            callee = dotted(v.func)
-            if callee in self.factories:
-                nums = self.factories[callee]
+            nums = self._made_by(v)
         if not nums:
             return
         for t in stmt.targets:

@@ -973,8 +973,8 @@ def horizon_scan(
 # ratio is <= 1 and an idempotent frozen-lane rewrite is exact
 # (ratio 1). Exact greedy token identity cannot survive quantization;
 # the serving engine keeps ``kv_quant="off"`` byte-identical to the
-# unquantized path (these branches are trace-time, the "off" programs
-# and memo keys are untouched) and gates the quantized path on output
+# unquantized path (these branches are trace-time: "off" traces the
+# program it always did) and gates the quantized path on output
 # tolerance + the speculative acceptance EMA (engine-side).
 
 _KVQ_QMAX = {"int8": 127.0, "int4": 7.0}
@@ -1093,17 +1093,15 @@ def decode_step_slots_paged(
     tok: jnp.ndarray,
     pos: jnp.ndarray,
     table: jnp.ndarray,
-    kc: jnp.ndarray,
-    vc: jnp.ndarray,
+    cache: Tuple[jnp.ndarray, ...],
     cfg: LlamaConfig,
     block_size: int,
     kv_quant: str = "off",
-    ks: Optional[jnp.ndarray] = None,
-    vs: Optional[jnp.ndarray] = None,
 ):
     """One slot-decode step over the paged pool. tok/pos [B] int32;
-    table [B, M] int32 physical block ids; kc/vc
-    [L, n_blocks, block_size, KV, hd]. Returns (logits [B, V], kc, vc).
+    table [B, M] int32 physical block ids; ``cache`` the pool's arrays,
+    (kc, vc) each [L, n_blocks, block_size, KV, hd]. Returns
+    (logits [B, V], cache), the cache with the arity it came in.
 
     Per-row math is IDENTICAL to :func:`decode_step_slots` — the only
     differences are the scatter target (the row's CURRENT block at
@@ -1116,13 +1114,14 @@ def decode_step_slots_paged(
     tables cover every written position — the contract
     tests/test_paged_kv.py pins at H ∈ {1, 4, 16}.
 
-    ``kv_quant`` != "off" switches the pool to quantized storage (int8
-    or packed int4 entries + per-block-per-kv-head f32 scales ``ks``/
-    ``vs`` [L, nb, KV], see the section comment): lane writes quantize
-    on the fly, the gather dequantizes via the factored scale multiply,
-    and the returned tuple grows ``(ks, vs)``. The "off" path is
+    ``kv_quant`` != "off" says the pool is quantized storage: ``cache``
+    is (kc, vc, ks, vs), int8 or packed int4 entries + per-block-per-
+    kv-head f32 scales ``ks``/``vs`` [L, nb, KV] (see the section
+    comment); lane writes quantize on the fly and the gather
+    dequantizes via the factored scale multiply. The "off" path is
     byte-identical to before the knob existed — the branch is
     trace-time."""
+    kc, vc, ks, vs = (*cache, None, None)[:4]  # no scale planes when off
     b = tok.shape[0]
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     groups = h // kvh
@@ -1188,9 +1187,7 @@ def decode_step_slots_paged(
     with jax.named_scope("head"):
         x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         logits = _matw(x[:, 0], params["lm_head"]).astype(jnp.float32)
-    if quant:
-        return logits, kc, vc, ks, vs
-    return logits, kc, vc
+    return logits, (kc, vc, ks, vs)[:len(cache)]
 
 
 def decode_horizon_slots_paged(
@@ -1201,8 +1198,7 @@ def decode_horizon_slots_paged(
     rem: jnp.ndarray,
     eosv: jnp.ndarray,
     table: jnp.ndarray,
-    kc: jnp.ndarray,
-    vc: jnp.ndarray,
+    cache: Tuple[jnp.ndarray, ...],
     cfg: LlamaConfig,
     block_size: int,
     horizon: int,
@@ -1210,8 +1206,6 @@ def decode_horizon_slots_paged(
     temperature=None,
     sampling: bool = False,
     kv_quant: str = "off",
-    ks: Optional[jnp.ndarray] = None,
-    vs: Optional[jnp.ndarray] = None,
 ):
     """The paged twin of :func:`decode_horizon_slots`: a fused horizon
     of ``horizon`` :func:`decode_step_slots_paged` steps with the SAME
@@ -1221,22 +1215,16 @@ def decode_horizon_slots_paged(
     every position the horizon can write before dispatching, so no
     mid-horizon allocation is ever needed on device.
 
-    Under ``kv_quant`` != "off" the scan carry grows the scale planes
-    and the return tuple ends in ``(..., kc, vc, ks, vs)``."""
-    quant = kv_quant != "off"
+    ``cache`` rides the scan carry whole (the scale planes with the
+    pools under ``kv_quant``) and comes back in one piece: ``(toks
+    [B, H], tok, pos, active, rem, cache)``."""
 
     def step(carry, k):
-        if quant:
-            tok, pos, active, rem, kc, vc, ks, vs = carry
-            logits, kc, vc, ks, vs = decode_step_slots_paged(
-                params, tok, pos, table, kc, vc, cfg, block_size,
-                kv_quant=kv_quant, ks=ks, vs=vs,
-            )
-        else:
-            tok, pos, active, rem, kc, vc = carry
-            logits, kc, vc = decode_step_slots_paged(
-                params, tok, pos, table, kc, vc, cfg, block_size
-            )
+        tok, pos, active, rem, cache = carry
+        logits, cache = decode_step_slots_paged(
+            params, tok, pos, table, cache, cfg, block_size,
+            kv_quant=kv_quant,
+        )
         with jax.named_scope("head"):
             if sampling:
                 nxt = jax.random.categorical(
@@ -1250,24 +1238,15 @@ def decode_horizon_slots_paged(
         rem = jnp.where(active, rem - 1, rem)
         hit = active & (eosv >= 0) & (nxt == eosv)
         active = active & ~hit & (rem > 0)
-        if quant:
-            return (nxt, pos, active, rem, kc, vc, ks, vs), out
-        return (nxt, pos, active, rem, kc, vc), out
+        return (nxt, pos, active, rem, cache), out
 
     keys = jax.random.split(
         key if key is not None else jax.random.PRNGKey(0), horizon
     )
-    if quant:
-        (tok, pos, active, rem, kc, vc, ks, vs), outs = jax.lax.scan(
-            step, (tok, pos, active, rem, kc, vc, ks, vs), keys
-        )
-        return (
-            jnp.swapaxes(outs, 0, 1), tok, pos, active, rem, kc, vc, ks, vs
-        )
-    (tok, pos, active, rem, kc, vc), outs = jax.lax.scan(
-        step, (tok, pos, active, rem, kc, vc), keys
+    (tok, pos, active, rem, cache), outs = jax.lax.scan(
+        step, (tok, pos, active, rem, tuple(cache)), keys
     )
-    return jnp.swapaxes(outs, 0, 1), tok, pos, active, rem, kc, vc
+    return jnp.swapaxes(outs, 0, 1), tok, pos, active, rem, cache
 
 
 def prefill_paged(
@@ -1276,13 +1255,10 @@ def prefill_paged(
     start,
     last,
     table: jnp.ndarray,
-    kc: jnp.ndarray,
-    vc: jnp.ndarray,
+    cache: Tuple[jnp.ndarray, ...],
     cfg: LlamaConfig,
     block_size: int,
     kv_quant: str = "off",
-    ks: Optional[jnp.ndarray] = None,
-    vs: Optional[jnp.ndarray] = None,
 ):
     """Prefill one CHUNK of one slot's prompt into the paged pool.
 
@@ -1291,8 +1267,9 @@ def prefill_paged(
     same bucket contract as :func:`prefill_padded`); positions below
     ``start`` must already be resident in the pool (earlier chunks, or
     shared prefix blocks another request prefilled). ``table`` [M] is
-    the ONE slot's block-table row. Returns (logits [1, V] at ``last``,
-    kc, vc).
+    the ONE slot's block-table row; ``cache`` is the pool's arrays as
+    :func:`decode_step_slots_paged` takes them. Returns (logits [1, V]
+    at ``last``, cache).
 
     This one function serves admission prefill (start = prefix-hit
     length), CHUNKED prefill of long prompts (each bounded chunk is a
@@ -1306,8 +1283,8 @@ def prefill_paged(
 
     Under ``kv_quant`` != "off" the whole chunk quantizes on the fly
     (one :func:`_kvq_store` per layer per plane — the chunk's writes to
-    a block land together, so its scale converges in one step) and the
-    return tuple grows ``(ks, vs)``."""
+    a block land together, so its scale converges in one step)."""
+    kc, vc, ks, vs = (*cache, None, None)[:4]
     b, tb = tokens.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     groups = h // kvh
@@ -1367,9 +1344,7 @@ def prefill_paged(
         x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         xl = x[jnp.arange(b), last]  # [1, d] — the chunk's last real token
         logits = _matw(xl, params["lm_head"]).astype(jnp.float32)
-    if quant:
-        return logits, kc, vc, ks, vs
-    return logits, kc, vc
+    return logits, (kc, vc, ks, vs)[:len(cache)]
 
 
 # -- inference: speculative decoding (draft-verify) --------------------------
@@ -1485,13 +1460,14 @@ def verify_step_slots(
     return _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc)
 
 
-def _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc):
+def _spec_accept(tok, draft, out, pos, active, rem, eosv, *cache):
     """On-device acceptance shared by the contiguous and paged verify
     steps: commit the longest draft prefix matching greedy argmax plus
     one bonus token, truncated by the remaining budget and cut after
     the first emitted EOS. Pure slot-state bookkeeping — the K/V for
     every committed position was already written by the verify lanes
-    (committed lane j's input token IS the matched draft)."""
+    (committed lane j's input token IS the matched draft); ``cache``
+    is handed back behind the slot state as it came."""
     b, d = draft.shape
     k = d + 1
     rows = jnp.arange(b)
@@ -1523,7 +1499,7 @@ def _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc):
     rem = rem - e
     hit = jnp.any(eos_emitted & emit, axis=1)
     active = active & ~hit & (rem > 0)
-    return outs, tok, pos, active, rem, kc, vc
+    return (outs, tok, pos, active, rem, *cache)
 
 
 def verify_step_slots_paged(
@@ -1535,18 +1511,17 @@ def verify_step_slots_paged(
     rem: jnp.ndarray,
     eosv: jnp.ndarray,
     table: jnp.ndarray,
-    kc: jnp.ndarray,
-    vc: jnp.ndarray,
+    cache: Tuple[jnp.ndarray, ...],
     cfg: LlamaConfig,
     block_size: int,
     kv_quant: str = "off",
-    ks: Optional[jnp.ndarray] = None,
-    vs: Optional[jnp.ndarray] = None,
 ):
     """The paged twin of :func:`verify_step_slots`: K = D+1 query lanes
     per row routed through the [B, M] block table, same on-device
-    acceptance. Lane writes target (table[row, (pos+j) // bs],
-    (pos+j) % bs); out-of-table lanes and uncovered positions route to
+    acceptance; ``cache`` is the pool's arrays as
+    :func:`decode_step_slots_paged` takes them, and the result is
+    ``(outs [B, K], tok, pos, active, rem, cache)``. Lane writes
+    target (table[row, (pos+j) // bs], (pos+j) % bs); out-of-table lanes and uncovered positions route to
     the scratch block (collisions there are never read). The engine
     covers every position the accepted run can commit before
     dispatching (``_ensure_cover`` sized to max(horizon, K)), so
@@ -1558,7 +1533,8 @@ def verify_step_slots_paged(
     :func:`_kvq_store` per plane per layer (rejected-lane garbage can
     only GROW a resident block's scale — a monotone rescale, never a
     corruption; the garbage values themselves are overwritten before
-    their positions unmask) and the return tuple grows ``(ks, vs)``."""
+    their positions unmask)."""
+    kc, vc, ks, vs = (*cache, None, None)[:4]
     b, d = draft.shape
     k = d + 1
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -1626,10 +1602,9 @@ def verify_step_slots_paged(
         x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
         logits = _matw(x, params["lm_head"]).astype(jnp.float32)
         out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    acc = _spec_accept(tok, draft, out, pos, active, rem, eosv, kc, vc)
-    if quant:
-        return acc + (ks, vs)
-    return acc
+    return _spec_accept(tok, draft, out, pos, active, rem, eosv) + (
+        (kc, vc, ks, vs)[:len(cache)],
+    )
 
 
 def generate(

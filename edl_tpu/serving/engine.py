@@ -2,9 +2,9 @@
 over the llama KV-cache path, driven in fused multi-step HORIZON
 blocks with a double-buffered async host pipeline.
 
-The decode roofline is HBM-bound and batch-sensitive (BENCH_r05: 0.73
-of roofline at B=1 vs 0.93 at B=32): a one-request-at-a-time server
-streams the full weight set per token for ONE token. This engine keeps
+The decode roofline is HBM-bound and batch-sensitive: a
+one-request-at-a-time server streams the full weight set per token for
+ONE token. This engine keeps
 a fixed table of ``max_slots`` KV slots and decodes every active slot
 in one batched step, prefill-inserting new requests into free slots and
 evicting finished ones BETWEEN blocks — requests are the elastic
@@ -47,6 +47,21 @@ mirroring ``llama._generate_program``:
   evict-oldest at the cap — a cache-clear here used to drop the hot
   decode program mid-traffic), so engines are cheap to construct and
   tests/harnesses reuse compiles.
+
+**The KV layout is decided in one place.** Three layouts are served:
+contiguous (one row a slot), paged (``block_size > 0``: a pool of
+blocks behind per-slot tables) and paged + quantized (``kv_quant``:
+int8 / int4 pools with scale planes). What the cache's arrays ARE is
+one tuple, ``self._cache``, allocated from one spec a layout; the
+engine never looks inside it: every program takes it as ``*cache``,
+donates it by position and hands it back, and every dispatch rebinds
+it whole. WHICH programs serve the layout, ``__init__`` chooses once;
+the operands a paged program wants in front of the cache (a block
+table, a ``start``) come from ``_block_table`` / ``_slot_table``,
+empty for the contiguous layout. So each kind of dispatch (decode
+block, verify, final prefill piece, prefill chunk, block copy) is
+written once, and ``self._paged`` is asked only where the HOST's
+bookkeeping differs (admission by blocks, tables, prefix cache, frees).
 
 Admission lands on BLOCK boundaries (``InterleavePolicy.block_budget``
 — the drain-to-admit budget): when the queue is non-empty but no slot
@@ -279,49 +294,72 @@ def _prefill_program(cfg, tb: int, sampling: bool):
     return _memo(("prefill", cfg, tb, sampling), make)
 
 
+def _paged_layout(kv_quant: str):
+    """(arrays in the paged cache tuple, suffix of its programs' names):
+    the K and V pools, and under ``kv_quant`` their scale planes too."""
+    return (2, "") if kv_quant == "off" else (4, "_q")
+
+
 def _block_program_paged(
     cfg: llama.LlamaConfig, b: int, nb: int, m: int, bs: int,
-    horizon: int, sampling: bool,
+    horizon: int, sampling: bool, kv_quant: str = "off",
 ):
     """The paged twin of :func:`_block_program`: same carries plus the
-    [B, M] block table (read-only, NOT donated — the host rebuilds it
-    from its allocator truth each dispatch); kc/vc are the block POOL
-    [L, nb, bs, KV, hd], donated under the same stale-reference
-    contract."""
+    [B, M] block table in front of the cache (read-only, NOT donated —
+    the host rebuilds it from its allocator truth each dispatch). The
+    cache is the block POOL, (kc, vc) [L, nb, bs, KV, hd], under
+    ``kv_quant`` int8 (packed int4 under the same dtype) with the scale
+    planes (ks, vs) [L, nb, KV] behind it; every array of it is donated
+    under the same stale-reference contract — a stale scale reference
+    is as unsafe as a stale pool. ``kv_quant="off"`` traces the plain
+    paged program."""
+    n, q = _paged_layout(kv_quant)
 
     def make():
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 7, 8))
-        @_named("edl_serve_block_paged")
-        def run(params, tok, pos, active, rem, eosv, table, kc, vc,
-                key, temperature):
-            return llama.decode_horizon_slots_paged(
-                params, tok, pos, active, rem, eosv, table, kc, vc, cfg,
-                block_size=bs, horizon=horizon, key=key,
-                temperature=temperature, sampling=sampling,
+        @partial(jax.jit, donate_argnums=(1, 2, 3, 4) + tuple(range(7, 7 + n)))
+        @_named("edl_serve_block_paged" + q)
+        def run(params, tok, pos, active, rem, eosv, table, *rest):
+            *cache, key, temperature = rest
+            toks, tok, pos, active, rem, cache = (
+                llama.decode_horizon_slots_paged(
+                    params, tok, pos, active, rem, eosv, table, tuple(cache),
+                    cfg, block_size=bs, horizon=horizon, key=key,
+                    temperature=temperature, sampling=sampling,
+                    kv_quant=kv_quant,
+                )
             )
+            # no counters from this model's paged block: the arity of
+            # :func:`_block_program`, so one host call serves both
+            return (toks, tok, pos, active, rem, *cache, {})
 
         return run
 
-    return _memo(("block-paged", cfg, b, nb, m, bs, horizon, sampling), make)
+    return _memo(
+        ("block-paged", kv_quant, cfg, b, nb, m, bs, horizon, sampling), make
+    )
 
 
 def _prefill_paged_program(cfg: llama.LlamaConfig, tb: int, bs: int,
-                           sampling: bool):
+                           sampling: bool, kv_quant: str = "off"):
     """Final-piece paged prefill: run the bucketed tail of a prompt
     (logical positions ``start .. start+last``) through
     ``llama.prefill_paged``, sample the first token, and reset the
     slot's device decode state — the paged twin of
-    :func:`_prefill_program`. Earlier positions (prefix-cache hits or
+    :func:`_prefill_program`, with ``start`` and the slot's table row
+    in front of the cache. Earlier positions (prefix-cache hits or
     previously dispatched chunks) are already resident in the pool."""
+    n, q = _paged_layout(kv_quant)
 
     def make():
-        @partial(jax.jit, donate_argnums=(7, 8, 9, 10, 11, 12, 13))
-        @_named(f"edl_serve_prefill_paged_{tb}")
-        def run(params, tokens, start, last, slot, max_new, eos,
-                tok, pos, active, rem, eosv, kc, vc, table,
-                key, temperature):
-            logits, kc, vc = llama.prefill_paged(
-                params, tokens, start, last, table, kc, vc, cfg, bs
+        @partial(jax.jit,
+                 donate_argnums=tuple(range(6, 11)) + tuple(range(13, 13 + n)))
+        @_named(f"edl_serve_prefill_paged{q}_{tb}")
+        def run(params, tokens, last, slot, max_new, eos,
+                tok, pos, active, rem, eosv, start, table, *rest):
+            *cache, key, temperature = rest
+            logits, cache = llama.prefill_paged(
+                params, tokens, start, last, table, tuple(cache), cfg, bs,
+                kv_quant=kv_quant,
             )
             t0 = _first_token(logits, key, temperature, sampling)
             tok = tok.at[slot].set(t0)
@@ -330,54 +368,63 @@ def _prefill_paged_program(cfg: llama.LlamaConfig, tb: int, bs: int,
             active = active.at[slot].set(~hit & (max_new > 1))
             rem = rem.at[slot].set(jnp.maximum(max_new - 1, 0))
             eosv = eosv.at[slot].set(eos)
-            return t0, tok, pos, active, rem, eosv, kc, vc
+            return (t0, tok, pos, active, rem, eosv, *cache)
 
         return run
 
-    return _memo(("prefill-paged", cfg, tb, bs, sampling), make)
+    return _memo(("prefill-paged", kv_quant, cfg, tb, bs, sampling), make)
 
 
-def _prefill_chunk_program(cfg: llama.LlamaConfig, c: int, bs: int):
+def _prefill_chunk_program(cfg: llama.LlamaConfig, c: int, bs: int,
+                           kv_quant: str = "off"):
     """One NON-final prefill chunk: write ``c`` prompt tokens' K/V into
-    the pool at ``start .. start+c-1`` and return only the pools — no
+    the pool at ``start .. start+c-1`` and return only the cache — no
     logits consumed, no slot state touched, so a long prompt advances
     one bounded dispatch at a time between decode blocks instead of
     one monolithic prefill that starves running slots."""
+    n, q = _paged_layout(kv_quant)
 
     def make():
-        @partial(jax.jit, donate_argnums=(3, 4))
-        @_named(f"edl_serve_prefill_chunk_{c}")
-        def run(params, tokens, start, kc, vc, table):
-            _, kc, vc = llama.prefill_paged(
-                params, tokens, start, jnp.int32(c - 1), table, kc, vc,
-                cfg, bs,
+        @partial(jax.jit, donate_argnums=tuple(range(4, 4 + n)))
+        @_named(f"edl_serve_prefill_chunk{q}_{c}")
+        def run(params, tokens, start, table, *cache):
+            _, cache = llama.prefill_paged(
+                params, tokens, start, jnp.int32(c - 1), table, cache,
+                cfg, bs, kv_quant=kv_quant,
             )
-            return kc, vc
+            return cache
 
         return run
 
-    return _memo(("prefill-chunk", cfg, c, bs), make)
+    return _memo(("prefill-chunk", kv_quant, cfg, c, bs), make)
 
 
-def _copy_block_program(cfg: llama.LlamaConfig, nb: int, bs: int):
-    """Copy one physical KV block (``src`` → ``dst``, traced indices)
-    in both pools — the copy-on-write primitive: a slot about to write
-    into a SHARED block gets a private copy first, so prefix-cache
-    blocks are immutable while referenced."""
+def _copy_block_program(cfg: llama.LlamaConfig, nb: int, bs: int,
+                        kv_quant: str = "off"):
+    """(*cache, src, dst) -> cache: copy one physical KV block (``src``
+    → ``dst``, traced indices) in EVERY array of the cache — the
+    copy-on-write primitive: a slot about to write into a SHARED block
+    gets a private copy first, so prefix-cache blocks are immutable
+    while referenced. Under ``kv_quant`` the block's SCALES move with
+    its values — a copied block re-quantized under the wrong scale
+    would silently rescale the whole shared prefix."""
+    n, q = _paged_layout(kv_quant)
 
     def make():
-        @partial(jax.jit, donate_argnums=(0, 1))
-        @_named("edl_serve_block_copy")
-        def run(kc, vc, src, dst):
-            kb = jax.lax.dynamic_slice_in_dim(kc, src, 1, axis=1)
-            vb = jax.lax.dynamic_slice_in_dim(vc, src, 1, axis=1)
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, kb, dst, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, vb, dst, axis=1)
-            return kc, vc
+        @partial(jax.jit, donate_argnums=tuple(range(n)))
+        @_named("edl_serve_block_copy" + q)
+        def run(*rest):
+            *cache, src, dst = rest
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    c, jax.lax.dynamic_slice_in_dim(c, src, 1, axis=1),
+                    dst, axis=1)
+                for c in cache
+            )
 
         return run
 
-    return _memo(("blockcopy", cfg, nb, bs), make)
+    return _memo(("blockcopy", kv_quant, cfg, nb, bs), make)
 
 
 def _verify_program(cfg: llama.LlamaConfig, b: int, s: int, d: int):
@@ -403,159 +450,27 @@ def _verify_program(cfg: llama.LlamaConfig, b: int, s: int, d: int):
 
 
 def _verify_program_paged(
-    cfg: llama.LlamaConfig, b: int, nb: int, m: int, bs: int, d: int
+    cfg: llama.LlamaConfig, b: int, nb: int, m: int, bs: int, d: int,
+    kv_quant: str = "off",
 ):
     """The paged twin of :func:`_verify_program`: same carries plus
-    the [B, M] block table (read-only, NOT donated, same as the paged
-    block program)."""
+    the [B, M] block table in front of the cache (read-only, NOT
+    donated, same as the paged block program)."""
+    n, q = _paged_layout(kv_quant)
 
     def make():
-        @partial(jax.jit, donate_argnums=(1, 3, 4, 5, 8, 9))
-        @_named("edl_serve_verify_paged")
-        def run(params, tok, draft, pos, active, rem, eosv, table, kc, vc):
-            return llama.verify_step_slots_paged(
-                params, tok, draft, pos, active, rem, eosv, table, kc, vc,
-                cfg, block_size=bs,
+        @partial(jax.jit, donate_argnums=(1, 3, 4, 5) + tuple(range(8, 8 + n)))
+        @_named("edl_serve_verify_paged" + q)
+        def run(params, tok, draft, pos, active, rem, eosv, table, *cache):
+            *state, cache = llama.verify_step_slots_paged(
+                params, tok, draft, pos, active, rem, eosv, table, cache,
+                cfg, block_size=bs, kv_quant=kv_quant,
             )
+            return (*state, *cache)
 
         return run
 
-    return _memo(("verify-paged", cfg, b, nb, m, bs, d), make)
-
-
-# -- quantized-KV program twins (kv_quant != "off") --------------------------
-#
-# Separate factories under separate memo keys, NOT a parameter on the
-# existing ones: the off path's keys and traced programs must stay
-# byte-identical to pre-quantization behavior (tests pin the memo-key
-# set and dispatch counters). Each twin threads the per-block scale
-# planes ks/vs [L, nb, KV] through the donation contract exactly like
-# the pools — a stale scale reference is as unsafe as a stale pool.
-
-
-def _block_program_paged_q(
-    cfg: llama.LlamaConfig, b: int, nb: int, m: int, bs: int,
-    horizon: int, sampling: bool, kv_quant: str,
-):
-    """Quantized-KV twin of :func:`_block_program_paged`: the pools are
-    int8 (packed int4 under the same dtype) and the carries grow the
-    scale planes, donated alongside them."""
-
-    def make():
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4, 7, 8, 9, 10))
-        @_named("edl_serve_block_paged_q")
-        def run(params, tok, pos, active, rem, eosv, table, kc, vc, ks, vs,
-                key, temperature):
-            return llama.decode_horizon_slots_paged(
-                params, tok, pos, active, rem, eosv, table, kc, vc, cfg,
-                block_size=bs, horizon=horizon, key=key,
-                temperature=temperature, sampling=sampling,
-                kv_quant=kv_quant, ks=ks, vs=vs,
-            )
-
-        return run
-
-    return _memo(
-        ("block-paged-q", kv_quant, cfg, b, nb, m, bs, horizon, sampling),
-        make,
-    )
-
-
-def _prefill_paged_program_q(
-    cfg: llama.LlamaConfig, tb: int, bs: int, sampling: bool, kv_quant: str
-):
-    """Quantized-KV twin of :func:`_prefill_paged_program`."""
-
-    def make():
-        @partial(jax.jit, donate_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
-        @_named(f"edl_serve_prefill_paged_q_{tb}")
-        def run(params, tokens, start, last, slot, max_new, eos,
-                tok, pos, active, rem, eosv, kc, vc, ks, vs, table,
-                key, temperature):
-            logits, kc, vc, ks, vs = llama.prefill_paged(
-                params, tokens, start, last, table, kc, vc, cfg, bs,
-                kv_quant=kv_quant, ks=ks, vs=vs,
-            )
-            t0 = _first_token(logits, key, temperature, sampling)
-            tok = tok.at[slot].set(t0)
-            pos = pos.at[slot].set(start + last + 1)
-            hit = (eos >= 0) & (t0 == eos)
-            active = active.at[slot].set(~hit & (max_new > 1))
-            rem = rem.at[slot].set(jnp.maximum(max_new - 1, 0))
-            eosv = eosv.at[slot].set(eos)
-            return t0, tok, pos, active, rem, eosv, kc, vc, ks, vs
-
-        return run
-
-    return _memo(("prefill-paged-q", kv_quant, cfg, tb, bs, sampling), make)
-
-
-def _prefill_chunk_program_q(
-    cfg: llama.LlamaConfig, c: int, bs: int, kv_quant: str
-):
-    """Quantized-KV twin of :func:`_prefill_chunk_program`."""
-
-    def make():
-        @partial(jax.jit, donate_argnums=(3, 4, 5, 6))
-        @_named(f"edl_serve_prefill_chunk_q_{c}")
-        def run(params, tokens, start, kc, vc, ks, vs, table):
-            _, kc, vc, ks, vs = llama.prefill_paged(
-                params, tokens, start, jnp.int32(c - 1), table, kc, vc,
-                cfg, bs, kv_quant=kv_quant, ks=ks, vs=vs,
-            )
-            return kc, vc, ks, vs
-
-        return run
-
-    return _memo(("prefill-chunk-q", kv_quant, cfg, c, bs), make)
-
-
-def _copy_block_program_q(
-    cfg: llama.LlamaConfig, nb: int, bs: int, kv_quant: str
-):
-    """Quantized-KV twin of :func:`_copy_block_program`: the CoW copy
-    must carry the block's SCALES with its values — a copied block
-    re-quantized under the wrong scale would silently rescale the
-    whole shared prefix."""
-
-    def make():
-        @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-        @_named("edl_serve_block_copy_q")
-        def run(kc, vc, ks, vs, src, dst):
-            kb = jax.lax.dynamic_slice_in_dim(kc, src, 1, axis=1)
-            vb = jax.lax.dynamic_slice_in_dim(vc, src, 1, axis=1)
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, kb, dst, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, vb, dst, axis=1)
-            ksb = jax.lax.dynamic_slice_in_dim(ks, src, 1, axis=1)
-            vsb = jax.lax.dynamic_slice_in_dim(vs, src, 1, axis=1)
-            ks = jax.lax.dynamic_update_slice_in_dim(ks, ksb, dst, axis=1)
-            vs = jax.lax.dynamic_update_slice_in_dim(vs, vsb, dst, axis=1)
-            return kc, vc, ks, vs
-
-        return run
-
-    return _memo(("blockcopy-q", kv_quant, cfg, nb, bs), make)
-
-
-def _verify_program_paged_q(
-    cfg: llama.LlamaConfig, b: int, nb: int, m: int, bs: int, d: int,
-    kv_quant: str,
-):
-    """Quantized-KV twin of :func:`_verify_program_paged`."""
-
-    def make():
-        @partial(jax.jit, donate_argnums=(1, 3, 4, 5, 8, 9, 10, 11))
-        @_named("edl_serve_verify_paged_q")
-        def run(params, tok, draft, pos, active, rem, eosv, table,
-                kc, vc, ks, vs):
-            return llama.verify_step_slots_paged(
-                params, tok, draft, pos, active, rem, eosv, table, kc, vc,
-                cfg, block_size=bs, kv_quant=kv_quant, ks=ks, vs=vs,
-            )
-
-        return run
-
-    return _memo(("verify-paged-q", kv_quant, cfg, b, nb, m, bs, d), make)
+    return _memo(("verify-paged", kv_quant, cfg, b, nb, m, bs, d), make)
 
 
 class SpecAcceptGuard:
@@ -745,10 +660,25 @@ class ContinuousBatchingEngine:
                 "alone: block_size, prefix_cache, prefill_chunk, kv_quant "
                 "and spec_k are the dense decoder's (llama.LlamaConfig)"
             )
+        # quantized paged KV (kv_quant != "off"): the pool stores int8
+        # (or packed int4) entries + per-block-per-kv-head f32 scales;
+        # decode moves 2-4x fewer cache bytes. "off" is the identity
+        # lane — byte-identical programs, no scale planes allocated.
+        if kv_quant not in ("off", "int8", "int4"):
+            raise ValueError(
+                f"kv_quant must be one of off/int8/int4, got {kv_quant!r}"
+            )
+        if kv_quant != "off" and not block_size > 0:
+            raise ValueError(
+                "kv_quant requires the paged KV cache (block_size > 0)"
+            )
+        self.kv_quant = str(kv_quant)
         # paged KV mode (block_size > 0): the cache is a pool of
         # fixed-size blocks addressed through per-slot block tables —
         # HBM scales with RESIDENT tokens, not slots x max_len, and
-        # admission gates on free blocks instead of free slots
+        # admission gates on free blocks instead of free slots.
+        # `_cache_spec` is what the cache's arrays are, ((shape, dtype),
+        # ...): all `_alloc_device_state` needs to know of the layout
         self._paged = block_size > 0
         if self._paged:
             if max_len % block_size != 0:
@@ -772,28 +702,25 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     f"prefill_chunk must be >= 0, got {prefill_chunk}"
                 )
+            # a block POOL for K and for V, not a slot slab. Quantized:
+            # int8 entries (int4 packs two per byte along head_dim, and
+            # raises here on an odd one) + per-block-per-kv-head f32
+            # scale planes for K and V. A zero scale decodes a zero
+            # block — the recovery realloc is self-consistent.
+            quant = kv_quant != "off"
+            L, kvh = cfg.n_layers, cfg.n_kv_heads
+            hdp = llama.kvq_packed_head_dim(kv_quant, cfg.head_dim)
+            pool = ((L, pool_blocks, block_size, kvh, hdp),
+                    jnp.int8 if quant else cfg.dtype)
+            scale = ((L, pool_blocks, kvh), jnp.float32)
+            self._cache_spec = (pool, pool) + ((scale, scale) if quant else ())
         elif prefix_cache or prefill_chunk:
             raise ValueError(
                 "prefix_cache/prefill_chunk require block_size > 0"
             )
         else:
             self._m = 0
-        # quantized paged KV (kv_quant != "off"): the pool stores int8
-        # (or packed int4) entries + per-block-per-kv-head f32 scales;
-        # decode moves 2-4x fewer cache bytes. "off" is the identity
-        # lane — byte-identical programs, no scale planes allocated.
-        if kv_quant not in ("off", "int8", "int4"):
-            raise ValueError(
-                f"kv_quant must be one of off/int8/int4, got {kv_quant!r}"
-            )
-        if kv_quant != "off":
-            if not self._paged:
-                raise ValueError(
-                    "kv_quant requires the paged KV cache (block_size > 0)"
-                )
-            # raises for int4 on odd head_dim (two lanes pack per byte)
-            llama.kvq_packed_head_dim(kv_quant, cfg.head_dim)
-        self.kv_quant = str(kv_quant)
+            self._cache_spec = cfg.serve_cache_spec(max_slots, max_len)
         self.block_size = int(block_size)
         self.pool_blocks = int(pool_blocks) if self._paged else 0
         self.prefill_chunk = int(prefill_chunk)
@@ -894,33 +821,38 @@ class ContinuousBatchingEngine:
         self._ledger.register(self._ledger_owner, "params", pbytes, "params")
         weakref.finalize(self, self._ledger.release_owner, self._ledger_owner)
         self._alloc_device_state()
-        if self._paged and self.kv_quant != "off":
-            self._decode = _block_program_paged_q(
-                cfg, max_slots, self.pool_blocks, self._m,
-                self.block_size, horizon, self._sampling, self.kv_quant,
-            )
-            self._copyblk = _copy_block_program_q(
-                cfg, self.pool_blocks, self.block_size, self.kv_quant
-            )
-        elif self._paged:
+        # the layout's programs, chosen here and nowhere else: the two
+        # every engine runs, and the per-bucket / per-chunk / per-draft
+        # factories bound to the layout's arguments (built on first
+        # use, through the memo)
+        if self._paged:
+            geom = (max_slots, self.pool_blocks, self._m, self.block_size)
             self._decode = _block_program_paged(
-                cfg, max_slots, self.pool_blocks, self._m,
-                self.block_size, horizon, self._sampling,
-            )
+                cfg, *geom, horizon, self._sampling, self.kv_quant)
             self._copyblk = _copy_block_program(
-                cfg, self.pool_blocks, self.block_size
-            )
+                cfg, self.pool_blocks, self.block_size, self.kv_quant)
+            self._prefill_for = partial(
+                _prefill_paged_program, cfg, bs=self.block_size,
+                sampling=self._sampling, kv_quant=self.kv_quant)
+            self._chunk_for = partial(
+                _prefill_chunk_program, cfg, bs=self.block_size,
+                kv_quant=self.kv_quant)
+            self._verify_for = partial(
+                _verify_program_paged, cfg, *geom, kv_quant=self.kv_quant)
         else:
             self._decode = _block_program(
                 cfg, max_slots, max_len, horizon, self._sampling
             )
+            self._prefill_for = partial(
+                _prefill_program, cfg, sampling=self._sampling)
+            self._verify_for = partial(
+                _verify_program, cfg, max_slots, max_len)
         log.info(
             "engine ready",
             slots=max_slots,
             max_len=max_len,
             horizon=horizon,
-            cache_mb=round(
-                (self._cache_nbytes() + self._kv_scale_nbytes()) / 2**20, 1),
+            cache_mb=round(self._cache_nbytes() / 2**20, 1),
             paged=self._paged,
             block_size=self.block_size,
             pool_blocks=self.pool_blocks,
@@ -928,27 +860,10 @@ class ContinuousBatchingEngine:
             sampling=self._sampling,
         )
 
-    # the cache's arrays, donated and rebound together; the dense
-    # decoder's two are known by name to its paged / verify paths
-    def _set_cache(self, i: int, value) -> None:
-        cache = list(self._cache)
-        cache[i] = value
-        self._cache = tuple(cache)
-
-    _kc = property(lambda self: self._cache[0],
-                   lambda self, v: self._set_cache(0, v))
-    _vc = property(lambda self: self._cache[1],
-                   lambda self, v: self._set_cache(1, v))
-
+    # the cache's arrays (the pools' scale planes among them), donated
+    # and rebound together: `_cache_nbytes` is the one byte count
     def _cache_nbytes(self) -> int:
         return sum(c.nbytes for c in self._cache)
-
-    def _kv_scale_nbytes(self) -> int:
-        """Bytes held by the quantized pool's scale planes (0 when
-        kv_quant is off — no planes exist)."""
-        if self._ks is None:
-            return 0
-        return self._ks.nbytes + self._vs.nbytes
 
     def _alloc_device_state(self) -> None:
         """(Re)allocate the device-side slot decode state — the block
@@ -958,38 +873,22 @@ class ContinuousBatchingEngine:
         NEVER syncs these on the hot path — it feeds the returned
         device arrays straight into the next dispatch and reconstructs
         its bookkeeping view from drained token matrices instead."""
-        cfg, max_slots, max_len = self.cfg, self.max_slots, self.max_len
+        max_slots = self.max_slots
         self._dtok = jnp.zeros(max_slots, jnp.int32)
         self._dpos = jnp.zeros(max_slots, jnp.int32)
         self._dact = jnp.zeros(max_slots, bool)
         self._drem = jnp.zeros(max_slots, jnp.int32)
         self._deos = jnp.full((max_slots,), -1, jnp.int32)
+        self._cache = tuple(
+            jnp.zeros(shape, dtype) for shape, dtype in self._cache_spec
+        )
         if self._paged:
-            L, kvh, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        self._ks: Optional[jnp.ndarray] = None
-        self._vs: Optional[jnp.ndarray] = None
-        if self._paged:
-            # block POOL, not slot slab — block 0 is SCRATCH (pads and
-            # frozen/inactive lanes write there, nothing reads it). The
-            # allocator, tables, and prefix cache are HOST truth
-            # rebuilt here from nothing: after a recovery the pool is
-            # zeros, so every prior block (including cached prefixes)
-            # is invalid and the re-prefill repopulates what it needs.
-            if self.kv_quant != "off":
-                # quantized pool: int8 entries (int4 packs two per
-                # byte along head_dim) + per-block-per-kv-head f32
-                # scale planes for K and V. A zero scale decodes a
-                # zero block — the recovery realloc is self-consistent.
-                hdp = llama.kvq_packed_head_dim(self.kv_quant, hd)
-                shape = (L, self.pool_blocks, self.block_size, kvh, hdp)
-                self._cache = (jnp.zeros(shape, jnp.int8),
-                               jnp.zeros(shape, jnp.int8))
-                self._ks = jnp.zeros((L, self.pool_blocks, kvh), jnp.float32)
-                self._vs = jnp.zeros((L, self.pool_blocks, kvh), jnp.float32)
-            else:
-                shape = (L, self.pool_blocks, self.block_size, kvh, hd)
-                self._cache = (jnp.zeros(shape, cfg.dtype),
-                               jnp.zeros(shape, cfg.dtype))
+            # block 0 of the pool is SCRATCH (pads and frozen/inactive
+            # lanes write there, nothing reads it). The allocator,
+            # tables, and prefix cache are HOST truth rebuilt here from
+            # nothing: after a recovery the pool is zeros, so every
+            # prior block (including cached prefixes) is invalid and
+            # the re-prefill repopulates what it needs.
             self._balloc = _paged.BlockAllocator(
                 self.pool_blocks, self.block_size
             )
@@ -1000,10 +899,12 @@ class ContinuousBatchingEngine:
             self._tables: List[List[int]] = [
                 [_paged.SCRATCH] * self._m for _ in range(max_slots)
             ]
-        else:
-            self._cache = tuple(
-                jnp.zeros(shape, dtype) for shape, dtype in
-                cfg.serve_cache_spec(max_slots, max_len)
+            # scrapeable shrink: pool bytes (values + scales) over the
+            # pool's token capacity — 4.12 B/tok bf16 vs 2.12 int8 on
+            # the flagship shape (scales add ~1/(2·bs) back)
+            self._ledger.set_kv_bytes_per_token(
+                self._ledger_owner, self._cache_nbytes(),
+                self.pool_blocks * self.block_size,
             )
         # lanes whose slot was evicted while the DEVICE row was still
         # active (deadline evictions are host-bookkeeping only): blocks
@@ -1027,19 +928,8 @@ class ContinuousBatchingEngine:
         # pins the exact figure), and the efficiency busy-clock resets
         # so discarded in-flight time is not charged
         self._ledger.register(
-            self._ledger_owner, "kv",
-            self._cache_nbytes() + self._kv_scale_nbytes(),
-            "kv",
+            self._ledger_owner, "kv", self._cache_nbytes(), "kv"
         )
-        if self._paged:
-            # scrapeable shrink: pool bytes (values + scales) over the
-            # pool's token capacity — 4.12 B/tok bf16 vs 2.12 int8 on
-            # the flagship shape (scales add ~1/(2·bs) back)
-            self._ledger.set_kv_bytes_per_token(
-                self._ledger_owner,
-                self._kc.nbytes + self._vc.nbytes + self._kv_scale_nbytes(),
-                self.pool_blocks * self.block_size,
-            )
         self._ledger.register(
             self._ledger_owner, "slot_state",
             self._dtok.nbytes + self._dpos.nbytes + self._dact.nbytes
@@ -1169,7 +1059,7 @@ class ContinuousBatchingEngine:
                 was = self._admit_seq
                 emitted += self._admit()
                 admit["admitted"] = self._admit_seq - was
-        if self._paged:
+        if self.prefill_chunk:
             # one bounded prefill chunk per prefilling slot per step,
             # interleaved with the decode block below — a long prompt
             # no longer starves running slots behind one monolithic
@@ -1363,27 +1253,32 @@ class ContinuousBatchingEngine:
                     f"(shape {a.shape}, dtype {a.dtype})"
                 )
 
+    def _block_table(self) -> tuple:
+        """The layout's operand in front of the cache in a decode or
+        verify dispatch: the [B, M] table snapshot of the slots that
+        are decoding — none, ``()``, for the contiguous layout, which
+        has no table. Grows coverage BEFORE building the snapshot: the
+        dispatch may advance each decoding slot past a block boundary
+        (``_ensure_cover`` sizes the window to max(horizon, K), so
+        every position an accepted run can commit is mapped), and
+        coverage may preempt other slots under pool pressure —
+        preempted rows then fall through to the all-scratch default."""
+        if not self._paged:
+            return ()
+        for i, sl in enumerate(self._slots):
+            if sl is not None and sl.pf_next is None:
+                self._ensure_cover(i)
+        tbl = np.zeros((self.max_slots, self._m), np.int32)
+        for i, sl in enumerate(self._slots):
+            if sl is not None and sl.pf_next is None:
+                tbl[i] = self._tables[i]
+        # the table is a TRACED operand snapshot: alloc/share/free
+        # between dispatches are host bookkeeping, never a retrace
+        return (jnp.asarray(tbl),)
+
     def _dispatch_block(self) -> None:
-        table = None
-        if self._paged:
-            # grow coverage BEFORE building the dispatch table: the
-            # block may advance each decoding slot past a block
-            # boundary, and coverage may preempt other slots under
-            # pool pressure — preempted rows then fall through to the
-            # all-scratch default below
-            for i, sl in enumerate(self._slots):
-                if sl is not None and sl.pf_next is None:
-                    self._ensure_cover(i)
-            tbl = np.zeros((self.max_slots, self._m), np.int32)
-            for i, sl in enumerate(self._slots):
-                if sl is not None and sl.pf_next is None:
-                    tbl[i] = self._tables[i]
-            # the table is a TRACED operand snapshot: alloc/share/free
-            # between dispatches are host bookkeeping, never a retrace
-            table = jnp.asarray(tbl)
+        where = self._block_table()
         old = (self._dtok, self._dpos, self._dact, self._drem) + self._cache
-        if self._ks is not None:
-            old = old + (self._ks, self._vs)
         # span measures the ENQUEUE cost only (the dispatch is async);
         # the device-side block time shows up as serving.drain on the
         # block that finally syncs it — together they are the
@@ -1401,39 +1296,35 @@ class ContinuousBatchingEngine:
             cost = self._cost.decode_block(
                 self.max_slots, self.horizon, share * self.max_len
             )
-        counters = None
         with tracing.span("serving.dispatch", **attrs) as attrs:
-            if self._paged and self._ks is not None:
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc, self._ks, self._vs) = self._decode(
-                    self.params, old[0], old[1], old[2], old[3],
-                    self._deos, table, old[4], old[5], old[6], old[7],
-                    self._next_key(), self._temp(),
-                )
-            elif self._paged:
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc) = self._decode(
-                    self.params, old[0], old[1], old[2], old[3],
-                    self._deos, table, old[4], old[5],
-                    self._next_key(), self._temp(),
-                )
-            else:
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 *cache, counters) = self._decode(
-                    self.params, old[0], old[1], old[2], old[3],
-                    self._deos, *old[4:], self._next_key(), self._temp(),
-                )
-                self._cache = tuple(cache)
-        self.metrics.on_dispatch("decode")
+            (toks, self._dtok, self._dpos, self._dact, self._drem,
+             *cache, counters) = self._decode(
+                self.params, *old[:4], self._deos, *where, *old[4:],
+                self._next_key(), self._temp(),
+            )
+            self._cache = tuple(cache)
+        # what the model counted on the device during the block comes
+        # back with its tokens, onto this dispatch's span
+        # edl: no-lint[donation-safety] the tail probes these dead refs
+        self._block_dispatched(old, "decode", toks, cost, rids, counted=(
+            (counters, attrs) if counters else None))
+
+    def _block_dispatched(self, old: tuple, kind: str, toks, cost, rids,
+                          drafted=None, counted=None, **event) -> None:
+        """What follows the program call of a decode or verify
+        dispatch, in this order: count it, probe the donation, put it
+        on the timeline, offer the chaos site, queue its token matrix
+        for the drain."""
+        self.metrics.on_dispatch(kind)
         # deliberate read of the donated refs: is_deleted() PROBES that
         # donation actually happened (the runtime half of this invariant)
-        # edl: no-lint[donation-safety]
         self._assert_donated(*old)
         flight.emit("serve.block", active=self.active_slots,
-                    horizon=self.horizon)
+                    horizon=self.horizon, **event)
         # chaos site: a crash HERE is the worst case — the donated
         # inputs are dead, the carries are rebound, and the block's
-        # token matrix is about to be lost
+        # token matrix (under speculation: the accepted tokens) is
+        # about to be lost
         faults.fault_point("serve.dispatch")
         # per-block lane membership: lane i's tokens belong to slot i's
         # occupant AT DISPATCH — a lane mid-chunked-prefill (or later
@@ -1445,11 +1336,8 @@ class ContinuousBatchingEngine:
             i: s.rid for i, s in enumerate(self._slots)
             if s is not None and s.pf_next is None
         }
-        # what the model counted on the device during the block comes
-        # back with its tokens, onto this dispatch's span
         self._inflight.append(
-            (toks, self.clock(), members, cost, None, rids,
-             (counters, attrs) if counters else None)
+            (toks, self.clock(), members, cost, drafted, rids, counted)
         )
 
     def _kv_read_share(self) -> float:
@@ -1473,8 +1361,9 @@ class ContinuousBatchingEngine:
         sentinel row is exactly one plain decode step, so membership
         and per-slot disable never change the program) and run the
         verify program over every slot. Same dispatch discipline as
-        ``_dispatch_block``: donated carries, ``_assert_donated``
-        probe, ``serve.dispatch`` chaos site — a crash here recovers
+        ``_dispatch_block``, through the same ``_block_dispatched``:
+        donated carries, ``_assert_donated`` probe, ``serve.dispatch``
+        chaos site — a crash here recovers
         identically (``generated`` holds only drained tokens, so the
         replay's committed truth is complete mid-speculation)."""
         d = self.spec_k
@@ -1484,72 +1373,20 @@ class ContinuousBatchingEngine:
             row = row[:d]
             dm[i, :len(row)] = row
             drafted[i] = len(row)
-        table = None
-        if self._paged:
-            # same pre-dispatch coverage walk as the block path;
-            # _ensure_cover sizes the window to max(horizon, K) so
-            # every position an accepted run can commit is mapped
-            for i, sl in enumerate(self._slots):
-                if sl is not None and sl.pf_next is None:
-                    self._ensure_cover(i)
-            tbl = np.zeros((self.max_slots, self._m), np.int32)
-            for i, sl in enumerate(self._slots):
-                if sl is not None and sl.pf_next is None:
-                    tbl[i] = self._tables[i]
-            table = jnp.asarray(tbl)
-        old = (self._dtok, self._dpos, self._dact, self._drem,
-               self._kc, self._vc)
-        if self._ks is not None:
-            old = old + (self._ks, self._vs)
+        where = self._block_table()
+        old = (self._dtok, self._dpos, self._dact, self._drem) + self._cache
         rids = [s.rid for s in self._slots if s is not None]
         with tracing.span("serving.dispatch", horizon=self.horizon,
                           rids=rids, spec_k=d):
-            if self._paged and self._ks is not None:
-                prog = _verify_program_paged_q(
-                    self.cfg, self.max_slots, self.pool_blocks,
-                    self._m, self.block_size, d, self.kv_quant,
-                )
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc, self._ks, self._vs) = prog(
-                    self.params, old[0], jnp.asarray(dm), old[1],
-                    old[2], old[3], self._deos, table, old[4], old[5],
-                    old[6], old[7],
-                )
-            elif self._paged:
-                prog = _verify_program_paged(
-                    self.cfg, self.max_slots, self.pool_blocks,
-                    self._m, self.block_size, d,
-                )
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc) = prog(
-                    self.params, old[0], jnp.asarray(dm), old[1],
-                    old[2], old[3], self._deos, table, old[4], old[5],
-                )
-            else:
-                prog = _verify_program(
-                    self.cfg, self.max_slots, self.max_len, d
-                )
-                (toks, self._dtok, self._dpos, self._dact, self._drem,
-                 self._kc, self._vc) = prog(
-                    self.params, old[0], jnp.asarray(dm), old[1],
-                    old[2], old[3], self._deos, old[4], old[5],
-                )
-        self.metrics.on_dispatch("verify")
-        # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
-        self._assert_donated(*old)
-        flight.emit("serve.block", active=self.active_slots,
-                    horizon=self.horizon, spec_k=d)
-        # chaos site: same worst case as the block dispatch — donated
-        # inputs dead, accepted tokens only on device
-        faults.fault_point("serve.dispatch")
-        members = {
-            i: s.rid for i, s in enumerate(self._slots)
-            if s is not None and s.pf_next is None
-        }
-        self._inflight.append(
-            (toks, self.clock(), members, self._verify_cost, drafted, rids,
-             None)
-        )
+            (toks, self._dtok, self._dpos, self._dact, self._drem,
+             *cache) = self._verify_for(d)(
+                self.params, old[0], jnp.asarray(dm), *old[1:4],
+                self._deos, *where, *old[4:],
+            )
+            self._cache = tuple(cache)
+        # edl: no-lint[donation-safety] the tail probes these dead refs
+        self._block_dispatched(old, "verify", toks, self._verify_cost, rids,
+                               drafted=drafted, spec_k=d)
 
     def _drain_one(self) -> int:
         """Sync the OLDEST in-flight block's [B, H] token matrix and
@@ -1791,13 +1628,15 @@ class ContinuousBatchingEngine:
         rid: Optional[str] = None,
         replay: bool = False,
     ) -> int:
-        """One prefill-insert dispatch: run ``seq`` through the bucketed
-        prefill program, scatter its K/V into cache row ``slot``, reset
-        the row's device decode state to a ``max_new``-token budget, and
-        return the first sampled token. Shared by admission (``seq`` =
-        the prompt) and crash recovery (``seq`` = prompt + generated —
-        greedy argmax over the full context emits exactly the token the
-        lost decode step would have)."""
+        """Prefill-insert ``seq`` into ``slot``: run it through the
+        layout's prefill (one dispatch from position 0 for the
+        contiguous cache; for the paged one the slot's table is set up
+        first, prefix hits are skipped and leading chunks run inline),
+        reset the row's device decode state to a ``max_new``-token
+        budget, and return the first sampled token. Shared by admission
+        (``seq`` = the prompt) and crash recovery (``seq`` = prompt +
+        generated — greedy argmax over the full context emits exactly
+        the token the lost decode step would have)."""
         if self._paged:
             start = self._pg_setup_table(slot, seq, rid=rid)
             tok0 = self._pg_prefill(slot, seq, start, max_new, eos_id,
@@ -1805,59 +1644,9 @@ class ContinuousBatchingEngine:
             if not replay:
                 self._pg_cache_insert(slot, seq)
             return tok0
-        t0 = len(seq)
-        tb = self._bucket(t0)
-        toks = np.zeros((1, tb), np.int32)
-        toks[0, :t0] = seq
-        t_pf = self.clock()
-        prefill = _prefill_program(self.cfg, tb, self._sampling)
-        old = (self._dtok, self._dpos, self._dact, self._drem,
-               self._deos) + self._cache
-        # request trace root, DERIVED from the rid: the prefill span
-        # and the serve.prefill event share trace id
-        # derived_trace_id("rid", rid) without any id exchange, so a
-        # fleet trace and the event log agree on the request's identity
-        rid_root = (
-            disttrace.root("rid", rid) if rid is not None
-            else contextlib.nullcontext()
+        return self._dispatch_prefill_final(
+            slot, seq, 0, max_new, eos_id, site=site, rid=rid, replay=replay,
         )
-        with rid_root, tracing.span("serving.prefill", bucket=tb, rid=rid):
-            (tok0, self._dtok, self._dpos, self._dact, self._drem,
-             self._deos, *cache) = prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.int32(t0 - 1),
-                jnp.int32(slot),
-                jnp.int32(max_new),
-                jnp.int32(-1 if eos_id is None else eos_id),
-                *old,
-                self._next_key(),
-                self._temp(),
-            )
-            self._cache = tuple(cache)
-            self.metrics.on_dispatch("prefill")
-            # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
-            self._assert_donated(*old)
-            flight.emit("serve.prefill", rid=rid, slot=slot, bucket=tb,
-                        replay=replay)
-            if site is not None:
-                # chaos site (admission only — recovery replays are
-                # not re-faulted at the same site, the dispatch sites
-                # cover post-recovery failures)
-                faults.fault_point(site)
-            # admission is a sync point by design: the first token
-            # IS the TTFT sample, so it must be observed now, not a
-            # block later (and any block dispatched before this
-            # admission completed on device as a dependency of the
-            # prefill)
-            first = int(np.asarray(tok0))
-            now = self.clock()
-            self._eff.observe(
-                "prefill", self._cost.prefill(tb),
-                now - max(self._t_eff_last, t_pf),
-            )
-            self._t_eff_last = now
-            return first
 
     # -- paged KV management ------------------------------------------------
     #
@@ -1946,130 +1735,114 @@ class ContinuousBatchingEngine:
             site=site, rid=rid, replay=replay,
         )
 
+    def _slot_table(self, slot: int, start: int) -> tuple:
+        """The layout's operands in front of the cache in a prefill
+        dispatch: where the piece starts and the slot's table row —
+        none, ``()``, for the contiguous layout (a whole prompt from
+        position 0 into row ``slot``)."""
+        if not self._paged:
+            return ()
+        return (jnp.int32(start),
+                jnp.asarray(np.asarray(self._tables[slot], np.int32)))
+
     def _dispatch_prefill_chunk(self, slot: int, seq: List[int],
                                 start: int, rid: Optional[str] = None,
                                 site: Optional[str] = None) -> None:
         """One non-final prefill chunk: K/V for ``prefill_chunk``
         prompt tokens written into the slot's blocks, no logits, no
-        slot-state reset — pools donated like every other dispatch."""
+        slot-state reset — the cache donated like every other
+        dispatch."""
         c = self.prefill_chunk
         toks = np.asarray(seq[start:start + c], np.int32)[None, :]
         t_pf = self.clock()
-        table = jnp.asarray(np.asarray(self._tables[slot], np.int32))
-        quant = self._ks is not None
-        if quant:
-            prog = _prefill_chunk_program_q(
-                self.cfg, c, self.block_size, self.kv_quant
-            )
-            old = (self._kc, self._vc, self._ks, self._vs)
-        else:
-            prog = _prefill_chunk_program(self.cfg, c, self.block_size)
-            old = (self._kc, self._vc)
+        where = self._slot_table(slot, start)
+        old = self._cache
         with tracing.span("serving.prefill", bucket=c, rid=rid,
                           chunk=True):
-            if quant:
-                self._kc, self._vc, self._ks, self._vs = prog(
-                    self.params, jnp.asarray(toks), jnp.int32(start),
-                    old[0], old[1], old[2], old[3], table,
-                )
-            else:
-                self._kc, self._vc = prog(
-                    self.params, jnp.asarray(toks), jnp.int32(start),
-                    old[0], old[1], table,
-                )
-            self.metrics.on_dispatch("prefill")
-            # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
-            self._assert_donated(*old)
-            flight.emit("serve.prefill_chunk", rid=rid, slot=slot,
-                        start=start, chunk=c)
-            if site is not None:
-                faults.fault_point(site)
-            now = self.clock()
-            self._eff.observe(
-                "prefill", self._cost.prefill(c),
-                now - max(self._t_eff_last, t_pf),
-            )
-            self._t_eff_last = now
+            self._cache = tuple(self._chunk_for(c)(
+                self.params, jnp.asarray(toks), *where, *old,
+            ))
+            # edl: no-lint[donation-safety] the tail probes these dead refs
+            self._prefill_dispatched(old, c, t_pf, site, None,
+                                     "serve.prefill_chunk", rid=rid,
+                                     slot=slot, start=start, chunk=c)
 
     def _dispatch_prefill_final(
         self, slot: int, seq: List[int], start: int, max_new: int,
         eos_id: Optional[int], site: Optional[str] = None,
         rid: Optional[str] = None, replay: bool = False,
     ) -> int:
-        """The paged analog of the contiguous prefill dispatch: run the
-        bucketed TAIL of ``seq`` (positions ``start..``), sample the
-        first token, and reset the slot's device decode state. Earlier
-        positions are already resident (prefix hits / chunks)."""
+        """The one prefill dispatch that lands a first token: run the
+        bucketed TAIL of ``seq`` (positions ``start..``) through the
+        layout's prefill program, write its K/V (into cache row
+        ``slot``, or through the slot's table), sample the first token,
+        and reset the slot's device decode state. The contiguous layout
+        is ``start = 0``; under paging earlier positions are already
+        resident (prefix hits / chunks)."""
         n = len(seq) - start
         tb = self._bucket(n)
         toks = np.zeros((1, tb), np.int32)
         toks[0, :n] = seq[start:]
         t_pf = self.clock()
-        table = jnp.asarray(np.asarray(self._tables[slot], np.int32))
-        quant = self._ks is not None
+        where = self._slot_table(slot, start)
         old = (self._dtok, self._dpos, self._dact, self._drem,
-               self._deos, self._kc, self._vc)
-        if quant:
-            old = old + (self._ks, self._vs)
+               self._deos) + self._cache
+        # request trace root, DERIVED from the rid: the prefill span
+        # and the serve.prefill event share trace id
+        # derived_trace_id("rid", rid) without any id exchange, so a
+        # fleet trace and the event log agree on the request's identity
         rid_root = (
             disttrace.root("rid", rid) if rid is not None
             else contextlib.nullcontext()
         )
         with rid_root, tracing.span("serving.prefill", bucket=tb, rid=rid):
-            if quant:
-                prefill = _prefill_paged_program_q(
-                    self.cfg, tb, self.block_size, self._sampling,
-                    self.kv_quant,
-                )
-                (tok0, self._dtok, self._dpos, self._dact, self._drem,
-                 self._deos, self._kc, self._vc, self._ks,
-                 self._vs) = prefill(
-                    self.params,
-                    jnp.asarray(toks),
-                    jnp.int32(start),
-                    jnp.int32(n - 1),
-                    jnp.int32(slot),
-                    jnp.int32(max_new),
-                    jnp.int32(-1 if eos_id is None else eos_id),
-                    old[0], old[1], old[2], old[3], old[4], old[5],
-                    old[6], old[7], old[8],
-                    table,
-                    self._next_key(),
-                    self._temp(),
-                )
-            else:
-                prefill = _prefill_paged_program(
-                    self.cfg, tb, self.block_size, self._sampling
-                )
-                (tok0, self._dtok, self._dpos, self._dact, self._drem,
-                 self._deos, self._kc, self._vc) = prefill(
-                    self.params,
-                    jnp.asarray(toks),
-                    jnp.int32(start),
-                    jnp.int32(n - 1),
-                    jnp.int32(slot),
-                    jnp.int32(max_new),
-                    jnp.int32(-1 if eos_id is None else eos_id),
-                    old[0], old[1], old[2], old[3], old[4], old[5], old[6],
-                    table,
-                    self._next_key(),
-                    self._temp(),
-                )
-            self.metrics.on_dispatch("prefill")
-            # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
-            self._assert_donated(*old)
-            flight.emit("serve.prefill", rid=rid, slot=slot, bucket=tb,
-                        replay=replay, start=start)
-            if site is not None:
-                faults.fault_point(site)
-            first = int(np.asarray(tok0))
-            now = self.clock()
-            self._eff.observe(
-                "prefill", self._cost.prefill(tb),
-                now - max(self._t_eff_last, t_pf),
+            (tok0, self._dtok, self._dpos, self._dact, self._drem,
+             self._deos, *cache) = self._prefill_for(tb)(
+                self.params,
+                jnp.asarray(toks),
+                jnp.int32(n - 1),
+                jnp.int32(slot),
+                jnp.int32(max_new),
+                jnp.int32(-1 if eos_id is None else eos_id),
+                *old[:5], *where, *old[5:],
+                self._next_key(),
+                self._temp(),
             )
-            self._t_eff_last = now
-            return first
+            self._cache = tuple(cache)
+            # the paged layout's event says where the piece started
+            at = {"start": start} if self._paged else {}
+            # edl: no-lint[donation-safety] the tail probes these dead refs
+            return self._prefill_dispatched(old, tb, t_pf, site, tok0,
+                                            "serve.prefill", rid=rid, slot=slot,
+                                            bucket=tb, replay=replay, **at)
+
+    def _prefill_dispatched(self, old: tuple, width: int, t_pf: float,
+                            site: Optional[str], tok0, event: str,
+                            **fields) -> Optional[int]:
+        """What follows the program call of a prefill dispatch (final
+        piece or chunk), in this order: count it, probe the donation,
+        put it on the timeline, offer the chaos site, sync the first
+        token (a final piece has one), charge the roofline meter."""
+        self.metrics.on_dispatch("prefill")
+        self._assert_donated(*old)
+        flight.emit(event, **fields)
+        if site is not None:
+            # chaos site (admission only — recovery replays are not
+            # re-faulted at the same site, the dispatch sites cover
+            # post-recovery failures)
+            faults.fault_point(site)
+        # admission is a sync point by design: the first token IS the
+        # TTFT sample, so it must be observed now, not a block later
+        # (and any block dispatched before this admission completed on
+        # device as a dependency of the prefill)
+        first = None if tok0 is None else int(np.asarray(tok0))
+        now = self.clock()
+        self._eff.observe(
+            "prefill", self._cost.prefill(width),
+            now - max(self._t_eff_last, t_pf),
+        )
+        self._t_eff_last = now
+        return first
 
     def _advance_prefills(self) -> int:
         """One bounded chunk per chunk-prefilling slot per step — the
@@ -2207,18 +1980,11 @@ class ContinuousBatchingEngine:
         if self._balloc.refcount(bid) <= 1:
             return
         dst = self._pg_alloc_or_preempt(slot)
-        if self._ks is not None:
-            # quantized CoW carries the block's scales with its values
-            old = (self._kc, self._vc, self._ks, self._vs)
-            self._kc, self._vc, self._ks, self._vs = self._copyblk(
-                old[0], old[1], old[2], old[3],
-                jnp.int32(bid), jnp.int32(dst),
-            )
-        else:
-            old = (self._kc, self._vc)
-            self._kc, self._vc = self._copyblk(
-                old[0], old[1], jnp.int32(bid), jnp.int32(dst)
-            )
+        # every array of the cache: a quantized block's scales move with
+        # its values
+        old = self._cache
+        self._cache = tuple(
+            self._copyblk(*old, jnp.int32(bid), jnp.int32(dst)))
         # edl: no-lint[donation-safety] deliberate is_deleted() probe of the donation contract
         self._assert_donated(*old)
         tbl[j] = dst

@@ -98,6 +98,113 @@ def test_donation_factory_and_self_attr_pattern(tmp_path):
     assert "'old'" in rep.findings[0].message
 
 
+ENGINE_SHAPE = """
+    from functools import partial
+    import jax
+
+    def _block(cfg, n):
+        def make():
+            @partial(jax.jit, donate_argnums=(1, 2) + tuple(range(4, 4 + n)))
+            def run(params, tok, pos, eosv, *rest):
+                *cache, key = rest
+                return (tok, pos, *cache)
+            return run
+        return make()
+
+    def _prefill(cfg, tb, n=2):
+        def make():
+            @partial(jax.jit, donate_argnums=tuple(range(2, 3 + n)))
+            def run(params, tokens, tok, *cache):
+                return (tok, *cache)
+            return run
+        return make()
+
+    class Engine:
+        def __init__(self, cfg, paged):
+            self._decode = _block(cfg, 4) if paged else None
+            self._decode = _block(cfg, 2)
+            self._prefill_for = partial(_prefill, cfg, n=2)
+"""
+
+
+@pytest.mark.parametrize("expr, fixed, tail", [
+    ("(1, 2)", (1, 2), None),
+    ("(1, 2, 3, 4) + tuple(range(6, 6 + n))", (1, 2, 3, 4), 6),
+    ("tuple(range(6, 11 + n))", (6, 7, 8, 9, 10), 11),
+    ("tuple(range(6, 11)) + tuple(range(13, 13 + n))",
+     (6, 7, 8, 9, 10), 13),
+    ("tuple(range(n))", (), 0),
+    ("tuple(sorted(x))", None, None),
+])
+def test_donation_argnums_literal_positions_and_an_open_run(expr, fixed, tail):
+    """What ``donate_argnums`` of a program taking ``*cache`` resolves
+    to: its literal positions, and where the run of computed length
+    starts; an expression the rule cannot read resolves to nothing."""
+    import ast
+    from edl_tpu.analysis.rules._util import literal_int_tuple
+
+    nums = literal_int_tuple(ast.parse(expr, mode="eval").body)
+    if fixed is None:
+        assert nums is None
+    else:
+        assert tuple(nums) == fixed and nums.tail == tail and bool(nums)
+
+
+def test_donation_through_a_splatted_cache_tuple(tmp_path):
+    """The engine's one dispatch a kind: the cache rides in a tuple
+    that is splatted into a program donating a computed run. Reading
+    the tuple afterwards is the stale read; rebinding from the result
+    (a starred target among them) is clean."""
+    rep = run_on(tmp_path, ENGINE_SHAPE + """
+        def dispatch(self, where):
+            old = (self._tok, self._pos) + self._cache
+            self._tok, self._pos, *cache = self._decode(
+                self.params, *old[:2], self._eosv, *where, *old[2:], self.key)
+            self._cache = tuple(cache)
+            return self._cache[0].sum()  # rebound: clean
+
+        def bad(self, where):
+            old = (self._tok, self._pos) + self._cache
+            out = self._decode(
+                self.params, *old[:2], self._eosv, *where, *old[2:], self.key)
+            self._check(*old)  # stale read through the splat
+            return out
+    """, rules=["donation-safety"])
+    assert rules_of(rep) == ["donation-safety"]
+    assert "'old' is read after being donated to self._decode" in (
+        rep.findings[0].message)
+
+
+def test_donation_through_a_factory_bound_to_an_attribute(tmp_path):
+    """``self.X = partial(factory, ...)``: the program ``self.X(tb)``
+    builds donates what the factory's does, called in place or through
+    a local binding; the cache attribute itself, splatted, is dead
+    until it is rebound."""
+    rep = run_on(tmp_path, ENGINE_SHAPE + """
+        def prefill(self, tb, toks):
+            old = (self._tok,) + self._cache
+            self._tok, *cache = self._prefill_for(tb)(
+                self.params, toks, *old)
+            self._cache = tuple(cache)
+
+        def bad_in_place(self, tb, toks):
+            out = self._prefill_for(tb)(
+                self.params, toks, self._tok, *self._cache)
+            return self._cache[0].sum()  # not rebound
+
+        def bad_local(self, tb, toks):
+            prog = self._prefill_for(tb)
+            old = (self._tok,) + self._cache
+            out = prog(self.params, toks, *old)
+            return old[1].sum()
+    """, rules=["donation-safety"])
+    assert rules_of(rep) == ["donation-safety"] * 2
+    assert [f.message.split(" is read")[0] for f in rep.findings] == [
+        "'self._cache'", "'old'"]
+    assert "donated to self._prefill_for(...)" in rep.findings[0].message
+    assert "donated to prog" in rep.findings[1].message
+
+
 def test_donation_suppression(tmp_path):
     rep = run_on(tmp_path, DONATED_DEF + """
     def probe(state, x):

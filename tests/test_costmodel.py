@@ -222,8 +222,7 @@ def test_int8_kv_decode_block_flops_and_bytes_vs_xla():
 
     def block(p, tok, pos, table, kc, vc, ks, vs):
         return llama.decode_step_slots_paged(
-            p, tok, pos, table, kc, vc, cfg, bs,
-            kv_quant="int8", ks=ks, vs=vs,
+            p, tok, pos, table, (kc, vc, ks, vs), cfg, bs, kv_quant="int8",
         )
 
     args = (

@@ -358,9 +358,11 @@ def test_the_dense_decoder_answers_the_seam_with_its_own_programs():
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
     eng = ContinuousBatchingEngine(params, cfg, max_slots=2, max_len=32)
-    assert len(eng._cache) == 2
-    assert eng._kc is eng._cache[0] and eng._vc is eng._cache[1]
-    assert eng._kc.shape == (cfg.n_layers, 2, 32, cfg.n_kv_heads, cfg.head_dim)
+    # the dense decoder's cache is the spec's two arrays
+    assert [(c.shape, c.dtype) for c in eng._cache] == [
+        (shape, dtype) for shape, dtype in cfg.serve_cache_spec(2, 32)]
+    assert eng._cache[0].shape == (
+        cfg.n_layers, 2, 32, cfg.n_kv_heads, cfg.head_dim)
     prompt = [5, 6, 7, 8, 9]
     eng.submit("a", prompt, 6)
     got = list(eng.run()["a"].tokens)

@@ -129,24 +129,26 @@ def test_kvq_int4_needs_even_head_dim():
 def test_kv_quant_off_byte_identical():
     """``kv_quant="off"`` is the same engine, not a quantized engine
     with a wide tolerance: identical tokens, identical dispatch
-    counters, float pools, no scale planes, and no quantized program
-    ever memoized under an "off" key."""
+    counters, float pools, no scale planes, and the plain paged
+    engine's very programs: running it memoizes nothing new."""
     plain = _engine(horizon=4)
     off = _engine(horizon=4, kv_quant="off")
     toks_plain = _run_all(plain)
+    keys_plain = set(engine_mod._programs)
     toks_off = _run_all(off)
     assert toks_plain == toks_off
     s1, s2 = plain.metrics.snapshot(), off.metrics.snapshot()
     for k in ("dispatches_decode", "dispatches_prefill", "tokens_out"):
         assert s1[k] == s2[k], k
-    assert off._ks is None and off._vs is None
-    assert off._kc.dtype == plain._kc.dtype != jnp.int8
+    assert len(off._cache) == len(plain._cache) == 2  # no scale planes
+    assert off._cache[0].dtype == plain._cache[0].dtype != jnp.int8
     assert off._kvq_guard is None
-    qkeys = [
-        k for k in engine_mod._programs
-        if isinstance(k, tuple) and str(k[0]).endswith("-q")
-    ]
-    assert all(k[1] != "off" for k in qkeys)
+    # one factory a kind of program, keyed by ``kv_quant``: the off
+    # lane IS the plain paged engine's programs, and nothing quantized
+    # was built for it
+    assert off._decode is plain._decode
+    assert off._copyblk is plain._copyblk
+    assert set(engine_mod._programs) == keys_plain
 
 
 def test_kv_quant_constructor_validation():
@@ -172,14 +174,14 @@ def test_kv_quant_pool_layout_and_ledger(kv_quant):
     try:
         eng = _engine(kv_quant=kv_quant)
         hdp = llama.kvq_packed_head_dim(kv_quant, CFG.head_dim)
-        assert eng._kc.dtype == jnp.int8
-        assert eng._kc.shape[-1] == hdp
-        assert eng._ks.shape == (
+        assert eng._cache[0].dtype == jnp.int8
+        assert eng._cache[0].shape[-1] == hdp
+        assert eng._cache[2].shape == (
             CFG.n_layers, eng.pool_blocks, CFG.n_kv_heads
         )
         pool_b = (
-            eng._kc.nbytes + eng._vc.nbytes + eng._ks.nbytes
-            + eng._vs.nbytes
+            eng._cache[0].nbytes + eng._cache[1].nbytes + eng._cache[2].nbytes
+            + eng._cache[3].nbytes
         )
         assert reg.get("edl_hbm_bytes").value(category="kv") == pool_b
         cap = eng.pool_blocks * eng.block_size
@@ -229,10 +231,10 @@ def test_cow_block_copy_carries_scales():
     values — a copied block that kept stale scales would dequantize
     to garbage."""
     eng = _engine(max_slots=2, kv_quant="int8", prefix_cache=True)
-    kc = eng._kc.at[:, 3].set(5)
-    vc = eng._vc.at[:, 3].set(-3)
-    ks = eng._ks.at[:, 3].set(0.25)
-    vs = eng._vs.at[:, 3].set(0.5)
+    kc = eng._cache[0].at[:, 3].set(5)
+    vc = eng._cache[1].at[:, 3].set(-3)
+    ks = eng._cache[2].at[:, 3].set(0.25)
+    vs = eng._cache[3].at[:, 3].set(0.5)
     kc, vc, ks, vs = eng._copyblk(
         kc, vc, ks, vs, jnp.int32(3), jnp.int32(4)
     )
@@ -278,7 +280,7 @@ def test_int8_recovery_replay_within_tolerance(plan):
     toks = _run_all(eng)
     faults.disarm()
     assert eng.recoveries >= 1
-    assert eng._kc.dtype == jnp.int8  # rebuilt pool is still quantized
+    assert eng._cache[0].dtype == jnp.int8  # rebuilt pool is still quantized
     agr = [_agreement(a, b) for a, b in zip(base, toks)]
     assert np.mean(agr) >= 0.8, (plan, agr)
     for t, mn in zip(toks, MAX_NEWS):

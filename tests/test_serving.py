@@ -142,29 +142,108 @@ def test_paged_engine_matches_contiguous_engine():
         )
 
 
-def test_donated_cache_second_use_raises():
-    """The stale-buffer invariant: every dispatch donates kc/vc (and
-    the slot-state vectors), so pre-dispatch references are DEAD — a
-    second use raises from jax, and the engine's own invariant saw the
-    buffers consumed (in-place update, no per-step cache copy)."""
-    eng = ContinuousBatchingEngine(PARAMS, CFG, max_slots=2, max_len=32,
-                                   horizon=4)
-    kc0, vc0 = eng._kc, eng._vc
-    ptr0 = kc0.unsafe_buffer_pointer()
-    eng.submit("a", [1, 2, 3], 6)
-    eng.step()  # prefill + first block both dispatched
-    assert eng._donates is True  # CPU/TPU backends donate
-    assert kc0.is_deleted() and vc0.is_deleted()
-    with pytest.raises(RuntimeError, match="deleted"):
-        np.asarray(kc0)
-    # buffer identity: the live cache occupies the ORIGINAL buffer's
-    # memory — the update chain is genuinely in place, no per-dispatch
-    # cache allocation + copy
-    assert eng._kc.unsafe_buffer_pointer() == ptr0
+# every KV layout the engine serves, and the arrays its cache tuple holds
+LAYOUTS = {
+    "contiguous": (dict(), 2),
+    "paged": (dict(block_size=8), 2),
+    "paged-int8": (dict(block_size=8, kv_quant="int8"), 4),
+    "paged-int4": (dict(block_size=8, kv_quant="int4"), 4),
+}
+
+
+@pytest.mark.parametrize("layout, kw", [
+    ("contiguous", dict(horizon=4)),
+    ("paged", dict(horizon=4)),
+    ("paged-int8", dict(horizon=4)),
+    ("paged-int4", dict(horizon=4)),
+    ("contiguous", dict(horizon=1, spec_k=4)),  # the verify dispatch
+], ids=["contiguous", "paged", "paged-int8", "paged-int4", "verify"])
+def test_cache_updates_in_place_and_old_buffers_die(layout, kw):
+    """The stale-buffer invariant, in every layout: every dispatch
+    donates the whole cache (and the slot-state vectors), so
+    pre-dispatch references are DEAD — a second use raises from jax,
+    and the engine's own invariant saw the buffers consumed (in-place
+    update, no per-step cache copy). The verify dispatch keeps the
+    same chain as the block program."""
+    layout_kw, arity = LAYOUTS[layout]
+    eng = ContinuousBatchingEngine(PARAMS, CFG, max_slots=2, max_len=64,
+                                   **layout_kw, **kw)
+    prompt = [5, 6, 7, 5, 6, 7, 5, 6, 7, 5, 6]  # repeats: drafts land
+    eng.submit("a", prompt, 12)
+    for _ in range(2):  # prefill + first block, then one more dispatch
+        old = eng._cache
+        ptrs = [c.unsafe_buffer_pointer() for c in old]
+        eng.step()
+        assert eng._donates is True  # CPU/TPU backends donate
+        assert len(old) == len(eng._cache) == arity
+        assert all(c.is_deleted() for c in old)
+        with pytest.raises(RuntimeError, match="deleted"):
+            np.asarray(old[0])
+        # buffer identity: the live cache occupies the ORIGINAL
+        # buffers' memory — the update chain is genuinely in place, no
+        # per-dispatch cache allocation + copy
+        assert [c.unsafe_buffer_pointer() for c in eng._cache] == ptrs
     # the live handles still serve: the engine never touches the dead
-    # references, and the request completes token-identically
+    # references, and the request completes (token-identically where
+    # the cache is not quantized)
     res = eng.run()
-    assert res["a"].tokens == _sequential([1, 2, 3], 6)
+    if arity == 2:
+        assert res["a"].tokens == _sequential(prompt, 12)
+    else:
+        assert 0 < len(res["a"].tokens) <= 12
+    if "spec_k" in kw:
+        assert eng.metrics.snapshot()["dispatches_verify"] >= 1
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_every_kind_of_dispatch_donates_the_whole_cache_tuple(layout):
+    """One cache tuple in every layout: after a decode block, a final
+    prefill piece, a prefill chunk and a block copy (the last two exist
+    under paging only), every array the cache held before is dead and
+    the cache has the layout's arity — the scale planes of a quantized
+    pool ride in the tuple, not beside it."""
+    from edl_tpu.serving.engine import _Slot
+
+    layout_kw, arity = LAYOUTS[layout]
+    paged = "block_size" in layout_kw
+    if paged:
+        layout_kw = dict(layout_kw, prefill_chunk=8, prefix_cache=True)
+    eng = ContinuousBatchingEngine(PARAMS, CFG, max_slots=2, max_len=64,
+                                   horizon=2, **layout_kw)
+    assert len(eng._cache) == arity
+    seq = list(range(2, 26))  # three blocks of 8
+
+    def dispatched(fn):
+        old = eng._cache
+        out = fn()
+        assert all(c.is_deleted() for c in old), fn
+        assert len(eng._cache) == arity
+        assert not any(c.is_deleted() for c in eng._cache)
+        return out
+
+    if paged:
+        start = eng._pg_setup_table(0, seq)
+        dispatched(lambda: eng._dispatch_prefill_chunk(0, seq, start))
+        tok0 = dispatched(lambda: eng._dispatch_prefill_final(
+            0, seq, start + 8, 4, None))
+        # a second reference makes the slot's first block shared: the
+        # copy-on-write copies it in every array of the cache
+        shared = eng._tables[0][0]
+        eng._balloc.incref(shared)
+        dispatched(lambda: eng._pg_make_writable(0, 0))
+        assert eng._tables[0][0] != shared
+        eng._balloc.free(shared)
+    else:
+        tok0 = dispatched(lambda: eng._prefill_into(0, seq, 4, None))
+    eng._slots[0] = _Slot(rid="a", prompt=seq, max_new=4, eos_id=None,
+                          generated=[tok0])
+    dispatched(eng._dispatch_block)
+    assert eng._drain_all() == 2
+    if arity == 2:
+        assert [tok0] + eng._slots[0].generated[1:] == _sequential(seq, 3)
+    snap = eng.metrics.snapshot()
+    assert snap["dispatches_prefill"] == (2 if paged else 1)
+    assert snap["dispatches_decode"] == 1
 
 
 def test_program_cache_lru_keeps_hot_entry():

@@ -188,29 +188,8 @@ def test_spec_zero_acceptance_streak_stays_identical():
 # -- donation ---------------------------------------------------------------
 
 
-def test_spec_verify_program_donates_cache():
-    """The verify dispatch keeps the in-place update chain: kc/vc and
-    the slot-state vectors are donated (stale references dead, buffer
-    reused), same contract as the block program."""
-    eng = ContinuousBatchingEngine(
-        PARAMS, CFG, max_slots=2, max_len=64, horizon=1, spec_k=4,
-    )
-    eng.submit("r0", list(REPETITIVE), 12)
-    eng.step()  # prefill + first speculative iteration
-    kc0, vc0 = eng._kc, eng._vc
-    ptr0 = kc0.unsafe_buffer_pointer()
-    eng.step()  # at least one more verify dispatch consumes kc0/vc0
-    assert eng._donates is True
-    assert kc0.is_deleted() and vc0.is_deleted()
-    with pytest.raises(RuntimeError, match="deleted"):
-        np.asarray(kc0)
-    assert eng._kc.unsafe_buffer_pointer() == ptr0
-    res = eng.run()
-    assert res["r0"].tokens == _sequential(REPETITIVE, 12)
-    assert eng.metrics.snapshot()["dispatches_verify"] >= 1
-
-
-# -- metrics + observability ------------------------------------------------
+# (the verify dispatch keeps the in-place update chain: a case of
+# tests/test_serving.py::test_cache_updates_in_place_and_old_buffers_die)
 
 
 def test_spec_metrics_and_flight_events():
