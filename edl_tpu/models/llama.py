@@ -440,18 +440,56 @@ def quantize_params_int8(params: Dict) -> Dict:
     return out
 
 
-def _qkv(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions=None):
+def _qkv(
+    cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions=None,
+    split_after: bool = False,
+):
     """Projections + RoPE — shared by the training layer and the
-    KV-cache decode so the model math cannot diverge between them."""
+    KV-cache decode so the model math cannot diverge between them.
+
+    ``split_after`` holds the three ``[b, t, n]`` products behind an
+    ``optimization_barrier`` before they are split into heads; the
+    cached steps pass it (:func:`_qkv_cached`), training and prefill
+    do not."""
     b, t, _ = a.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     i8, wb = cfg.int8_mxu, cfg.int8_wgrad_bf16
-    q = _matw(a, lp["wq"], i8, wb).reshape(b, t, h, hd)
-    k = _matw(a, lp["wk"], i8, wb).reshape(b, t, kv, hd)
-    v = _matw(a, lp["wv"], i8, wb).reshape(b, t, kv, hd)
+
+    def product(name, n):
+        # bare, each product is split where it is made: the operations
+        # in the order the training step and prefill have always traced
+        # them, so their lowered text and compile-cache entries stand
+        y = _matw(a, lp[name], i8, wb)
+        return y if split_after else y.reshape(b, t, n, hd)
+
+    q, k, v = product("wq", h), product("wk", kv), product("wv", kv)
+    if split_after:
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+        q = q.reshape(b, t, h, hd)
+        k = k.reshape(b, t, kv, hd)
+        v = v.reshape(b, t, kv, hd)
     q = _rope(q, cfg.rope_theta, positions)
     k = _rope(k, cfg.rope_theta, positions)
     return q, k, v
+
+
+def _qkv_cached(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions):
+    """:func:`_qkv` for the steps that run against a KV cache: a few
+    rows a weight, the layers unrolled over the stacked tree.
+
+    Without the barrier XLA folds the head split into the dot, the
+    weight becomes a ``[d_in, h, hd]`` operand, and layout assignment
+    wants it ``d_in``-minor, which the stored ``[L, d_in, d_out]`` leaf
+    is not: every layer's ``wq`` / ``wk`` / ``wv`` was sliced out,
+    physically transposed (32 MB at 7B widths) and only then read, 36
+    to 48 weight-sized copies a step beside a 16-row product (PERF.md
+    section 6, PR 34). Held to the activation, the split costs the
+    ``[b, t, n]`` result (kilobytes) and the weights enter their dot
+    fusions as the stacked parameter, read once where they lie, as
+    ``w1`` / ``w3`` / ``wo`` / ``lm_head`` do. Training and prefill
+    multiply thousands of rows a weight and must stay free to fuse and
+    to differentiate: they call :func:`_qkv` bare."""
+    return _qkv(cfg, a, lp, positions, split_after=True)
 
 
 @jax.named_scope("mlp")
@@ -675,7 +713,7 @@ def _decode_step(params: Dict, tok: jnp.ndarray, pos, kc, vc, cfg: LlamaConfig):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
             # same projections/RoPE as training (_qkv); only the
             # cache-update + masked-dense attention differ by construction
-            q, knew, vnew = _qkv(cfg, a, lp, positions)
+            q, knew, vnew = _qkv_cached(cfg, a, lp, positions)
             kc = jax.lax.dynamic_update_slice(kc, knew[None], (i, 0, pos, 0, 0))
             vc = jax.lax.dynamic_update_slice(vc, vnew[None], (i, 0, pos, 0, 0))
             kci, vci = kc[i], vc[i]  # static-index slices of the carry
@@ -777,7 +815,7 @@ def decode_step_slots(
         lp = jax.tree_util.tree_map(lambda a: a[i], params["layers"])
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
+            q, knew, vnew = _qkv_cached(cfg, a, lp, pos[:, None])
             kc = kc.at[i, rows, pos].set(knew[:, 0])
             vc = vc.at[i, rows, pos].set(vnew[:, 0])
             qg = q.reshape(b, kvh, groups, hd)
@@ -1146,7 +1184,7 @@ def decode_step_slots_paged(
         dt = x.dtype
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, knew, vnew = _qkv(cfg, a, lp, pos[:, None])
+            q, knew, vnew = _qkv_cached(cfg, a, lp, pos[:, None])
             if quant:
                 kc, ks = _kvq_store(kc, ks, i, blk, off, knew[:, 0], kv_quant)
                 vc, vs = _kvq_store(vc, vs, i, blk, off, vnew[:, 0], kv_quant)
@@ -1311,7 +1349,7 @@ def prefill_paged(
         dt = x.dtype
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, knew, vnew = _qkv(cfg, a, lp, positions)
+            q, knew, vnew = _qkv_cached(cfg, a, lp, positions)
             if quant:
                 kc, ks = _kvq_store(kc, ks, i, wblk, woff, knew[0], kv_quant)
                 vc, vs = _kvq_store(vc, vs, i, wblk, woff, vnew[0], kv_quant)
@@ -1439,7 +1477,7 @@ def verify_step_slots(
         dt = x.dtype
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, knew, vnew = _qkv(cfg, a, lp, qpos)
+            q, knew, vnew = _qkv_cached(cfg, a, lp, qpos)
             # per-row K-lane scatter; rows[:, None] broadcasts against the
             # [B, K] positions. Writes past S drop (frozen rows parked at
             # the cache end), never clamp — a clamp would alias S-1.
@@ -1563,7 +1601,7 @@ def verify_step_slots_paged(
         dt = x.dtype
         with jax.named_scope("attn"):
             a = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, knew, vnew = _qkv(cfg, a, lp, qpos)
+            q, knew, vnew = _qkv_cached(cfg, a, lp, qpos)
             if quant:
                 kc, ks = _kvq_store(
                     kc, ks, i, wblk.reshape(-1), woff.reshape(-1),

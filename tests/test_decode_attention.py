@@ -179,6 +179,101 @@ def test_decode_step_slots_under_use_flash(n_layers):
             assert same[:, ~written].all()
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("records", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize(
+    "heads,kv_heads", [(8, 8), (32, 8)], ids=["mha", "gqa8"]
+)
+def test_the_cached_steps_projection_is_qkv(heads, kv_heads, records, dtype):
+    """``_qkv_cached`` (the cached steps' projections: the products
+    held behind a barrier before the head split, so the chip reads the
+    stacked weights where they lie) against ``_qkv`` on the same layer
+    of the same stacked tree, both jitted: the same q, k, v. float32
+    exactly; bfloat16 within one ulp of the product (a barrier may stop
+    the compiler from carrying a product wider into RoPE)."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), d_model=heads * 16, n_heads=heads,
+        n_kv_heads=kv_heads, n_layers=2, dtype=dtype,
+    )
+    params = jax.tree_util.tree_map(
+        lambda x: x.astype(dtype),
+        llama.init_params(jax.random.PRNGKey(0), cfg),
+    )
+    if records:
+        params = llama.quantize_params_int8(params)
+    b = 5
+    a = jax.random.normal(
+        jax.random.PRNGKey(1), (b, 1, cfg.d_model), jnp.float32
+    ).astype(dtype)
+    pos = jnp.asarray([0, 3, 17, 40, 63], jnp.int32)[:, None]
+
+    def layer(project, i):
+        def run(layers, a, pos):
+            lp = jax.tree_util.tree_map(lambda w: w[i], layers)
+            return project(cfg, a, lp, pos)
+        return jax.jit(run)(params["layers"], a, pos)
+
+    for i in range(cfg.n_layers):
+        want = layer(llama._qkv, i)
+        got = layer(llama._qkv_cached, i)
+        for g, w, n in zip(got, want, (heads, kv_heads, kv_heads)):
+            assert g.shape == (b, 1, n, cfg.head_dim) and g.dtype == dtype
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            if dtype == jnp.float32:
+                np.testing.assert_array_equal(g, w)
+            else:  # one ulp of bfloat16 at the value's own exponent
+                ulp = 2.0 ** (np.floor(np.log2(np.abs(w) + 1e-30)) - 7)
+                assert (np.abs(g - w) <= ulp).all()
+
+
+def _traced_barriers(program):
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), n_layers=2)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    b, s, bs = 2, 16, 4
+    i32 = jnp.zeros((b,), jnp.int32)
+    kc = jnp.zeros((2, b, s, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    pool = jnp.zeros((2, 9, bs, cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    table = jnp.zeros((b, s // bs), jnp.int32)
+    draft = jnp.zeros((b, 2), jnp.int32)
+    on = jnp.ones((b,), bool)
+    run = {
+        "train": lambda p: jax.grad(llama.make_loss_fn(cfg))(
+            p, {"tokens": jnp.zeros((b, 9), jnp.int32)}),
+        "prefill": lambda p: llama.prefill_padded(
+            p, jnp.zeros((b, 8), jnp.int32), i32, cfg),
+        "slots": lambda p: llama.decode_step_slots(p, i32, i32, kc, kc, cfg),
+        "generate": lambda p: llama._decode_step(
+            p, i32, jnp.int32(3), kc, kc, cfg),
+        "paged": lambda p: llama.decode_step_slots_paged(
+            p, i32, i32, table, (pool, pool), cfg, bs),
+        "chunk": lambda p: llama.prefill_paged(
+            p, jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(7),
+            table[0], (pool, pool), cfg, bs),
+        "verify": lambda p: llama.verify_step_slots(
+            p, i32, draft, i32, on, i32 + 4, i32 - 1, kc, kc, cfg),
+        "verify-paged": lambda p: llama.verify_step_slots_paged(
+            p, i32, draft, i32, on, i32 + 4, i32 - 1, table, (pool, pool),
+            cfg, bs),
+    }[program]
+    return str(jax.make_jaxpr(run)(params)).count("optimization_barrier")
+
+
+@pytest.mark.parametrize(
+    "program,layers_held",
+    [("train", 0), ("prefill", 0), ("slots", 2), ("generate", 2),
+     ("paged", 2), ("chunk", 2), ("verify", 2), ("verify-paged", 2)],
+)
+def test_which_program_is_built_decides_the_projections_form(
+    program, layers_held
+):
+    """No flag, no test of a model or of a row count: the programs whose
+    layers are unrolled over the stacked tree against a cache hold each
+    layer's products behind one barrier (``_qkv_cached``); the training
+    step and the scanned prefill trace none (``_qkv`` bare, the
+    parent's programs to the byte)."""
+    assert _traced_barriers(program) == layers_held
+
+
 @pytest.mark.parametrize("horizon", [1, 4])
 def test_engine_under_use_flash_serves_the_dense_engines_tokens(horizon):
     """The contiguous engine with ``use_flash=True`` (prefill through

@@ -10,8 +10,11 @@ installed). The persistent compile cache is switched off around the
 compiles: an entry written for a described device cannot be read back.
 """
 
+import collections
 import dataclasses
+import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 # describing a topology loads libtpu, which otherwise takes a machine-wide
@@ -143,15 +146,22 @@ def test_flash_under_a_mesh_runs_per_shard(v5e):
 
 # -- the ragged decode attention (ops/decode_attention.py) -------------------
 
-# the two serving cells of BENCHMARK.json: DeepSeek-7B at 12 layers (MHA,
-# 16 slots) and Mistral-7B at 16 (GQA-8, groups 4, 32 slots), 2048 long
-DECODE_CELLS = {
-    "deepseek7b-L12": (12, 16, 2048, 32, 1),
-    "mistral7b-L16": (16, 32, 2048, 8, 4),
+# the two dense serving cells of BENCHMARK.json, by their configurations
+# (benchmark/configs/<name>.json): (slots, the model's widths at the depth
+# it is served at), the cache 2048 long. DeepSeek-7B is MHA, Mistral-7B
+# GQA-8 (groups 4)
+SERVING_CELLS = {
+    "deepseek7b-L12": (16, dict(
+        vocab=102400, d_model=4096, n_layers=12, n_heads=32, n_kv_heads=32,
+        d_ff=11008, rope_theta=1e4, norm_eps=1e-6)),
+    "mistral7b-L16": (32, dict(
+        vocab=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        d_ff=14336, rope_theta=1e6, norm_eps=1e-5)),
 }
+CACHE_LEN = 2048
 
 
-@pytest.mark.parametrize("cell", sorted(DECODE_CELLS))
+@pytest.mark.parametrize("cell", sorted(SERVING_CELLS))
 def test_decode_attention_kernel_compiles_for_v5e(v5e, cell):
     """The kernel alone at a cell's real cache: it compiles (tiling,
     VMEM, the dynamic grid), takes the stacked cache as it is stored (the
@@ -159,7 +169,9 @@ def test_decode_attention_kernel_compiles_for_v5e(v5e, cell):
     is a temporary) and is there under its name."""
     from edl_tpu.ops.decode_attention import decode_attention
 
-    n_layers, b, s, kvh, groups = DECODE_CELLS[cell]
+    b, w = SERVING_CELLS[cell]
+    n_layers, s, kvh = w["n_layers"], CACHE_LEN, w["n_kv_heads"]
+    groups = w["n_heads"] // kvh
     one = SingleDeviceSharding(v5e[0])
     kc = _sds((n_layers, b, s, kvh, 128), jnp.bfloat16, one)
     compiled = jax.jit(decode_attention).lower(
@@ -172,21 +184,16 @@ def test_decode_attention_kernel_compiles_for_v5e(v5e, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < one_layer // 16
 
 
-def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
-    """``edl_serve_block`` at serve-open's shape (Mistral-7B widths, 16
-    layers, 32 slots x 2048, horizon 1, ``use_flash``): every layer's
-    attention is ``edl_decode_attn``, the cache updates in place, and no
-    operation produces a layer's worth of cache (the 32 ``slice``s of
-    ``bf16[32,2048,8,128]`` that were 42.5% of this program's time)."""
-    import re
-
+def _cell_block(v5e, cell):
+    """``edl_serve_block`` compiled at a cell's shape (slots x 2048,
+    horizon 1, ``use_flash``, a bf16 export): (cfg, cache spec,
+    compiled)."""
     from edl_tpu.serving import engine
 
     one = SingleDeviceSharding(v5e[0])
+    b, widths = SERVING_CELLS[cell]
     cfg = llama.LlamaConfig(
-        vocab=32768, d_model=4096, n_layers=16, n_heads=32, n_kv_heads=8,
-        d_ff=14336, rope_theta=1e6, norm_eps=1e-5, dtype=jnp.bfloat16,
-        use_flash=True, remat=False,
+        dtype=jnp.bfloat16, use_flash=True, remat=False, **widths
     )
     params = jax.eval_shape(
         lambda: jax.tree_util.tree_map(
@@ -197,7 +204,7 @@ def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
     params = jax.tree_util.tree_map(
         lambda x: _sds(x.shape, x.dtype, one), params
     )
-    b, s = 32, 2048
+    s = CACHE_LEN
     i32 = _sds((b,), jnp.int32, one)
     kc = _sds((cfg.n_layers, b, s, cfg.n_kv_heads, cfg.head_dim),
               cfg.dtype, one)
@@ -205,6 +212,23 @@ def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
         params, i32, i32, _sds((b,), jnp.bool_, one), i32, i32, kc, kc,
         _sds((2,), jnp.uint32, one), _sds((), jnp.float32, one),
     ).compile()
+    return cfg, kc, compiled
+
+
+@pytest.fixture(scope="module")
+def cell_block(v5e):
+    """:func:`_cell_block`, each cell's program compiled once a module."""
+    return functools.lru_cache(maxsize=None)(
+        functools.partial(_cell_block, v5e))
+
+
+def test_serve_open_block_reads_the_cache_through_the_kernel(cell_block):
+    """``edl_serve_block`` at serve-open's shape (Mistral-7B widths, 16
+    layers, 32 slots x 2048, horizon 1, ``use_flash``): every layer's
+    attention is ``edl_decode_attn``, the cache updates in place, and no
+    operation produces a layer's worth of cache (the 32 ``slice``s of
+    ``bf16[32,2048,8,128]`` that were 42.5% of this program's time)."""
+    cfg, kc, compiled = cell_block("mistral7b-L16")
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == cfg.n_layers
     assert "edl_decode_attn" in text
@@ -220,6 +244,66 @@ def test_serve_open_block_reads_the_cache_through_the_kernel(v5e):
     cache_bytes = 2 * kc.size * 2
     assert mem.alias_size_in_bytes >= cache_bytes  # updated in place
     assert mem.temp_size_in_bytes < cache_bytes // 8
+
+
+def _outside_fusions(text):
+    """(name, result shapes, op, operands) of every instruction of an
+    optimized module that is not in a fusion's body: the operations the
+    device runs one by one (the entry computation and what it calls)."""
+    bodies = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", text))
+    out, skip = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            skip = head.group(1) in bodies
+        elif not skip:
+            # a layout holds `T(8,128)` but no space: the op is the first
+            # word after one that opens a bracket
+            made = re.match(
+                r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)", line)
+            if made:
+                name, result, op, rest = made.groups()
+                shapes = re.findall(r"\w+\[[\d,]*\]", result)
+                out.append((name, shapes, op, rest))
+    return out
+
+
+@pytest.mark.parametrize("cell", sorted(SERVING_CELLS))
+def test_serve_block_reads_the_projection_weights_where_they_lie(
+    cell_block, cell
+):
+    """The dense serving cells' block: every leaf of ``params["layers"]``
+    goes into fusions and nothing else (the slice of a layer is inside
+    the fusion that reads it), and no operation outside a fusion makes a
+    layer's ``wq`` / ``wk`` / ``wv`` (either way round) by ``copy``,
+    ``transpose`` or a ``slice_bitcast_fusion``. With the head split
+    folded into the dot (``llama._qkv`` bare) the compiler sliced each
+    out, transposed it physically and only then read it: 48 weight-sized
+    copies a step at serve-open's shape, 36 at decode-closed's (PERF.md
+    section 6, PR 34); ``llama._qkv_cached`` keeps the split on the
+    activation. This count is that mechanism's counter."""
+    cfg, _, compiled = cell_block(cell)
+    text = compiled.as_text()
+    ops = _outside_fusions(text)
+    assert sum(op == "custom-call" for _, _, op, _ in ops) >= cfg.n_layers
+    d, kvd = cfg.d_model, cfg.n_kv_heads * cfg.head_dim
+    weight = {f"bf16[{m},{n}]" for m, n in ((d, d), (d, kvd), (kvd, d))}
+    relayouts = collections.Counter(
+        (shapes[0], "slice_bitcast_fusion" if op == "fusion" else op)
+        for name, shapes, op, _ in ops
+        if shapes[:1] and shapes[0] in weight and (
+            op in ("copy", "transpose", "slice")
+            or name.startswith("slice_bitcast_fusion")
+        )
+    )
+    assert not relayouts, dict(relayouts)
+    readers = {
+        (leaf, op) for _, _, op, rest in ops
+        for leaf in re.findall(r"%params__layers____(\w+?)__[.\d]*\b", rest)
+    }
+    assert {leaf for leaf, _ in readers} == set(llama._INT8_WEIGHTS) | {
+        "ln1", "ln2"}
+    assert {op for _, op in readers} == {"fusion"}, sorted(readers)
 
 
 # -- the latent-attention expert model (kanana2.decode-wide's shapes) --------
