@@ -1,0 +1,9 @@
+"""Median device time of one run of the decode block program
+(``edl_serve_block`` on the ``XLA Modules`` line) of the power-retention
+model: one decode step of 24 slots at the cell's ``horizon`` of 1."""
+
+from benchmark.reduce import program
+
+
+def read(run):
+    return program.block_device_ms(run)

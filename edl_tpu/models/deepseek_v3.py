@@ -208,6 +208,10 @@ class DeepseekV3Config:
             params, tok, pos, active, rem, eosv, cache[0], self, **kw)
         return toks, tok, pos, active, rem, (latent,), counters
 
+    def serve_cache_read(self, held, max_len: int, block: int):
+        return "kv_read_share", _ll.positional_read_share(
+            held, max_len, block)
+
     def serve_attn_block(self, max_len: int) -> int:
         """Positions of one S-block the decode attention fetches (the
         engine's ``kv_read_share`` counts in these)."""

@@ -144,6 +144,10 @@ class LlamaConfig:
             params, tok, pos, active, rem, eosv, *cache, self, **kw)
         return toks, tok, pos, active, rem, (kc, vc), {}
 
+    def serve_cache_read(self, held, max_len: int, block: int):
+        return "kv_read_share", positional_read_share(
+            held, max_len, block)
+
     def serve_attn_block(self, max_len: int) -> int:
         """Positions of one S-block ``edl_decode_attn`` fetches; the
         dense read is one block of ``max_len`` a slot."""
@@ -187,6 +191,17 @@ class LlamaConfig:
             d_ff=128,
             dtype=jnp.float32,
         )
+
+
+def positional_read_share(held, max_len: int, blk: int) -> float:
+    """S-blocks of a positional cache that a decode block fetches, over
+    the blocks of the padded cache, from the host's slot table
+    (``held``: the tokens each slot holds, None for an idle one): a
+    slot is read up to the block that holds its last token, an idle
+    slot (fed ``pos = 0``) costs one block. 1.0 for the dense program,
+    whose one block a slot is ``max_len``."""
+    fetched = sum(1 if n is None else -(-n // blk) for n in held)
+    return fetched / (len(held) * (max_len // blk))
 
 
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict:
@@ -442,7 +457,7 @@ def quantize_params_int8(params: Dict) -> Dict:
 
 def _qkv(
     cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions=None,
-    split_after: bool = False,
+    split_after: bool = False, qk_norm=None,
 ):
     """Projections + RoPE — shared by the training layer and the
     KV-cache decode so the model math cannot diverge between them.
@@ -450,7 +465,9 @@ def _qkv(
     ``split_after`` holds the three ``[b, t, n]`` products behind an
     ``optimization_barrier`` before they are split into heads; the
     cached steps pass it (:func:`_qkv_cached`), training and prefill
-    do not."""
+    do not. ``qk_norm`` is the (query, key) pair of per-head RMSNorm
+    weights of a model that norms its heads before RoPE
+    (``models/retention.py``); this decoder has none."""
     b, t, _ = a.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     i8, wb = cfg.int8_mxu, cfg.int8_wgrad_bf16
@@ -468,12 +485,16 @@ def _qkv(
         q = q.reshape(b, t, h, hd)
         k = k.reshape(b, t, kv, hd)
         v = v.reshape(b, t, kv, hd)
+    if qk_norm is not None:
+        q = _rmsnorm(q, qk_norm[0], cfg.norm_eps)
+        k = _rmsnorm(k, qk_norm[1], cfg.norm_eps)
     q = _rope(q, cfg.rope_theta, positions)
     k = _rope(k, cfg.rope_theta, positions)
     return q, k, v
 
 
-def _qkv_cached(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions):
+def _qkv_cached(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions,
+                qk_norm=None):
     """:func:`_qkv` for the steps that run against a KV cache: a few
     rows a weight, the layers unrolled over the stacked tree.
 
@@ -489,7 +510,7 @@ def _qkv_cached(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions):
     ``w1`` / ``w3`` / ``wo`` / ``lm_head`` do. Training and prefill
     multiply thousands of rows a weight and must stay free to fuse and
     to differentiate: they call :func:`_qkv` bare."""
-    return _qkv(cfg, a, lp, positions, split_after=True)
+    return _qkv(cfg, a, lp, positions, split_after=True, qk_norm=qk_norm)
 
 
 @jax.named_scope("mlp")

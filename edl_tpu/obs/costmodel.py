@@ -151,7 +151,9 @@ def detect_peak(device: Any = None) -> DevicePeak:
 # methods ``matmul_params()`` (parameters a token multiplies: the
 # ACTIVATED experts), ``n_params()``, ``attn_width()`` (h * hd of the
 # attention products) and ``cache_numbers_per_token()``, and the
-# functions below ask those first (``models/deepseek_v3.py``). The
+# functions below ask those first (``models/deepseek_v3.py``); one whose
+# cache is a state a slot and not rows a position also has
+# ``cache_step_bytes_per_slot()`` (``models/retention.py``). The
 # ``hasattr(cfg, "n_experts")`` lines remain for ``models/moe.py``,
 # whose config has no such methods.
 
@@ -172,13 +174,15 @@ def _dims(cfg):
 
 def _attn_width(cfg) -> float:
     d, h, kv, hd, ff, L, V = _dims(cfg)
-    return _own(cfg, "attn_width") or h * hd
+    own = _own(cfg, "attn_width")
+    return h * hd if own is None else own
 
 
 def _cache_numbers(cfg) -> float:
     """Numbers one position holds in the cache, all layers."""
     d, h, kv, hd, ff, L, V = _dims(cfg)
-    return _own(cfg, "cache_numbers_per_token") or 2.0 * L * kv * hd
+    own = _own(cfg, "cache_numbers_per_token")
+    return 2.0 * L * kv * hd if own is None else own
 
 
 def matmul_params(cfg) -> float:
@@ -293,7 +297,7 @@ def kv_scale_bytes(cfg, slots: int, s_pad: int, kv_block_size: int) -> float:
 
 
 def decode_step_bytes(
-    cfg, param_bytes_total: float, b: int, s_pad: int,
+    cfg, param_bytes_total: float, b: float, s_pad: float,
     kv_bytes_per_el: float = 2, kv_block_size: int = 0,
 ) -> float:
     """HBM bytes one decode step must move: every parameter byte
@@ -307,7 +311,14 @@ def decode_step_bytes(
     for int8, 0.5 for packed int4) and adds the per-block f32 scale
     strips the gather reads — pass the paged ``kv_block_size`` so the
     scale term is priced honestly (it is ~1/(2·bs) of the values for
-    int8, small but not zero)."""
+    int8, small but not zero).
+
+    A config whose cache is a state a slot says what a step moves of it
+    for one slot (``cache_step_bytes_per_slot``: the state read and
+    written); ``b`` is then the slots whose state the step moves."""
+    own = _own(cfg, "cache_step_bytes_per_slot")
+    if own is not None:
+        return param_bytes_total + own * b
     return (
         param_bytes_total
         + kv_cache_bytes(cfg, b, s_pad, kv_bytes_per_el)
@@ -404,14 +415,19 @@ class CostModel:
             + kv_cache_bytes(self.cfg, 1, t, self.kv_bytes_per_el),
         )
 
-    def decode_block(self, b: int, horizon: int, s_pad: int) -> Cost:
+    def decode_block(self, b: int, horizon: int, s_pad: int,
+                     read_share: float = 1.0) -> Cost:
         """One fused horizon block as dispatched: ``horizon`` steps of
-        ``b`` rows (frozen rows still compute — program cost) at the
-        context the program reads: the full padded one, or, for a
-        program that reads live blocks only, the mean positions read a
-        row (a float is fine)."""
+        ``b`` rows (frozen rows still compute — program cost) over a
+        cache padded to ``s_pad`` positions a row, of which the program
+        reads ``read_share``: all of it, the live S-blocks of a
+        positional cache, or the live slots of a per-slot state."""
+        if _own(self.cfg, "cache_step_bytes_per_slot") is None:
+            s_pad, rows = read_share * s_pad, b
+        else:
+            rows = read_share * b
         step_bytes = decode_step_bytes(
-            self.cfg, self.param_bytes, b, s_pad, self.kv_bytes_per_el,
+            self.cfg, self.param_bytes, rows, s_pad, self.kv_bytes_per_el,
             self.kv_block_size,
         )
         return Cost(

@@ -209,18 +209,31 @@ def _memo(key, make):
 # always ran; the paged, quantized, chunked and verify programs below
 # are that model's alone) --
 # ``cfg.serve_cache_spec(slots, max_len)``: the ((shape, dtype), ...) of
-# the cache's arrays, each ``[L, slots, max_len, ...]``;
+# the cache's arrays, each ``[L, slots, ...]``: what follows the slot
+# axis is the model's (positions of a KV cache, ``max_len`` of them; a
+# recurrent model's state, sized by the slots alone). ``max_len`` is
+# what bounds a prompt's bucket and a request's total length;
 # ``cfg.serve_prefill(params, tokens [1, Tb], last)`` -> (logits [1, V],
-# one ``[L, 1, Tb, ...]`` array of rows per cache array);
+# one ``[L, 1, ...]`` array of a slot's rows per cache array), which
+# the engine writes into the slot. A prompt is END-padded to its bucket:
+# what the cache holds afterwards must be what it holds after position
+# ``last``;
 # ``cfg.serve_decode_block(params, tok, pos, active, rem, eosv, cache,
 # horizon=, key=, temperature=, sampling=)`` -> (toks [B, H], tok, pos,
 # active, rem, cache, counters), ``counters`` a dict of device scalars
 # about the block (may be empty) that the engine drains with the
 # block's tokens onto its ``serving.dispatch`` span;
 # ``cfg.serve_attn_block(max_len)``: positions of one S-block its decode
-# attention fetches.
+# attention fetches (``max_len``: the whole slot at once);
+# ``cfg.serve_cache_read(held, max_len, block)`` -> (name, share): the
+# share of the cache the block about to be dispatched reads, under the
+# name it has on the dispatch span, from the host's slot table:
+# ``held`` has one entry a slot, the tokens it holds or None for an
+# idle one; ``block`` is the S-block above. The ledger files the cache
+# under ``cfg.serve_cache_category`` where the config has one ("kv"
+# otherwise).
 _SEAM = ("serve_cache_spec", "serve_prefill", "serve_decode_block",
-         "serve_attn_block")
+         "serve_attn_block", "serve_cache_read")
 
 
 def _block_program(cfg, b: int, s: int, horizon: int, sampling: bool):
@@ -786,9 +799,9 @@ class ContinuousBatchingEngine:
         # every block runs max_slots rows for `horizon` steps. The paged
         # programs and the dense contiguous one read the full padded
         # cache: a constant cost. The contiguous `use_flash` program
-        # (`edl_decode_attn`) reads each slot's live S-blocks, so its
-        # blocks are priced one by one at dispatch (`_kv_read_share`);
-        # a dense read is one block of `max_len` a slot
+        # (`edl_decode_attn`) reads each slot's live S-blocks, and a
+        # recurrent model the live slots' states, so those blocks are
+        # priced one by one at dispatch (`cfg.serve_cache_read`)
         self._block_cost = self._cost.decode_block(
             max_slots, horizon, max_len
         )
@@ -928,7 +941,8 @@ class ContinuousBatchingEngine:
         # pins the exact figure), and the efficiency busy-clock resets
         # so discarded in-flight time is not charged
         self._ledger.register(
-            self._ledger_owner, "kv", self._cache_nbytes(), "kv"
+            self._ledger_owner, "kv", self._cache_nbytes(),
+            getattr(self.cfg, "serve_cache_category", "kv"),
         )
         self._ledger.register(
             self._ledger_owner, "slot_state",
@@ -1292,9 +1306,12 @@ class ContinuousBatchingEngine:
         attrs = {"horizon": self.horizon, "rids": rids}
         cost = self._block_cost
         if not self._paged:
-            share = attrs["kv_read_share"] = self._kv_read_share()
+            # what of the cache this block reads, under the model's own
+            # name for it
+            name, share = self._cache_read()
+            attrs[name] = share
             cost = self._cost.decode_block(
-                self.max_slots, self.horizon, share * self.max_len
+                self.max_slots, self.horizon, self.max_len, share
             )
         with tracing.span("serving.dispatch", **attrs) as attrs:
             (toks, self._dtok, self._dpos, self._dact, self._drem,
@@ -1340,20 +1357,22 @@ class ContinuousBatchingEngine:
             (toks, self.clock(), members, cost, drafted, rids, counted)
         )
 
-    def _kv_read_share(self) -> float:
-        """S-blocks of the contiguous cache the block about to be
-        dispatched fetches, over the blocks of the padded cache, from
-        the host's slot table: a slot holding ``len(prompt) +
-        len(generated)`` tokens is read up to the block that holds its
-        last one, an idle slot (fed ``pos = 0``) costs one block. 1.0
-        for the dense program, whose one block a slot is ``max_len``."""
-        blk = self._attn_block
-        fetched = sum(
-            1 if s is None else
-            -(-min(len(s.prompt) + len(s.generated), self.max_len) // blk)
+    def _cache_read(self):
+        """(name, share) of the contiguous cache that the block about
+        to be dispatched reads, as the model's config reckons it from
+        the host's slot table (the tokens each slot holds, None for an
+        idle one): ``kv_read_share`` of a positional cache
+        (``llama.positional_read_share``), ``state_live_share`` of a
+        per-slot state."""
+        return self.cfg.serve_cache_read([
+            None if s is None else
+            min(len(s.prompt) + len(s.generated), self.max_len)
             for s in self._slots
-        )
-        return fetched / (self.max_slots * (self.max_len // blk))
+        ], self.max_len, self._attn_block)
+
+    def _kv_read_share(self) -> float:
+        """The share alone (the benchmark's tests ask it by this name)."""
+        return self._cache_read()[1]
 
     def _dispatch_verify(self, drafts: Dict[int, List[int]]) -> None:
         """One speculative verify dispatch: assemble the [B, D] draft

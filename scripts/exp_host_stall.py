@@ -8,9 +8,11 @@ second thread wakes every 2 ms and keeps its longest sleeps. A stall in
 which the engine's thread used the CPU all along is Python's own (the
 collector, if its time says so); one in which it did not, and the second
 thread overslept too, took the process off the CPU (the machine's
-scheduler or a CPU quota: ``cpu.stat``'s ``nr_throttled`` is printed
-before and after); one in which only the engine's thread waited was
-inside a call.
+scheduler or a CPU quota: ``cpu.stat``'s ``nr_throttled`` and
+``/proc/stat``'s steal seconds are printed before and after); one in
+which only the engine's thread waited was inside a call. PR 35's
+``brumby14b.decode-state`` runs met the second kind: 0.9-3.9 s in three
+runs of twelve, tens of milliseconds in most.
 Passive: a dozen clock reads a step.
 
     python scripts/exp_host_stall.py --workload kanana2.decode-wide \
@@ -19,6 +21,7 @@ Passive: a dozen clock reads a step.
 
 import gc
 import json
+import os
 import resource
 import sys
 import threading
@@ -42,6 +45,14 @@ def cpu_stat():
     try:
         out["pressure"] = open("/proc/pressure/cpu").readline().strip()
     except OSError:
+        pass
+    try:
+        # seconds the hypervisor ran someone else while this machine's
+        # CPUs had work (all CPUs together), and the load beside it
+        cpu = open("/proc/stat").readline().split()
+        out["steal_s"] = int(cpu[8]) / os.sysconf("SC_CLK_TCK")
+        out["loadavg"] = open("/proc/loadavg").read().split()[:3]
+    except (OSError, IndexError, ValueError):
         pass
     return out
 

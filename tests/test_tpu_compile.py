@@ -392,3 +392,146 @@ def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
                 if shape in experts
                 and op not in ("parameter", "get-tuple-element")], \
         "experts copied"
+
+
+# -- PR 35: a cache that is a state a slot --------------------------------------
+
+# sha256 (first 16 hex digits) of the lowered text of each serving
+# cell's block program and 1024 prefill, a Mosaic kernel's payload
+# aside (it holds its callers' files and line numbers): recorded from
+# the parent commit of PR 35 (546dfaa), whose seam change and
+# ``llama._qkv``'s ``qk_norm`` must leave these programs as they were
+PARENTS_TEXT = {
+    "deepseek7b.decode-closed": ("1295e2201debccba", "2b18d84018a249e3"),
+    "mistral7b.serve-open": ("f67a0903cf71b5a3", "9edf94936f77b1f2"),
+    "kanana2.decode-wide": ("649724fd83f366cb", "4627de3d25bb91b7"),
+}
+
+
+def _serving_programs(v5e, cell_name, bucket):
+    """(cfg, lowered block program, lowered prefill of ``bucket``) of a
+    serving cell at its own widths and engine sizes."""
+    from benchmark import harness
+    from edl_tpu.serving import engine
+
+    one = SingleDeviceSharding(v5e[0])
+    cell = harness.Cell(cell_name)
+    cfg = cell.family.program_config(cell.config, training=False)
+    params = harness.layout_tree(
+        cell.family.param_layout(cell.config),
+        lambda path, shape, std, stacked: _sds(shape, jnp.bfloat16, one))
+    spec = cell.spec["engine"]
+    b, s, h = spec["max_slots"], spec["max_len"], spec.get("horizon", 1)
+    cache = [_sds(shape, dtype, one)
+             for shape, dtype in cfg.serve_cache_spec(b, s)]
+    i32, s0 = _sds((b,), jnp.int32, one), _sds((), jnp.int32, one)
+    on = _sds((b,), jnp.bool_, one)
+    tail = (_sds((2,), jnp.uint32, one), _sds((), jnp.float32, one))
+    block = engine._block_program(cfg, b, s, h, False).lower(
+        params, i32, i32, on, i32, i32, *cache, *tail)
+    prefill = engine._prefill_program(cfg, bucket, False).lower(
+        params, _sds((1, bucket), jnp.int32, one), s0, s0, s0, s0,
+        i32, i32, on, i32, i32, *cache, *tail)
+    return cfg, block, prefill
+
+
+@pytest.mark.parametrize("cell", sorted(PARENTS_TEXT))
+def test_serving_cells_programs_lower_to_the_parents_text(v5e, cell):
+    import hashlib
+
+    _, block, prefill = _serving_programs(v5e, cell, 1024)
+    got = tuple(
+        hashlib.sha256(re.sub(
+            r'backend_config = "[^"]*"', 'backend_config = ""',
+            lowered.as_text()).encode()).hexdigest()[:16]
+        for lowered in (block, prefill))
+    assert got == PARENTS_TEXT[cell]
+
+
+def _state_sized(text, cfg, slots):
+    """Operations of the optimized text that make an array as large as
+    the state of all slots, of one layer's, or of one slot's across the
+    layers, outside a fusion: (shape, operation) pairs."""
+    tail = f"{cfg.n_kv_heads},{cfg.head_dim},{cfg.state_width}]"
+    sizes = {f"f32[{cfg.n_layers},{slots},{tail}", f"f32[{slots},{tail}",
+             f"f32[1,{slots},{tail}", f"f32[{cfg.n_layers},1,{tail}"}
+    return [(shape, op) for _, shapes, op, _ in _outside_fusions(text)
+            for shape in shapes if shape in sizes]
+
+
+@pytest.mark.parametrize("widths", ["tiny", "published"])
+def test_retention_block_moves_the_state_once_and_in_place(v5e, widths):
+    """``edl_serve_block`` of the power-retention model, at a tiny
+    config (lane-wide heads) and at ``brumby14b.decode-state``'s (8
+    layers, 24 slots): the state aliases its output, every layer's step
+    is one ``edl_retention_step`` that reads a live slot's state once
+    and writes it once, and nothing outside a fusion makes, copies or
+    transposes an array the size of the state, of a layer of it or of
+    a slot's share of it. The published block: 15.0 GB of arguments and
+    5 MB of temporaries."""
+    from edl_tpu.models import retention
+    from edl_tpu.serving import engine
+
+    one = SingleDeviceSharding(v5e[0])
+    if widths == "published":
+        cfg, lowered, _ = _serving_programs(
+            v5e, "brumby14b.decode-state", 256)
+        slots = 24
+    else:
+        cfg = retention.RetentionConfig(
+            vocab=1024, d_model=256, n_layers=2, n_heads=10, n_kv_heads=2,
+            head_dim=128, d_ff=512, use_kernel=True)
+        slots = 4
+        shapes = jax.eval_shape(
+            lambda k: retention.init_params(k, cfg), jax.random.PRNGKey(0))
+        params = jax.tree_util.tree_map(
+            lambda s: _sds(s.shape, jnp.bfloat16, one), shapes)
+        i32 = _sds((slots,), jnp.int32, one)
+        cache = [_sds(shape, dtype, one)
+                 for shape, dtype in cfg.serve_cache_spec(slots, 256)]
+        lowered = engine._block_program(cfg, slots, 256, 1, False).lower(
+            params, i32, i32, _sds((slots,), jnp.bool_, one), i32, i32,
+            *cache, _sds((2,), jnp.uint32, one), _sds((), jnp.float32, one))
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    state = slots * cfg.state_bytes_per_slot()
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert text.count("edl_retention_step") >= cfg.n_layers
+    made = _state_sized(text, cfg, slots)
+    assert not [m for m in made if m[1] not in (
+        "parameter", "get-tuple-element", "custom-call", "tuple")], made
+    # the kernel call itself is the one operation that yields the state
+    assert sum(op == "custom-call" for _, op in made) <= cfg.n_layers
+
+
+def test_retention_prefill_fits_beside_24_slots_of_state(v5e):
+    """The largest prefill program of ``brumby14b.decode-state`` (one
+    4096 bucket) beside the weights and 24 slots of state, through the
+    seam's plain ``serve_prefill`` and the engine's scatter: it fits
+    the chip (the compiler's own count: 14.99 GB of arguments and 0.41
+    GB of temporaries, a slot's stacked states, 272 MB, among them;
+    15.75 GiB = 16.9 GB is the chip's), the scatter writes the slot's
+    row into the donated cache in place, every chunk's work against
+    the carried state is ``edl_retention_chunk`` and no ``phi`` of a
+    chunk's queries is written out (170 MB a chunk of 256 in the plain
+    lines)."""
+    cfg, _, lowered = _serving_programs(v5e, "brumby14b.decode-state", 4096)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 24 * cfg.state_bytes_per_slot()
+    assert mem.temp_size_in_bytes < 512 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert "edl_retention_chunk" in text
+    # nothing but the in-place scatter yields an array the size of the
+    # whole cache
+    whole = [op for shape, op in _state_sized(text, cfg, 24)
+             if shape.startswith(f"f32[{cfg.n_layers},24,")]
+    assert not [op for op in whole if op not in (
+        "parameter", "get-tuple-element", "tuple", "fusion")], whole
+    assert whole.count("fusion") <= 1
+    rows = cfg.chunk * cfg.groups
+    unwanted = (f"bf16[8,{rows},8320]", f"f32[8,{rows},8320]")
+    assert not [(shape, op) for _, shapes, op, _ in _outside_fusions(text)
+                for shape in shapes if shape in unwanted], "phi written out"
