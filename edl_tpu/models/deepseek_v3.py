@@ -82,7 +82,8 @@ class DeepseekV3Config:
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     # the Pallas kernels: ``edl_flash_fwd`` in prefill,
-    # ``edl_decode_attn_latent`` in decode. Off: the dense lines.
+    # ``edl_decode_attn_latent`` and ``edl_expert_mlp`` in decode. Off:
+    # the dense lines and the grouped matmuls.
     use_flash: bool = False
 
     @classmethod
@@ -423,7 +424,8 @@ def _ffn(cfg: DeepseekV3Config, x: jnp.ndarray, lp: Dict, rows=None):
             flat, lp["router"], lp["router_bias"], cfg.top_k,
             cfg.route_scale, cfg.norm_topk)
         y = _moe.moe_dropless(
-            flat, idx, w, lp["we1"], lp["we3"], lp["we2"])
+            flat, idx, w, lp["we1"], lp["we3"], lp["we2"],
+            kernel=cfg.use_flash)
         with jax.named_scope("moe.shared"):
             y = y + _swiglu(flat, lp["ws1"], lp["ws3"], lp["ws2"])
         load = _moe.expert_load(idx, cfg.n_experts, rows)

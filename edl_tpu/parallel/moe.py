@@ -136,11 +136,24 @@ def moe_ffn(
 #
 # ``moe_ffn`` above bounds every expert's queue (capacity 1.25) and drops
 # what overflows, which no published checkpoint's arithmetic does. The
-# functions below never drop: every (token, chosen expert) pair is a row,
-# rows are sorted by expert, and each expert multiplies its own
-# contiguous run of rows (``jax.lax.ragged_dot``: grouped matmuls whose
-# group sizes are data). The weights of an expert nobody chose are not
-# multiplied, and on a bandwidth-bound decode step not read.
+# functions below never drop, and the weights of an expert nobody chose
+# are not multiplied, and on a bandwidth-bound decode step not read.
+# How many rows a step has picks the algorithm (``moe_dropless``):
+#
+# - more than ``ops.expert_mlp.MAX_ROWS`` (128) rows (every prefill
+#   bucket), an int8 record, or no kernels asked for: every (token,
+#   chosen expert) pair is a row, rows are sorted by expert, and each
+#   expert multiplies its own contiguous run of rows
+#   (``jax.lax.ragged_dot``: grouped matmuls whose group sizes are
+#   data). Compute-bound, XLA's own ground;
+# - up to 128 rows (a decode step) under ``kernel``: no sort. An expert
+#   has N * k / E rows (4.5 at 96 x 6 / 128), the layer is its weights
+#   passing through the chip once, and XLA's grouped matmuls stream
+#   them at 56% of a v5e's HBM peak; ``edl_expert_mlp`` puts all N rows
+#   through each hit expert while the next one's weights arrive. Why
+#   128: the wasted rows cost N operations a byte against the chip's
+#   ridge of ~240, and 128 rows are one pass of its 128 x 128 MXU per
+#   weight tile; past that the arithmetic shows and sorting pays.
 
 
 def route_sigmoid_topk(
@@ -191,6 +204,7 @@ def moe_dropless(
     w3,
     w2,
     first: int = 0,
+    kernel: bool = False,
 ) -> jnp.ndarray:
     """SwiGLU experts over routed rows, no token dropped: x [N, d], idx
     / w [N, k] from :func:`route_sigmoid_topk`; w1 / w3 [E_held, d, f]
@@ -199,12 +213,26 @@ def moe_dropless(
     weighted sum of the HELD experts' outputs for each token: the shares
     of disjoint ranges add up to the whole layer's routed term.
 
-    Rows are the N * k (token, choice) pairs, sorted by expert (stable:
-    a token's rows keep their order); a pair whose expert is not held
-    sorts behind every group, belongs to none, and is given weight 0."""
+    ``kernel`` (the model's ``use_flash``) lets a step of at most
+    ``ops.expert_mlp.MAX_ROWS`` rows over plain weight arrays run
+    ``edl_expert_mlp`` (a share of the experts too); the row count and
+    the weights' kind decide, nothing else. The rest is the grouped
+    form: rows are the N * k (token, choice) pairs, sorted by expert
+    (stable: a token's rows keep their order); a pair whose expert is
+    not held sorts behind every group, belongs to none, and is given
+    weight 0."""
     n, k = idx.shape
-    held = (w1["q8"] if isinstance(w1, dict) else w1).shape[0]
+    int8 = isinstance(w1, dict)
+    held = (w1["q8"] if int8 else w1).shape[0]
     with jax.named_scope("moe.experts"):
+        if kernel and not int8:
+            from edl_tpu.ops import expert_mlp as _em
+            from edl_tpu.ops.flash_attention import _INTERPRET
+
+            if n <= _em.MAX_ROWS:
+                return _em.expert_mlp(
+                    x, idx, w, w1, w3, w2, first=first,
+                    interpret=_INTERPRET.get())
         local = idx.reshape(-1) - first
         mine = (local >= 0) & (local < held)
         key = jnp.where(mine, local, held)

@@ -1,0 +1,264 @@
+"""``ops/expert_mlp.py`` (``edl_expert_mlp``: the routed experts of a
+decode step's few rows, no sort) under the Pallas interpreter, against
+the grouped form it stands in for (``parallel.moe.moe_dropless``
+without ``kernel``) and the benchmark's float32 table. float32 at
+``highest`` on both sides differs by the order of summation alone, so
+1e-5 holds; bfloat16 rows and weights are held to bfloat16's step."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark.reference import mla_moe as reference
+from edl_tpu.ops import expert_mlp as em
+from edl_tpu.ops.flash_attention import interpret_kernels
+from edl_tpu.parallel import moe
+
+D, F, E, K = 32, 24, 16, 3
+CONFIG = {"num_experts_per_tok": K, "norm_topk_prob": True,
+          "routed_scaling_factor": 2.448}
+
+
+def err(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+def layer(seed, e=E, d=D, f=F, dtype=jnp.float32):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    tree = {"router": jax.random.normal(k[0], (d, e)) * d ** -0.5,
+            "router_bias": jax.random.normal(k[1], (e,)) * 0.02,
+            "we1": jax.random.normal(k[2], (e, d, f)) * d ** -0.5,
+            "we3": jax.random.normal(k[3], (e, d, f)) * d ** -0.5,
+            "we2": jax.random.normal(k[4], (e, f, d)) * f ** -0.5}
+    return {name: leaf.astype(dtype) if name.startswith("we") else leaf
+            for name, leaf in tree.items()}
+
+
+def routed(lp, x, k=K):
+    with jax.default_matmul_precision("highest"):
+        return moe.route_sigmoid_topk(
+            x, lp["router"], lp["router_bias"], k, 2.448)
+
+
+def both(x, idx, w, lp, **kw):
+    """(the kernel's layer, the grouped form's) on the same routing."""
+    experts = (lp["we1"], lp["we3"], lp["we2"])
+    with interpret_kernels(), jax.default_matmul_precision("highest"):
+        got = moe.moe_dropless(x, idx, w, *experts, kernel=True, **kw)
+        want = moe.moe_dropless(x, idx, w, *experts, **kw)
+    return got, want
+
+
+def jaxpr_text(fn, *args) -> str:
+    return str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize("n", [1, 8, 96, 128])
+def test_kernel_layer_is_the_grouped_form_and_the_references_table(n):
+    lp = layer(n)
+    x = jax.random.normal(jax.random.PRNGKey(100 + n), (n, D))
+    idx, w = routed(lp, x)
+    got, grouped = both(x, idx, w, lp)
+    with jax.default_matmul_precision("highest"):
+        table = reference.route(x, lp["router"], lp["router_bias"], CONFIG)
+        want = reference.routed(x, table, lp["we1"], lp["we3"], lp["we2"])
+    assert got.shape == (n, D) and got.dtype == x.dtype
+    assert err(got, grouped) < 1e-5
+    assert err(got, want) < 1e-5
+    assert float(jnp.max(jnp.abs(want))) > 0.1
+
+
+@pytest.mark.parametrize("n", [1, 8, 96, 128])
+def test_bfloat16_rows_stay_within_bfloat16_of_the_float32_table(n):
+    """bf16 rows and weights, float32 accumulation: no further from the
+    float32 table than the grouped form, which rounds more often."""
+    lp = layer(n, dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(200 + n), (n, D))
+    idx, w = routed(lp, x)
+    got, grouped = both(x.astype(jnp.bfloat16), idx, w, lp)
+    with jax.default_matmul_precision("highest"):
+        table = jnp.zeros((n, E)).at[jnp.arange(n)[:, None], idx].add(w)
+        want = reference.routed(
+            x.astype(jnp.bfloat16).astype(jnp.float32), table,
+            *(lp[name].astype(jnp.float32) for name in ("we1", "we3", "we2")))
+    assert got.dtype == jnp.bfloat16
+    top = float(jnp.max(jnp.abs(want)))
+    assert err(got, want) < 2.0 ** -6 * top
+    assert err(got, want) <= err(grouped, want) + 2.0 ** -8 * top
+
+
+@pytest.mark.parametrize("n", [1, 48, 128])
+def test_every_token_sent_to_one_expert_still_gets_its_result(n):
+    lp = layer(3)
+    x = jax.random.normal(jax.random.PRNGKey(300 + n), (n, D))
+    idx = jnp.tile(jnp.array([[2, 5]]), (n, 1))
+    w = jnp.tile(jnp.array([[0.7, 0.3]]), (n, 1))
+    got, _ = both(x, idx, w, lp)
+    with jax.default_matmul_precision("highest"):
+        one = lambda e: (jax.nn.silu(x @ lp["we1"][e]) * (x @ lp["we3"][e])
+                         ) @ lp["we2"][e]
+        want = 0.7 * one(2) + 0.3 * one(5)
+    assert err(got, want) < 1e-5
+    assert float(jnp.min(jnp.linalg.norm(got, axis=-1))) > 0
+
+
+@pytest.mark.parametrize("n", [1, 8, 96])
+def test_an_expert_with_no_row_is_skipped_not_multiplied_by_zero(n):
+    """The weights of every expert nobody chose are NaN: one of them
+    read into the sum, at whatever weight, would show."""
+    lp = layer(4)
+    x = jax.random.normal(jax.random.PRNGKey(400 + n), (n, D))
+    idx, w = routed(lp, x)
+    want, _ = both(x, idx, w, lp)
+    unhit = ~jnp.any(idx[..., None] == jnp.arange(E), axis=(0, 1))
+    assert n > 8 or int(jnp.sum(unhit)) > 0
+    poisoned = {name: jnp.where(unhit[:, None, None], jnp.nan, lp[name])
+                for name in ("we1", "we3", "we2")}
+    got, _ = both(x, idx, w, poisoned)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert err(got, want) == 0.0
+
+
+def test_a_row_that_chose_another_expert_is_masked_not_multiplied():
+    """Expert 0 overflows on every row; row 1 did not choose it: ``0 *
+    inf`` would be NaN there, ``where`` leaves it expert 1's term."""
+    lp = layer(5)
+    lp["we1"] = lp["we1"].at[0].mul(1e20)
+    lp["we3"] = lp["we3"].at[0].mul(1e20)
+    x = jax.random.normal(jax.random.PRNGKey(500), (2, D))
+    idx = jnp.array([[0], [1]])
+    got, want = both(x, idx, jnp.ones((2, 1)), lp)
+    assert not bool(jnp.all(jnp.isfinite(got[0])))
+    assert bool(jnp.all(jnp.isfinite(got[1])))
+    assert err(got[1], want[1]) < 1e-5 and float(jnp.max(jnp.abs(got[1]))) > 0
+
+
+@pytest.mark.parametrize("n", [8, 40])
+def test_eight_shares_of_the_experts_add_up_to_the_uncut_layer(n):
+    e, k = 128, 6
+    lp = layer(6, e=e)
+    x = jax.random.normal(jax.random.PRNGKey(600 + n), (n, D))
+    idx, w = routed(lp, x, k)
+    whole, grouped = both(x, idx, w, lp)
+    total = jnp.zeros_like(x)
+    for first in range(0, e, 16):
+        share = {name: lp[name][first:first + 16]
+                 for name in ("we1", "we3", "we2")}
+        got, want = both(x, idx, w, share, first=first)
+        assert err(got, want) < 1e-5
+        total += got
+    assert err(total, whole) < 1e-5 and err(total, grouped) < 1e-5
+    # and one share alone is not the layer
+    assert err(got, whole) > 0.1
+
+
+def test_a_share_nobody_chose_is_zeros():
+    lp = layer(7)
+    x = jax.random.normal(jax.random.PRNGKey(700), (8, D))
+    idx = jnp.full((8, 2), 3) + jnp.arange(2)
+    got, want = both(x, idx, jnp.ones((8, 2)), lp, first=E)
+    assert bool(jnp.all(got == 0.0)) and bool(jnp.all(want == 0.0))
+
+
+@pytest.mark.parametrize("case", ["129 rows", "int8 record", "no kernels"])
+def test_what_the_kernel_does_not_take_goes_through_the_grouped_matmul(case):
+    from edl_tpu.models import deepseek_v3 as ds
+
+    n = 129 if case == "129 rows" else 20
+    lp = layer(8)
+    x = jax.random.normal(jax.random.PRNGKey(800), (n, D))
+    idx, w = routed(lp, x)
+    experts = {name: lp[name] for name in ("we1", "we3", "we2")}
+    if case == "int8 record":
+        experts = ds.quantize_params_int8(
+            {"layers": {"00": experts}, "lm_head": jnp.ones((4, 4))}
+        )["layers"]["00"]
+        assert experts["we1"]["q8"].dtype == jnp.int8
+    run = lambda x: moe.moe_dropless(
+        x, idx, w, experts["we1"], experts["we3"], experts["we2"],
+        kernel=case != "no kernels")
+    text = jaxpr_text(run, x)
+    assert text.count(" = ragged_dot") == 3
+    assert "pallas_call" not in text
+
+
+@pytest.mark.parametrize("n", [1, 128])
+def test_a_decode_step_of_any_size_is_one_kernel_and_no_grouped_matmul(n):
+    lp = layer(9)
+    x = jax.random.normal(jax.random.PRNGKey(900), (n, D))
+    idx, w = routed(lp, x)
+    with interpret_kernels():
+        text = jaxpr_text(lambda x: moe.moe_dropless(
+            x, idx, w, lp["we1"], lp["we3"], lp["we2"], kernel=True), x)
+    assert text.count(" = pallas_call[") == 1
+    assert "ragged_dot" not in text and " sort[" not in text
+
+
+def test_more_rows_than_the_kernel_takes_are_refused_by_the_kernel():
+    lp = layer(10)
+    x = jnp.zeros((em.MAX_ROWS + 1, D))
+    idx = jnp.zeros((em.MAX_ROWS + 1, K), jnp.int32)
+    with pytest.raises(ValueError, match="at most"):
+        em.expert_mlp(x, idx, idx.astype(jnp.float32), lp["we1"], lp["we3"],
+                      lp["we2"], interpret=True)
+
+
+def test_experts_that_do_not_fit_vmem_whole_twice_over_are_refused():
+    """An expert is taken whole; the widths that would need ``f`` in
+    blocks bring them. The cell's 2048 x 768 pass (traced, not run)."""
+    def shapes(d, f):
+        sds = jax.ShapeDtypeStruct
+        return (sds((96, d), jnp.bfloat16), sds((96, 6), jnp.int32),
+                sds((96, 6), jnp.float32), sds((E, d, f), jnp.bfloat16),
+                sds((E, d, f), jnp.bfloat16), sds((E, f, d), jnp.bfloat16))
+
+    run = lambda *a: em.expert_mlp(*a, interpret=True)
+    assert jax.eval_shape(run, *shapes(2048, 768)).shape == (96, 2048)
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        jax.eval_shape(run, *shapes(7168, 2048))
+
+
+def test_a_weight_of_exactly_zero_reads_as_not_chosen():
+    """Expert 0 overflows on every row. Row 0 chose it at weight 1, row
+    1 at weight 0: the mask is the weight, so row 1 has expert 1's term
+    alone where the grouped form's ``0 * inf`` leaves it NaN."""
+    lp = layer(5)
+    lp["we1"] = lp["we1"].at[0].mul(1e20)
+    lp["we3"] = lp["we3"].at[0].mul(1e20)
+    x = jax.random.normal(jax.random.PRNGKey(1100), (2, D))
+    idx = jnp.array([[0, 1], [0, 1]])
+    got, grouped = both(x, idx, jnp.array([[1.0, 1.0], [0.0, 1.0]]), lp)
+    alone, _ = both(x[1:], jnp.array([[1]]), jnp.ones((1, 1)), lp)
+    assert not bool(jnp.all(jnp.isfinite(got[0])))
+    assert not bool(jnp.all(jnp.isfinite(grouped[1])))
+    assert err(got[1], alone[0]) < 1e-5
+
+
+def test_float32_weights_under_bfloat16_rows_are_cast_as_the_grouped_form_does():
+    lp = layer(12)
+    x = jax.random.normal(jax.random.PRNGKey(1200), (24, D)).astype(
+        jnp.bfloat16)
+    idx, w = routed(lp, x.astype(jnp.float32))
+    cast = {name: lp[name].astype(jnp.bfloat16)
+            for name in ("we1", "we3", "we2")}
+    got, grouped = both(x, idx, w, lp)
+    want, _ = both(x, idx, w, cast)
+    assert got.dtype == jnp.bfloat16 and grouped.dtype == jnp.bfloat16
+    assert err(got, want) == 0.0
+
+
+def test_the_hit_list_is_the_hit_experts_ascending_then_the_last_again():
+    idx = jnp.array([[9, 2], [2, 5], [11, 9]])
+    w = jnp.array([[0.5, 0.25], [1.0, 2.0], [4.0, 8.0]])
+    c, hit, n_hit = em.combine_weights(idx, w, 8, 4)
+    # held experts 4..11: 5, 9, 11 are hit -> local 1, 5, 7
+    assert n_hit.tolist() == [3]
+    assert hit.tolist() == [1, 5, 7, 7, 7, 7, 7, 7]
+    want = jnp.zeros((3, 8)).at[0, 5].set(0.5).at[1, 1].set(2.0) \
+        .at[2, 7].set(4.0).at[2, 5].set(8.0)
+    assert err(c, want) == 0.0
+    # a row that names an expert twice adds its weights up
+    c, _, _ = em.combine_weights(jnp.array([[3, 3]]), jnp.array([[1., 2.]]),
+                                 4, 0)
+    assert c.tolist() == [[0.0, 0.0, 0.0, 3.0]]

@@ -348,8 +348,9 @@ def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
     nothing the size of the cache or of a layer of it is made (at 576
     columns the compiler kept the cache positions-minor and transposed
     all of it around every kernel), each layer's attention is
-    ``edl_decode_attn_latent`` and each expert layer's three grouped
-    matmuls read the experts where they lie. The temporaries are
+    ``edl_decode_attn_latent`` and each expert layer's routed experts
+    are one ``edl_expert_mlp`` that reads the experts where they lie
+    (three grouped matmuls a layer before PR 36). The temporaries are
     21 MB."""
     import re
 
@@ -379,7 +380,10 @@ def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
     assert mem.temp_size_in_bytes < 64 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     assert text.count("edl_decode_attn_latent") >= cfg.n_layers
-    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16", text)) == 3 * 7
+    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16", text)) == 0
+    assert len(re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*edl_expert_mlp", text)
+    ) == 7, "edl_expert_mlp once an expert layer"
     # (the loop hands its operands on by get-tuple-element: no copy)
     made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text)
     whole, layer = "bf16[8,96,4096,640]", "bf16[96,4096,640]"
@@ -394,17 +398,41 @@ def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
         "experts copied"
 
 
+@pytest.mark.parametrize("rows", [16, 96, 128])
+def test_expert_mlp_kernel_compiles_for_v5e(v5e, rows):
+    """``edl_expert_mlp`` at the cell's widths (128 experts of 2048 x
+    768 bf16, six a token): a whole expert twice over in VMEM (18.9 MB,
+    past the 16 MiB a kernel gets unasked), nothing expert-sized made
+    beside it."""
+    from edl_tpu.ops.expert_mlp import expert_mlp
+
+    one = SingleDeviceSharding(v5e[0])
+    e, d, f, k = 128, 2048, 768, 6
+    compiled = jax.jit(expert_mlp).lower(
+        _sds((rows, d), jnp.bfloat16, one), _sds((rows, k), jnp.int32, one),
+        _sds((rows, k), jnp.float32, one),
+        _sds((e, d, f), jnp.bfloat16, one), _sds((e, d, f), jnp.bfloat16, one),
+        _sds((e, f, d), jnp.bfloat16, one),
+    ).compile()
+    text = compiled.as_text()
+    assert "edl_expert_mlp" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # -- PR 35: a cache that is a state a slot --------------------------------------
 
 # sha256 (first 16 hex digits) of the lowered text of each serving
 # cell's block program and 1024 prefill, a Mosaic kernel's payload
 # aside (it holds its callers' files and line numbers): recorded from
 # the parent commit of PR 35 (546dfaa), whose seam change and
-# ``llama._qkv``'s ``qk_norm`` must leave these programs as they were
+# ``llama._qkv``'s ``qk_norm`` must leave these programs as they were.
+# PR 36 re-recorded decode-wide's block alone (``edl_expert_mlp`` for
+# the grouped matmuls): its prefill's digest standing is the proof that
+# prefill bypasses the kernel, the other pairs' that the dense decoders do
 PARENTS_TEXT = {
     "deepseek7b.decode-closed": ("1295e2201debccba", "2b18d84018a249e3"),
     "mistral7b.serve-open": ("f67a0903cf71b5a3", "9edf94936f77b1f2"),
-    "kanana2.decode-wide": ("649724fd83f366cb", "4627de3d25bb91b7"),
+    "kanana2.decode-wide": ("095d2ebfb11a760f", "4627de3d25bb91b7"),
 }
 
 
