@@ -209,9 +209,11 @@ class DeepseekV3Config:
             params, tok, pos, active, rem, eosv, cache[0], self, **kw)
         return toks, tok, pos, active, rem, (latent,), counters
 
+    serve_cache_kinds = ("kv",)
+
     def serve_cache_read(self, held, max_len: int, block: int):
-        return "kv_read_share", _ll.positional_read_share(
-            held, max_len, block)
+        return {"kv_read_share": _ll.positional_read_share(
+            held, max_len, block)}
 
     def serve_attn_block(self, max_len: int) -> int:
         """Positions of one S-block the decode attention fetches (the

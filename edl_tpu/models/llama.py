@@ -144,9 +144,12 @@ class LlamaConfig:
             params, tok, pos, active, rem, eosv, *cache, self, **kw)
         return toks, tok, pos, active, rem, (kc, vc), {}
 
+    # what the memory ledger files each array of the cache under
+    serve_cache_kinds = ("kv", "kv")
+
     def serve_cache_read(self, held, max_len: int, block: int):
-        return "kv_read_share", positional_read_share(
-            held, max_len, block)
+        return {"kv_read_share": positional_read_share(
+            held, max_len, block)}
 
     def serve_attn_block(self, max_len: int) -> int:
         """Positions of one S-block ``edl_decode_attn`` fetches; the
@@ -202,6 +205,14 @@ def positional_read_share(held, max_len: int, blk: int) -> float:
     whose one block a slot is ``max_len``."""
     fetched = sum(1 if n is None else -(-n // blk) for n in held)
     return fetched / (len(held) * (max_len // blk))
+
+
+def state_live_share(held) -> float:
+    """Slots whose per-slot state a decode block moves, over all slots,
+    from the same slot table: a live slot's state is read and written
+    whole whatever it holds, an idle one's not at all
+    (``models/retention.py``, ``models/ssm_hybrid.py``)."""
+    return sum(n is not None for n in held) / len(held)
 
 
 def init_params(key: jax.Array, cfg: LlamaConfig) -> Dict:
@@ -467,7 +478,9 @@ def _qkv(
     cached steps pass it (:func:`_qkv_cached`), training and prefill
     do not. ``qk_norm`` is the (query, key) pair of per-head RMSNorm
     weights of a model that norms its heads before RoPE
-    (``models/retention.py``); this decoder has none."""
+    (``models/retention.py``); this decoder has none. A config whose
+    ``rope_theta`` is None has no positional embedding
+    (``models/ssm_hybrid.py``) and ``positions`` is not read."""
     b, t, _ = a.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     i8, wb = cfg.int8_mxu, cfg.int8_wgrad_bf16
@@ -488,6 +501,8 @@ def _qkv(
     if qk_norm is not None:
         q = _rmsnorm(q, qk_norm[0], cfg.norm_eps)
         k = _rmsnorm(k, qk_norm[1], cfg.norm_eps)
+    if cfg.rope_theta is None:
+        return q, k, v
     q = _rope(q, cfg.rope_theta, positions)
     k = _rope(k, cfg.rope_theta, positions)
     return q, k, v
@@ -514,14 +529,18 @@ def _qkv_cached(cfg: LlamaConfig, a: jnp.ndarray, lp: Dict, positions,
 
 
 @jax.named_scope("mlp")
-def _mlp(cfg: LlamaConfig, x: jnp.ndarray, lp: Dict) -> jnp.ndarray:
+def _mlp(cfg: LlamaConfig, x: jnp.ndarray, lp: Dict,
+         residual: Optional[float] = None) -> jnp.ndarray:
     """Post-attention SwiGLU block (residual included) — shared by the
-    training layer and the decode step."""
+    training layer and the decode step. ``residual`` scales what the
+    block adds to the stream, for a model that publishes such a
+    multiplier (``models/ssm_hybrid.py``)."""
     i8, wb = cfg.int8_mxu, cfg.int8_wgrad_bf16
     m = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
     gate = checkpoint_name(jax.nn.silu(_matw(m, lp["w1"], i8, wb)), "mlp_gate")
     up = checkpoint_name(_matw(m, lp["w3"], i8, wb), "mlp_up")
-    return x + _matw(gate * up, lp["w2"], i8, wb)
+    out = _matw(gate * up, lp["w2"], i8, wb)
+    return x + out if residual is None else x + residual * out
 
 
 def _layer(
@@ -858,16 +877,18 @@ def decode_step_slots(
     return logits, kc, vc
 
 
-def slot_attention_dense(qg, kci, vci, pos):
+def slot_attention_dense(qg, kci, vci, pos, sm_scale=None):
     """The dense form of one layer's slot attention: qg [B, KV, groups,
     hd] against ALL ``S`` positions of kci / vci [B, S, KV, hd], masked
     to ``<= pos[row]`` afterwards. The ``use_flash=False`` path, and
-    what ``ops.decode_attention`` is tested against."""
+    what ``ops.decode_attention`` is tested against. ``sm_scale``
+    multiplies the scores in place of ``1 / sqrt(hd)``."""
     b, kvh, groups, hd = qg.shape
     s = kci.shape[1]
     scores = jnp.einsum(
         "btkgd,bskd->bkgts", qg[:, None], kci
-    ) / np.sqrt(hd)
+    )
+    scores = scores / np.sqrt(hd) if sm_scale is None else scores * sm_scale
     mask = (jnp.arange(s)[None, :] <= pos[:, None])[:, None, None, None, :]
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(qg.dtype)

@@ -91,8 +91,8 @@ class RetentionConfig:
     # ``llama._qkv`` and ``llama._mlp`` ask these of a config
     int8_mxu = False
     int8_wgrad_bf16 = False
-    # the memory ledger's category of this model's cache
-    serve_cache_category = "state"
+    # what the memory ledger files each array of the cache under
+    serve_cache_kinds = ("state", "state")
 
     def __post_init__(self):
         if self.n_heads % self.n_kv_heads or self.head_dim % 2:
@@ -212,8 +212,7 @@ class RetentionConfig:
     def serve_cache_read(self, held, max_len: int, block: int):
         """A live slot's state is read whole whatever it holds, an idle
         one's not at all."""
-        return "state_live_share", \
-            sum(n is not None for n in held) / len(held)
+        return {"state_live_share": _ll.state_live_share(held)}
 
 
 def init_params(key: jax.Array, cfg: RetentionConfig) -> Dict:

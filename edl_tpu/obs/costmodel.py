@@ -53,7 +53,7 @@ from __future__ import annotations
 import os
 import threading
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Mapping, Optional
 
 from edl_tpu.obs import metrics as obs_metrics
 
@@ -153,7 +153,8 @@ def detect_peak(device: Any = None) -> DevicePeak:
 # attention products) and ``cache_numbers_per_token()``, and the
 # functions below ask those first (``models/deepseek_v3.py``); one whose
 # cache is a state a slot and not rows a position also has
-# ``cache_step_bytes_per_slot()`` (``models/retention.py``). The
+# ``cache_step_bytes_per_slot()`` (``models/retention.py``); one with
+# layers of both kinds answers both (``models/ssm_hybrid.py``). The
 # ``hasattr(cfg, "n_experts")`` lines remain for ``models/moe.py``,
 # whose config has no such methods.
 
@@ -299,6 +300,7 @@ def kv_scale_bytes(cfg, slots: int, s_pad: int, kv_block_size: int) -> float:
 def decode_step_bytes(
     cfg, param_bytes_total: float, b: float, s_pad: float,
     kv_bytes_per_el: float = 2, kv_block_size: int = 0,
+    state_slots: Optional[float] = None,
 ) -> float:
     """HBM bytes one decode step must move: every parameter byte
     (weights stream once per token — the defining cost of small-batch
@@ -313,14 +315,16 @@ def decode_step_bytes(
     scale term is priced honestly (it is ~1/(2·bs) of the values for
     int8, small but not zero).
 
-    A config whose cache is a state a slot says what a step moves of it
-    for one slot (``cache_step_bytes_per_slot``: the state read and
-    written); ``b`` is then the slots whose state the step moves."""
-    own = _own(cfg, "cache_step_bytes_per_slot")
-    if own is not None:
-        return param_bytes_total + own * b
+    A config whose cache holds a state a slot says what a step moves of
+    it for one slot (``cache_step_bytes_per_slot``: the state read and
+    written), for ``state_slots`` slots (``b`` if None); what it holds
+    by position (``cache_numbers_per_token``, 0 for a model of
+    recurrent layers alone) is priced beside it: a model with layers of
+    both kinds pays both."""
+    state = _own(cfg, "cache_step_bytes_per_slot") or 0.0
     return (
         param_bytes_total
+        + state * (b if state_slots is None else state_slots)
         + kv_cache_bytes(cfg, b, s_pad, kv_bytes_per_el)
         + kv_scale_bytes(cfg, b, s_pad, kv_block_size)
     )
@@ -416,19 +420,24 @@ class CostModel:
         )
 
     def decode_block(self, b: int, horizon: int, s_pad: int,
-                     read_share: float = 1.0) -> Cost:
+                     read_share=1.0) -> Cost:
         """One fused horizon block as dispatched: ``horizon`` steps of
         ``b`` rows (frozen rows still compute — program cost) over a
         cache padded to ``s_pad`` positions a row, of which the program
         reads ``read_share``: all of it, the live S-blocks of a
-        positional cache, or the live slots of a per-slot state."""
-        if _own(self.cfg, "cache_step_bytes_per_slot") is None:
-            s_pad, rows = read_share * s_pad, b
-        else:
-            rows = read_share * b
+        positional cache (``kv_read_share``), the live slots of a
+        per-slot state (``state_live_share``). One number is the share
+        of whatever kind the model has; a model with both kinds is
+        priced from a mapping that names each, as the serving engine's
+        ``cfg.serve_cache_read`` gives it."""
+        if not isinstance(read_share, Mapping):
+            read_share = {"kv_read_share": read_share,
+                          "state_live_share": read_share}
+        s_pad = read_share.get("kv_read_share", 1.0) * s_pad
         step_bytes = decode_step_bytes(
-            self.cfg, self.param_bytes, rows, s_pad, self.kv_bytes_per_el,
+            self.cfg, self.param_bytes, b, s_pad, self.kv_bytes_per_el,
             self.kv_block_size,
+            state_slots=read_share.get("state_live_share", 1.0) * b,
         )
         return Cost(
             flops=horizon * b * decode_flops_per_token(self.cfg, s_pad),

@@ -194,7 +194,8 @@ def head_bias(kvh: int, groups: int, block_s: int) -> jnp.ndarray:
     return jnp.where(heads == cols, 0.0, NEG_INF).astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("block_s", "sm_scale", "interpret"))
 def decode_attention(
     q: jnp.ndarray,
     kc: jnp.ndarray,
@@ -203,6 +204,7 @@ def decode_attention(
     layer,
     *,
     block_s: int | None = None,
+    sm_scale: float | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Single-query attention of each slot over its own live prefix.
@@ -211,8 +213,9 @@ def decode_attention(
     head); kc / vc [L, B, S, KV, hd], the stacked caches, never a
     layer's slice; pos [B] int32, the last live position of each slot,
     INCLUSIVE (the one the caller just wrote); layer, an int32 scalar
-    (traced: every layer of a model is one compiled kernel). Returns
-    [B, KV, groups, hd] in q's dtype."""
+    (traced: every layer of a model is one compiled kernel);
+    ``sm_scale`` multiplies the scores (``1 / sqrt(hd)`` if None).
+    Returns [B, KV, groups, hd] in q's dtype."""
     b, kvh, groups, hd = q.shape
     n_layers, _, s, _, _ = kc.shape
     h = kvh * groups
@@ -221,8 +224,10 @@ def decode_attention(
     if s % block_s:
         raise ValueError(f"block_s={block_s} must divide the cache length {s}")
     rows = block_s * kvh
+    if sm_scale is None:
+        sm_scale = 1.0 / np.sqrt(hd)
     kernel = functools.partial(
-        _kernel, block_s=block_s, kvh=kvh, sm_scale=1.0 / np.sqrt(hd)
+        _kernel, block_s=block_s, kvh=kvh, sm_scale=sm_scale
     )
 
     # a position past the cache would be a block past it

@@ -187,7 +187,8 @@ def _flash_kernel(
 
 
 def _fwd_call(
-    qb, kb, vb, groups, block_q, block_k, causal, interpret, with_lse
+    qb, kb, vb, groups, block_q, block_k, causal, interpret, with_lse,
+    sm_scale=None,
 ):
     """Forward pallas call in flattened [B*H, T, d] layout → out or
     (out, lse): lse is produced only when saving residuals for grad.
@@ -201,7 +202,7 @@ def _fwd_call(
         block_q=block_q,
         block_k=block_k,
         causal=causal,
-        sm_scale=1.0 / np.sqrt(d),
+        sm_scale=1.0 / np.sqrt(d) if sm_scale is None else sm_scale,
         with_lse=with_lse,
     )
     o_spec = pl.BlockSpec((1, block_q, dv), lambda bh, qi, ki: (bh, qi, 0))
@@ -472,7 +473,8 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "sm_scale"),
 )
 def flash_attention(
     q: jnp.ndarray,
@@ -482,6 +484,7 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 1024,
     interpret: bool = False,
+    sm_scale: float | None = None,
 ) -> jnp.ndarray:
     """q [B, T, H, d], k/v [B, T, KV, d] with H % KV == 0 (GQA) →
     [B, T, H, d]; v may be [B, T, KV, dv] of another width, forward
@@ -491,7 +494,8 @@ def flash_attention(
     d=128 (the kernel is VPU-bound; wider kv blocks amortize the
     running-max rescale). Differentiable:
     the FlashAttention-2-style backward (dQ sweep + dK/dV sweep pallas
-    kernels, logsumexp residual) is wired via custom_vjp."""
+    kernels, logsumexp residual) is wired via custom_vjp. ``sm_scale``
+    multiplies the scores in place of ``1 / sqrt(d)``, forward only."""
     b, t, h, d = q.shape
     hk = k.shape[2]
     if h % hk:
@@ -510,7 +514,13 @@ def flash_attention(
     kb = k.transpose(0, 2, 1, 3).reshape(b * hk, t, d)
     dv = v.shape[3]
     vb = v.transpose(0, 2, 1, 3).reshape(b * hk, t, dv)
-    out = _flash(qb, kb, vb, groups, block_q, block_k, causal, interpret)
+    if sm_scale is None:
+        out = _flash(qb, kb, vb, groups, block_q, block_k, causal, interpret)
+    else:
+        # a model that publishes its own scale (``models/ssm_hybrid.py``):
+        # the forward kernel alone, the backward kernels keep 1 / sqrt(d)
+        out = _fwd_call(qb, kb, vb, groups, block_q, block_k, causal,
+                        interpret, with_lse=False, sm_scale=sm_scale)
     return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
@@ -548,7 +558,7 @@ def interpret_kernels():
         _INTERPRET.reset(token)
 
 
-def attention_auto(q, k, v, causal: bool = True):
+def attention_auto(q, k, v, causal: bool = True, sm_scale=None):
     """flash_attention with sequence-length-tuned block sizes — the
     model path's entry (``LlamaConfig.use_flash``, Ulysses). The
     compiled kernel unless the caller opened :func:`interpret_kernels`;
@@ -563,5 +573,5 @@ def attention_auto(q, k, v, causal: bool = True):
     bq, bk = (1024, 1024) if t >= 4096 else (512, 1024)
     return flash_attention(
         q, k, v, causal=causal, block_q=bq, block_k=bk,
-        interpret=_INTERPRET.get(),
+        interpret=_INTERPRET.get(), sm_scale=sm_scale,
     )

@@ -209,10 +209,15 @@ def _memo(key, make):
 # always ran; the paged, quantized, chunked and verify programs below
 # are that model's alone) --
 # ``cfg.serve_cache_spec(slots, max_len)``: the ((shape, dtype), ...) of
-# the cache's arrays, each ``[L, slots, ...]``: what follows the slot
-# axis is the model's (positions of a KV cache, ``max_len`` of them; a
-# recurrent model's state, sized by the slots alone). ``max_len`` is
-# what bounds a prompt's bucket and a request's total length;
+# the cache's arrays, each ``[L, slots, ...]``: the depth ``L`` is the
+# array's own (a model with layers of two kinds holds arrays of two
+# depths) and what follows the slot axis is the model's (positions of a
+# KV cache, ``max_len`` of them; a recurrent layer's state, sized by
+# the slots alone). ``max_len`` is what bounds a prompt's bucket and a
+# request's total length;
+# ``cfg.serve_cache_kinds``: one name per array of the spec, what the
+# memory ledger files it under (``edl_hbm_bytes{category=}``): "kv" for
+# rows a position, "state" for a state a slot;
 # ``cfg.serve_prefill(params, tokens [1, Tb], last)`` -> (logits [1, V],
 # one ``[L, 1, ...]`` array of a slot's rows per cache array), which
 # the engine writes into the slot. A prompt is END-padded to its bucket:
@@ -225,15 +230,16 @@ def _memo(key, make):
 # block's tokens onto its ``serving.dispatch`` span;
 # ``cfg.serve_attn_block(max_len)``: positions of one S-block its decode
 # attention fetches (``max_len``: the whole slot at once);
-# ``cfg.serve_cache_read(held, max_len, block)`` -> (name, share): the
-# share of the cache the block about to be dispatched reads, under the
-# name it has on the dispatch span, from the host's slot table:
-# ``held`` has one entry a slot, the tokens it holds or None for an
-# idle one; ``block`` is the S-block above. The ledger files the cache
-# under ``cfg.serve_cache_category`` where the config has one ("kv"
-# otherwise).
-_SEAM = ("serve_cache_spec", "serve_prefill", "serve_decode_block",
-         "serve_attn_block", "serve_cache_read")
+# ``cfg.serve_cache_read(held, max_len, block)`` -> {name: share}: what
+# share of each kind of cache the model has the block about to be
+# dispatched reads, under the names they have on the dispatch span and
+# in ``CostModel.decode_block`` (``kv_read_share`` of a positional
+# cache, ``state_live_share`` of a per-slot state; a model with both
+# answers both), from the host's slot table: ``held`` has one entry a
+# slot, the tokens it holds or None for an idle one; ``block`` is the
+# S-block above.
+_SEAM = ("serve_cache_spec", "serve_cache_kinds", "serve_prefill",
+         "serve_decode_block", "serve_attn_block", "serve_cache_read")
 
 
 def _block_program(cfg, b: int, s: int, horizon: int, sampling: bool):
@@ -940,10 +946,15 @@ class ContinuousBatchingEngine:
         # gauge cannot drift across crash/recover cycles; exp_chaos
         # pins the exact figure), and the efficiency busy-clock resets
         # so discarded in-flight time is not charged
-        self._ledger.register(
-            self._ledger_owner, "kv", self._cache_nbytes(),
-            getattr(self.cfg, "serve_cache_category", "kv"),
-        )
+        # each array under its own kind: a model with a per-slot state
+        # beside a positional cache sets both gauges
+        kinds = (("kv",) * len(self._cache) if self._paged
+                 else self.cfg.serve_cache_kinds)
+        for kind in sorted(set(kinds)):
+            self._ledger.register(
+                self._ledger_owner, kind,
+                sum(c.nbytes for c, k in zip(self._cache, kinds)
+                    if k == kind), kind)
         self._ledger.register(
             self._ledger_owner, "slot_state",
             self._dtok.nbytes + self._dpos.nbytes + self._dact.nbytes
@@ -1306,12 +1317,12 @@ class ContinuousBatchingEngine:
         attrs = {"horizon": self.horizon, "rids": rids}
         cost = self._block_cost
         if not self._paged:
-            # what of the cache this block reads, under the model's own
-            # name for it
-            name, share = self._cache_read()
-            attrs[name] = share
+            # what of each kind of cache this block reads, under the
+            # model's own names for them
+            shares = self._cache_read()
+            attrs.update(shares)
             cost = self._cost.decode_block(
-                self.max_slots, self.horizon, self.max_len, share
+                self.max_slots, self.horizon, self.max_len, shares
             )
         with tracing.span("serving.dispatch", **attrs) as attrs:
             (toks, self._dtok, self._dpos, self._dact, self._drem,
@@ -1358,12 +1369,12 @@ class ContinuousBatchingEngine:
         )
 
     def _cache_read(self):
-        """(name, share) of the contiguous cache that the block about
+        """{name: share} of the contiguous cache that the block about
         to be dispatched reads, as the model's config reckons it from
         the host's slot table (the tokens each slot holds, None for an
         idle one): ``kv_read_share`` of a positional cache
         (``llama.positional_read_share``), ``state_live_share`` of a
-        per-slot state."""
+        per-slot state, both of a model that holds both."""
         return self.cfg.serve_cache_read([
             None if s is None else
             min(len(s.prompt) + len(s.generated), self.max_len)
@@ -1372,7 +1383,7 @@ class ContinuousBatchingEngine:
 
     def _kv_read_share(self) -> float:
         """The share alone (the benchmark's tests ask it by this name)."""
-        return self._cache_read()[1]
+        return self._cache_read()["kv_read_share"]
 
     def _dispatch_verify(self, drafts: Dict[int, List[int]]) -> None:
         """One speculative verify dispatch: assemble the [B, D] draft

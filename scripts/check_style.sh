@@ -8,7 +8,10 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== byte-compile =="
-python -m compileall -q edl_tpu tests examples bench.py __graft_entry__.py
+# not tests/benchmark/later_pr/: test_benchmark_files.py copies that tree
+# file by file, and a __pycache__ left in it fails the copy
+python -m compileall -q -x 'tests/benchmark/later_pr/' \
+    edl_tpu tests examples bench.py __graft_entry__.py
 
 echo "== debugger / print leftovers =="
 if grep -rn "breakpoint()\|pdb.set_trace" edl_tpu/ --include='*.py'; then

@@ -465,8 +465,8 @@ def test_the_dispatch_span_says_what_share_of_the_states_a_block_moves(
     mine = tracing.tracer().spans("serving.dispatch")[before:]
     assert mine and all(s.attrs["state_live_share"] == 0.25 for s in mine)
     assert all("kv_read_share" not in s.attrs for s in mine)
-    assert cfg.serve_cache_read([3, None, 60, None], 64, 64) == (
-        "state_live_share", 0.5)
+    assert cfg.serve_cache_read([3, None, 60, None], 64, 64) == {
+        "state_live_share": 0.5}
 
 
 def test_a_positional_cache_answers_as_it_did():
@@ -477,8 +477,8 @@ def test_a_positional_cache_answers_as_it_did():
     held = [21, 5, None, None]
     for blk in (64, 16, 8):
         fetched = sum(1 if n is None else -(-n // blk) for n in held)
-        assert cfg.serve_cache_read(held, 64, blk) == (
-            "kv_read_share", fetched / (4 * (64 // blk)))
+        assert cfg.serve_cache_read(held, 64, blk) == {
+            "kv_read_share": fetched / (4 * (64 // blk))}
 
 
 def test_recovery_replays_into_the_same_state(params, tokens):

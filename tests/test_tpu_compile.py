@@ -428,11 +428,15 @@ def test_expert_mlp_kernel_compiles_for_v5e(v5e, rows):
 # ``llama._qkv``'s ``qk_norm`` must leave these programs as they were.
 # PR 36 re-recorded decode-wide's block alone (``edl_expert_mlp`` for
 # the grouped matmuls): its prefill's digest standing is the proof that
-# prefill bypasses the kernel, the other pairs' that the dense decoders do
+# prefill bypasses the kernel, the other pairs' that the dense decoders do.
+# PR 37 added decode-state's pair from its own parent (36433bc): the
+# seam widened for two kinds of cache, ``llama._qkv`` without RoPE and
+# ``_mlp``'s residual multiplier leave all four as they were
 PARENTS_TEXT = {
     "deepseek7b.decode-closed": ("1295e2201debccba", "2b18d84018a249e3"),
     "mistral7b.serve-open": ("f67a0903cf71b5a3", "9edf94936f77b1f2"),
     "kanana2.decode-wide": ("095d2ebfb11a760f", "4627de3d25bb91b7"),
+    "brumby14b.decode-state": ("5c42d86ecb8336c5", "aa4143d59a7dc56c"),
 }
 
 
@@ -563,3 +567,102 @@ def test_retention_prefill_fits_beside_24_slots_of_state(v5e):
     unwanted = (f"bf16[8,{rows},8320]", f"f32[8,{rows},8320]")
     assert not [(shape, op) for _, shapes, op, _ in _outside_fusions(text)
                 for shape in shapes if shape in unwanted], "phi written out"
+
+
+# -- PR 37: layers of two kinds, two kinds of cache in one tuple -----------------
+
+HYBRID = "granite4h.decode-hybrid"
+
+
+def _weight_sized(text, cfg):
+    """Operations of the optimized text outside a fusion that make, by
+    ``copy``, ``transpose`` or ``slice``, an array as large as a layer's
+    matrix or as the embedding: (shape, operation) pairs."""
+    d, ff, di = cfg.d_model, cfg.d_ff, cfg.d_inner
+    mats = {(d, di + cfg.conv_width), (di, d), (d, ff), (ff, d),
+            (d, cfg.n_heads * cfg.head_dim), (cfg.vocab, d)}
+    shapes = {f"bf16[{m},{n}]" for a, b in mats for m, n in ((a, b), (b, a))}
+    shapes |= {f"bf16[1,{s[5:]}" for s in shapes}
+    return [(shape, op) for _, made, op, _ in _outside_fusions(text)
+            for shape in made if shape in shapes
+            and op in ("copy", "transpose", "slice", "dynamic-slice")]
+
+
+def test_hybrid_block_moves_each_kind_of_cache_once_and_in_place(v5e):
+    """``edl_serve_block`` of ``granite4h.decode-hybrid`` (all 40
+    layers, 72 slots): it fits the chip (the compiler's own count: 14.30
+    GB of arguments, 4 MB of temporaries), the whole cache tuple (7.92
+    GB) aliases its output, the state-space layers are five loops over
+    their stacked tree, one ``edl_ssm_step`` each, and the attention
+    layers four ``edl_decode_attn``; nothing outside a fusion copies,
+    transposes or slices out a layer's matrix or the embedding, which
+    is also the head (411 MB), and nothing copies an array of the
+    cache."""
+    cfg, lowered, _ = _serving_programs(v5e, HYBRID, 256)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    cache = sum(
+        int(jnp.dtype(dtype).itemsize) * functools.reduce(
+            lambda a, b: a * b, shape)
+        for shape, dtype in cfg.serve_cache_spec(72, 4096))
+    assert round(cache / 1e9, 2) == 7.92
+    assert mem.alias_size_in_bytes >= cache
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert 14.2e9 < mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 15.75e9
+    ops = _outside_fusions(text)
+    kernels = collections.Counter(
+        name for _, _, op, rest in ops if op == "custom-call"
+        for name in ("edl_ssm_step", "edl_decode_attn") if name in rest)
+    runs = [r for r in cfg.runs if r[0] == "mamba"]
+    assert kernels["edl_ssm_step"] == len(runs) == 5
+    assert kernels["edl_decode_attn"] == cfg.n_attn == 4
+    assert sum(op == "while" for _, _, op, _ in ops) >= len(runs)
+    assert not _weight_sized(text, cfg), _weight_sized(text, cfg)
+    sizes = {"f32[36,72,64,64,128]", "f32[72,64,64,128]",
+             "bf16[36,72,13056]", "bf16[4,72,4096,4,128]"}
+    made = [(shape, op) for _, shapes, op, _ in ops for shape in shapes
+            if shape in sizes]
+    assert not [m for m in made if m[1] not in (
+        "parameter", "get-tuple-element", "custom-call", "tuple", "while",
+        "fusion", "bitcast")], made
+
+
+def test_hybrid_prefill_fits_beside_72_slots_of_cache(v5e):
+    """The largest prefill program of the cell (one 4096 bucket) beside
+    the weights and 72 slots of both kinds of cache, through the seam's
+    ``serve_prefill`` and the engine's scatter: 14.30 GB of arguments
+    and under 0.6 GB of temporaries; the attention layers' prefill is
+    ``edl_flash_fwd`` at the config's scale."""
+    cfg, _, lowered = _serving_programs(v5e, HYBRID, 4096)
+    compiled = lowered.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7.9e9
+    assert mem.temp_size_in_bytes < 600 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert "edl_flash_fwd" in text and "edl_ssm_step" not in text
+    # the embedding is read where it lies by the lookup and by the head
+    assert not [m for m in _weight_sized(text, cfg)
+                if m[0].endswith(f"[{cfg.vocab},{cfg.d_model}]")
+                or m[0].endswith(f"[{cfg.d_model},{cfg.vocab}]")]
+
+
+@pytest.mark.parametrize("pack", [1, 2])
+def test_decode_attention_at_the_hybrid_cells_cache(v5e, pack):
+    """``edl_decode_attn`` at the caller's scale over the hybrid cell's
+    keys and values, two heads of 64 a 128-lane row (4 x 128, blocks of
+    512 positions), and as it would be unpacked (8 x 64: compiles, and
+    the compiler stores such a cache positions-minor, which is why the
+    model packs)."""
+    from edl_tpu.ops.decode_attention import block_positions, decode_attention
+
+    one = SingleDeviceSharding(v5e[0])
+    kv, hd = 8 // pack, 64 * pack
+    cache = _sds((4, 72, 4096, kv, hd), jnp.bfloat16, one)
+    q = _sds((72, kv, 4 * pack, hd), jnp.bfloat16, one)
+    assert block_positions(kv, hd, 2, 4096) == 512
+    compiled = jax.jit(functools.partial(
+        decode_attention, sm_scale=0.015625)).lower(
+        q, cache, cache, _sds((72,), jnp.int32, one),
+        _sds((), jnp.int32, one)).compile()
+    assert "edl_decode_attn" in compiled.as_text()
