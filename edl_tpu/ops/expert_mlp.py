@@ -1,13 +1,24 @@
-"""Pallas TPU routed-expert SwiGLU for a decode step's few rows — what
-``parallel.moe.moe_dropless`` runs under ``kernel=True`` when a step has
-at most :data:`MAX_ROWS` rows.
+"""Pallas TPU routed-expert SwiGLU, what ``parallel.moe.moe_dropless``
+runs under ``kernel=True`` over plain weight arrays. The row count picks
+the kernel:
 
-A decode step of ``N`` tokens that each choose ``k`` of ``E`` experts
-gives every hit expert ``N * k / E`` rows (4.5 at 96 x 6 / 128): the
-layer is the experts' weights passing through the chip once, and the
-sorted, grouped form (``jax.lax.ragged_dot`` three times, with a sort,
-two gathers and a combine around them) streams them at 56% of a v5e's
-HBM peak (PERF.md section 6, PR 36). This kernel does not sort:
+- at most :data:`MAX_ROWS` rows (a decode step): ``edl_expert_mlp``, no
+  sort, every row through every hit expert, masked;
+- more (every prefill bucket): ``edl_grouped_expert_mlp``, the rows
+  sorted by expert and each hit expert's own run of them, in tiles of
+  :data:`GROUP_TILE` rows.
+
+Both fetch each hit expert's three matrices once, whole, where they lie
+in the layer's ``[E, ...]`` leaves, while the expert before it computes,
+and never read an expert nobody chose.
+
+**A decode step's few rows.** ``N`` tokens that each choose ``k`` of
+``E`` experts give every hit expert ``N * k / E`` rows (4.5 at 96 x 6 /
+128): the layer is the experts' weights passing through the chip once,
+and the sorted, grouped form (``jax.lax.ragged_dot`` three times, with
+a sort, two gathers and a combine around them) streams them at 56% of a
+v5e's HBM peak (PERF.md section 6, PR 36). ``edl_expert_mlp`` does not
+sort:
 
 - the grid is the list of the experts HIT, ascending, one expert a
   step; its ``[d, f]``, ``[d, f]`` and ``[f, d]`` matrices are fetched
@@ -32,11 +43,38 @@ HBM peak (PERF.md section 6, PR 36). This kernel does not sort:
   one ``h`` and the sum.
 
 A combine weight of exactly 0 reads as "not chosen": the row gets
-nothing from that expert, whatever the expert computes. An expert is
-taken whole: shapes whose expert twice over (one computing, one
-arriving) would not fit a v5e's VMEM are refused, not tiled.
+nothing from that expert, whatever the expert computes.
 
-Off-TPU the kernel runs only under the Pallas interpreter, asked for by
+**A prefill's many.** A bucket of 1024-4096 tokens x 6 over 128 experts
+gives an expert 48-192 rows, still under the ridge: the layer is still
+its weights passing through once, and ``ragged_dot`` x 3 streams them
+at 171-226 GB/s, a fifth to a quarter of the peak (PERF.md section 6,
+PR 38; this kernel at 439-663). ``edl_grouped_expert_mlp`` takes
+the rows as ``moe_dropless`` sorts them and the runs' sizes:
+
+- the grid is the list of (expert, row tile) pairs to visit, expert by
+  expert and tile by tile inside an expert's run (``group_visits``:
+  scalar-prefetch operands, repeating the last visit to the grid's
+  static length). A tile that a run's end crosses is visited once for
+  each run in it: ``rows / GROUP_TILE + experts hit - 1`` visits at most;
+- a visit puts its whole tile through its expert (``h`` stays in VMEM,
+  rounded once; float32 accumulation) and writes the rows of the tile
+  that are the expert's, by ``where``: the tile stays in VMEM while
+  consecutive visits name it. The rows are written where they were read
+  (input and output alias);
+- the experts lie in two VMEM buffers filled by hand: an expert's first
+  visit starts the fetch of the next expert with rows, which so arrives
+  under ALL of this expert's visits (as BlockSpec operands it would be
+  asked for one visit ahead, under the last visit alone);
+- the roundings are ``h`` and each expert's output, two fewer than
+  ``ragged_dot`` x 3; sort, gathers, un-sort and the float32 combine
+  stay ``moe_dropless``'s.
+
+An expert is taken whole by both: shapes whose expert twice over (one
+computing, one arriving) would not fit a v5e's VMEM are refused, not
+tiled.
+
+Off-TPU the kernels run only under the Pallas interpreter, asked for by
 the caller (``interpret=True``, or ``flash_attention.interpret_kernels``
 around the model call).
 """
@@ -50,9 +88,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the most rows the kernel takes. Every row passes every hit expert, so
-# the arithmetic grows with the rows while the bytes do not: at 128 rows
-# it is 128 operations a byte, half a v5e's ridge (197 TFLOP/s over 819
+# the most rows ``edl_expert_mlp`` takes; more go sorted through
+# ``edl_grouped_expert_mlp``. Every row passes every hit expert, so the
+# arithmetic grows with the rows while the bytes do not: at 128 rows it
+# is 128 operations a byte, half a v5e's ridge (197 TFLOP/s over 819
 # GB/s = 240), and one row tile of its 128 x 128 MXU
 MAX_ROWS = 128
 VMEM_BYTES = 128 << 20  # a v5e's
@@ -180,3 +219,169 @@ def expert_mlp(
         name="edl_expert_mlp",
     )(hit, n_hit, x, c, w1, w3, w2)
     return out[:n] if pad else out
+
+
+# -- more rows than MAX_ROWS: each expert its own run of the sorted rows -----
+
+GROUP_TILE = 128  # rows a visit: one pass of the 128 x 128 MXU a weight tile
+
+
+def group_visits(sizes: jnp.ndarray, n_tiles: int, tile: int):
+    """The (expert, row tile) pairs a grouped call has to visit, expert
+    by expert and tile by tile inside an expert, as int32 arrays for the
+    kernel's scalar memory. ``sizes [held]``: rows of each expert's run
+    in the sorted rows (runs lie back to back from row 0; rows past the
+    last run belong to nobody). A tile that a run's end crosses is
+    visited once for each run in it, so there are at most ``n_tiles +
+    held - 1`` visits: the arrays have that length and repeat their
+    last visit past ``n_visits``.
+
+    Returns (expert [V], tile [V], fresh [V]: 1 where the visit is its
+    expert's first, slot [V]: which of the two weight buffers the expert
+    lies in, ahead [V]: the next expert with rows, -1 after the last,
+    edges [held + 1]: where each run starts, n_visits [1])."""
+    held = sizes.shape[0]
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first_tile = starts // tile
+    tiles = jnp.where(sizes > 0, (ends - 1) // tile - first_tile + 1, 0)
+    upto = jnp.cumsum(tiles)  # visits up to and with expert g
+    n_visits = upto[-1]
+    v = jnp.minimum(jnp.arange(n_tiles + held - 1, dtype=jnp.int32),
+                    jnp.maximum(n_visits - 1, 0))
+    # the expert of visit v: how many experts' visits end at or before it
+    g = jnp.minimum(jnp.sum(upto[None, :] <= v[:, None], axis=1,
+                            dtype=jnp.int32), held - 1)
+    since = v - (upto[g] - tiles[g])
+    # the visit after an expert's last is the next expert's first
+    after = jnp.minimum(upto[g], v.shape[0] - 1)
+    ahead = jnp.where(upto[g] < n_visits, g[after], -1)
+    rank = jnp.cumsum(sizes > 0, dtype=jnp.int32) - 1
+    edges = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return (g, first_tile[g] + since, (since == 0).astype(jnp.int32),
+            rank[g] % 2, ahead, edges, n_visits.reshape(1))
+
+
+def visit_tile(x_ref, w1, w3, w2, o_ref, t, start, end):
+    """Row tile ``t`` whole through one expert's matrices; the rows
+    ``start <= row < end`` (the expert's run) are written. The tile
+    stays in VMEM while consecutive visits name it: each run of rows in
+    it is written by its own expert's visit."""
+    x = x_ref[...]
+    dt = x.dtype
+    a = jnp.dot(x, w1.astype(dt), preferred_element_type=jnp.float32)
+    b = jnp.dot(x, w3.astype(dt), preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(a) * b).astype(dt)
+    y = jnp.dot(h, w2.astype(dt), preferred_element_type=jnp.float32)
+    tile = x.shape[0]
+    row = t * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    mine = (row >= start) & (row < end)
+    o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
+
+
+def _grouped_kernel(
+    g_ref, t_ref, fresh_ref, slot_ref, ahead_ref, edge_ref, n_ref,
+    x_ref, w1_hbm, w3_hbm, w2_hbm, o_ref, b1, b3, b2, sem
+):
+    v = pl.program_id(0)
+
+    def copies(e, slot):
+        return [pltpu.make_async_copy(src.at[e], dst.at[slot], sem.at[m, slot])
+                for m, (src, dst) in enumerate(
+                    ((w1_hbm, b1), (w3_hbm, b3), (w2_hbm, b2)))]
+
+    @pl.when((v == 0) & (n_ref[0] > 0))
+    def _first():  # nothing to hide the first expert's arrival under
+        for c in copies(g_ref[0], 0):
+            c.start()
+
+    @pl.when(v < n_ref[0])
+    def _visit():
+        g, slot = g_ref[v], slot_ref[v]
+
+        @pl.when(fresh_ref[v] == 1)
+        def _arrive():
+            @pl.when(ahead_ref[v] >= 0)
+            def _next():  # arrives under ALL of this expert's visits
+                for c in copies(ahead_ref[v], 1 - slot):
+                    c.start()
+
+            for c in copies(g, slot):
+                c.wait()
+
+        visit_tile(x_ref, b1[slot], b3[slot], b2[slot], o_ref,
+                   t_ref[v], edge_ref[g], edge_ref[g + 1])
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def grouped_expert_mlp(
+    rows: jnp.ndarray,
+    sizes: jnp.ndarray,
+    w1: jnp.ndarray,
+    w3: jnp.ndarray,
+    w2: jnp.ndarray,
+    *,
+    tile: int = GROUP_TILE,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Each expert's SwiGLU over its own run of rows sorted by expert:
+    ``(silu(r @ w1[e]) * (r @ w3[e])) @ w2[e]`` for row ``r`` of run
+    ``e``, in rows' dtype (``jax.lax.ragged_dot`` three times, as one
+    kernel).
+
+    rows [M, d], sorted so that expert ``e``'s ``sizes[e]`` rows follow
+    expert ``e - 1``'s from row 0; w1 / w3 [E_held, d, f], w2 [E_held,
+    f, d], read where they lie, each expert with a row once. Returns
+    [M, d]; a row past the last run holds whatever was there."""
+    m, d = rows.shape
+    held, _, f = w1.shape
+    pad = -m % tile
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    n_tiles = (m + pad) // tile
+    item = max(w1.dtype.itemsize, rows.dtype.itemsize)
+    # the experts twice (one computing, one arriving) and a copy where
+    # they are cast; a tile of rows in and out twice; a, b, h, y and the
+    # compiler's own
+    vmem = (2 * 3 * d * f * w1.dtype.itemsize + 3 * d * f * item
+            + 4 * tile * d * rows.dtype.itemsize
+            + 4 * tile * f * 4 + 2 * tile * d * 4 + (4 << 20))
+    if vmem > VMEM_BYTES:
+        raise ValueError(
+            f"experts of {d} x {f} need {vmem >> 20} MiB of VMEM whole; "
+            f"the chip has {VMEM_BYTES >> 20}")
+    visits = group_visits(sizes, n_tiles, tile)
+
+    def row_tile(v, g_ref, t_ref, *_):
+        return (t_ref[v], 0)
+
+    out = pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(visits),
+            grid=(n_tiles + held - 1,),
+            in_specs=[
+                pl.BlockSpec((tile, d), row_tile),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((tile, d), row_tile),
+            scratch_shapes=[
+                pltpu.VMEM((2, d, f), w1.dtype),
+                pltpu.VMEM((2, d, f), w3.dtype),
+                pltpu.VMEM((2, f, d), w2.dtype),
+                pltpu.SemaphoreType.DMA((3, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, rows.dtype),
+        input_output_aliases={len(visits): 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem),
+        ),
+        interpret=interpret,
+        name="edl_grouped_expert_mlp",
+    )(*visits, rows, w1, w3, w2)
+    return out[:m] if pad else out

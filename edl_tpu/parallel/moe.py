@@ -10,6 +10,7 @@ all-to-all-style collectives over ICI, with no per-token scatter loops
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -138,22 +139,28 @@ def moe_ffn(
 # what overflows, which no published checkpoint's arithmetic does. The
 # functions below never drop, and the weights of an expert nobody chose
 # are not multiplied, and on a bandwidth-bound decode step not read.
-# How many rows a step has picks the algorithm (``moe_dropless``):
+# How many rows a step has, and the weights' kind, pick the algorithm
+# (``moe_dropless``):
 #
-# - more than ``ops.expert_mlp.MAX_ROWS`` (128) rows (every prefill
-#   bucket), an int8 record, or no kernels asked for: every (token,
-#   chosen expert) pair is a row, rows are sorted by expert, and each
-#   expert multiplies its own contiguous run of rows
-#   (``jax.lax.ragged_dot``: grouped matmuls whose group sizes are
-#   data). Compute-bound, XLA's own ground;
-# - up to 128 rows (a decode step) under ``kernel``: no sort. An expert
-#   has N * k / E rows (4.5 at 96 x 6 / 128), the layer is its weights
-#   passing through the chip once, and XLA's grouped matmuls stream
-#   them at 56% of a v5e's HBM peak; ``edl_expert_mlp`` puts all N rows
-#   through each hit expert while the next one's weights arrive. Why
-#   128: the wasted rows cost N operations a byte against the chip's
-#   ridge of ~240, and 128 rows are one pass of its 128 x 128 MXU per
-#   weight tile; past that the arithmetic shows and sorting pays.
+# - up to ``ops.expert_mlp.MAX_ROWS`` (128) rows (a decode step) under
+#   ``kernel``: no sort. An expert has N * k / E rows (4.5 at 96 x 6 /
+#   128), the layer is its weights passing through the chip once, and
+#   XLA's grouped matmuls stream them at 56% of a v5e's HBM peak;
+#   ``edl_expert_mlp`` puts all N rows through each hit expert while the
+#   next one's weights arrive. Why 128: the wasted rows cost N
+#   operations a byte against the chip's ridge of ~240, and 128 rows are
+#   one pass of its 128 x 128 MXU per weight tile; past that the
+#   arithmetic shows and sorting pays;
+# - more rows (every prefill bucket): every (token, chosen expert) pair
+#   is a row, rows are sorted by expert, and each expert multiplies its
+#   own contiguous run of rows. A bucket of 1024-4096 tokens gives an
+#   expert 48-192 rows, still under the ridge: under ``kernel``
+#   ``edl_grouped_expert_mlp`` fetches each hit expert once and passes
+#   its run through in row tiles;
+# - an int8 record, or no kernels asked for, at any row count: the
+#   sorted rows through ``jax.lax.ragged_dot`` (grouped matmuls whose
+#   group sizes are data), the plain form the kernels are tested
+#   against.
 
 
 def route_sigmoid_topk(
@@ -213,47 +220,66 @@ def moe_dropless(
     weighted sum of the HELD experts' outputs for each token: the shares
     of disjoint ranges add up to the whole layer's routed term.
 
-    ``kernel`` (the model's ``use_flash``) lets a step of at most
-    ``ops.expert_mlp.MAX_ROWS`` rows over plain weight arrays run
-    ``edl_expert_mlp`` (a share of the experts too); the row count and
-    the weights' kind decide, nothing else. The rest is the grouped
-    form: rows are the N * k (token, choice) pairs, sorted by expert
-    (stable: a token's rows keep their order); a pair whose expert is
-    not held sorts behind every group, belongs to none, and is given
-    weight 0."""
-    n, k = idx.shape
-    int8 = isinstance(w1, dict)
-    held = (w1["q8"] if int8 else w1).shape[0]
+    ``kernel`` (the model's ``use_flash``) lets a step over plain
+    weight arrays run ``edl_expert_mlp`` (at most
+    ``ops.expert_mlp.MAX_ROWS`` rows, unsorted) or
+    ``edl_grouped_expert_mlp`` (more rows, in the three grouped
+    matmuls' place), a share of the experts too; the row count and the
+    weights' kind decide, nothing else. Past ``MAX_ROWS``, and without
+    kernels, the form is grouped: rows are the N * k (token, choice)
+    pairs, sorted by expert (stable: a token's rows keep their order);
+    a pair whose expert is not held sorts behind every group, belongs
+    to none, and is given weight 0."""
+    n = idx.shape[0]
+    interpret = False
+    kernel = kernel and not isinstance(w1, dict)
     with jax.named_scope("moe.experts"):
-        if kernel and not int8:
+        if kernel:
             from edl_tpu.ops import expert_mlp as _em
             from edl_tpu.ops.flash_attention import _INTERPRET
 
+            interpret = _INTERPRET.get()
             if n <= _em.MAX_ROWS:
                 return _em.expert_mlp(
-                    x, idx, w, w1, w3, w2, first=first,
-                    interpret=_INTERPRET.get())
-        local = idx.reshape(-1) - first
-        mine = (local >= 0) & (local < held)
-        key = jnp.where(mine, local, held)
-        order = jnp.argsort(key, stable=True)
+                    x, idx, w, w1, w3, w2, first=first, interpret=interpret)
+        return _sorted_experts(x, idx, w, w1, w3, w2, first=first,
+                               kernel=kernel, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("first", "kernel", "interpret"))
+def _sorted_experts(x, idx, w, w1, w3, w2, *, first, kernel, interpret):
+    """``moe_dropless``'s grouped form. Its own ``jit``: a model's
+    layers are traced one by one, and the layers after the first take
+    this jaxpr as traced (a prefill program's seven expert layers cost
+    its set-up one trace, not seven)."""
+    n, k = idx.shape
+    held = (w1["q8"] if isinstance(w1, dict) else w1).shape[0]
+    local = idx.reshape(-1) - first
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
+        axis=0, dtype=jnp.int32,
+    )
+    rows = x[order // k]
+    if kernel:
+        from edl_tpu.ops.expert_mlp import grouped_expert_mlp
+
+        out = grouped_expert_mlp(rows, sizes, w1, w3, w2, interpret=interpret)
+    else:
         expert_of = jnp.minimum(key[order], held - 1)
-        sizes = jnp.sum(
-            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :],
-            axis=0, dtype=jnp.int32,
-        )
-        rows = x[order // k]
         h = jax.nn.silu(_ragged(rows, w1, sizes, expert_of)) * _ragged(
             rows, w3, sizes, expert_of
         )
         out = _ragged(h, w2, sizes, expert_of)
-        # back to (token, choice) order: a gather, where a scatter-add
-        # over tokens would serialise on the TPU
-        out = out[jnp.argsort(order)].reshape(n, k, -1)
-        wk = jnp.where(mine.reshape(n, k), w, 0.0)
-        # a row of no group holds whatever the grouped matmul left there
-        out = jnp.where(mine.reshape(n, k, 1), out.astype(jnp.float32), 0.0)
-        return jnp.sum(out * wk[..., None], axis=1).astype(x.dtype)
+    # back to (token, choice) order: a gather, where a scatter-add
+    # over tokens would serialise on the TPU
+    out = out[jnp.argsort(order)].reshape(n, k, -1)
+    wk = jnp.where(mine.reshape(n, k), w, 0.0)
+    # a row of no group holds whatever the grouped matmul left there
+    out = jnp.where(mine.reshape(n, k, 1), out.astype(jnp.float32), 0.0)
+    return jnp.sum(out * wk[..., None], axis=1).astype(x.dtype)
 
 
 def expert_load(idx: jnp.ndarray, n_experts: int, rows=None):

@@ -1,8 +1,10 @@
 """The routed experts of one ``kanana2.decode-wide`` layer, alone on the
 chip: how fast each candidate streams the HIT experts' weights at a
-decode step's few rows (PERF.md section 6, PR 36).
+decode step's few rows (PERF.md section 6, PR 36) and at a prefill
+bucket's many (PR 38).
 
     chiprun -- python scripts/exp_expert_matmul.py
+    chiprun -- python scripts/exp_expert_matmul.py --rows 512 1024 2048 4096 --iters 30
 
 128 experts of 2048 x 768 (bf16, three matrices each), N tokens that
 each choose 6, the routing drawn with a popularity skew so that at N =
@@ -22,12 +24,22 @@ read). Candidates, one JSON line each at every N:
   expert a grid step through the BlockSpec pipeline), the whole layer;
 - ``edl_expert_mlp_ring[<buffers>]``: the same arithmetic with the
   experts fetched by hand into a ring of ``buffers`` (this file's
-  ``expert_mlp_ring``: what deeper buffering would buy).
+  ``expert_mlp_ring``: what deeper buffering would buy);
+- past ``MAX_ROWS`` rows, where ``edl_expert_mlp`` does not go:
+  ``grouped_x1[tile]``: ``ops.expert_mlp.grouped_expert_mlp`` in the
+  three grouped matmuls' place (each hit expert fetched by hand under
+  ALL the visits of the one before it), ``grouped_pipeline_x1[tile]``:
+  the same visits with the experts as BlockSpec operands (this file's
+  ``grouped_pipeline``: an expert arrives under the LAST visit of the
+  one before it alone), and ``moe_dropless_grouped``: the whole layer
+  as ``parallel.moe`` runs it under ``kernel`` since PR 38.
 
 ``ms`` is the host clock over ``--iters`` back-to-back calls ended by
 one ``block_until_ready``; ``gbps`` the hit experts' weights over it;
+``tflops`` the N * 6 rows' 6 * d * f operations over it;
 ``err`` the largest difference from the float32 table, over the
-largest entry of the table.
+largest entry of the table (the grouped matmuls alone: from
+``ragged_dot_x3``'s rows, over their largest entry).
 """
 
 from __future__ import annotations
@@ -200,6 +212,49 @@ def expert_mlp_ring(x, idx, w, w1, w3, w2, buffers: int = 3):
     )(hit, n_hit, x, c, w1, w3, w2)
 
 
+def _pipeline_kernel(g_ref, t_ref, fresh_ref, slot_ref, ahead_ref, edge_ref,
+                     n_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref):
+    v = pl.program_id(0)
+
+    @pl.when(v < n_ref[0])
+    def _visit():
+        g = g_ref[v]
+        em.visit_tile(x_ref, w1_ref[...], w3_ref[...], w2_ref[...], o_ref,
+                      t_ref[v], edge_ref[g], edge_ref[g + 1])
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def grouped_pipeline(rows, sizes, w1, w3, w2, tile: int = em.GROUP_TILE):
+    """``em.grouped_expert_mlp``'s visits with the experts as BlockSpec
+    operands: the pipeline asks for a visit's blocks one visit ahead."""
+    m, d = rows.shape
+    held, _, f = w1.shape
+    n_tiles = m // tile
+    visits = em.group_visits(sizes, n_tiles, tile)
+    row_tile = lambda v, g_ref, t_ref, *_: (t_ref[v], 0)
+    expert = lambda v, g_ref, *_: (g_ref[v], 0, 0)
+    return pl.pallas_call(
+        _pipeline_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(visits),
+            grid=(n_tiles + held - 1,),
+            in_specs=[
+                pl.BlockSpec((tile, d), row_tile),
+                pl.BlockSpec((None, d, f), expert),
+                pl.BlockSpec((None, d, f), expert),
+                pl.BlockSpec((None, f, d), expert),
+            ],
+            out_specs=pl.BlockSpec((tile, d), row_tile),
+        ),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=3 * 3 * d * f * 2 + 8 * tile * d * 4 + (8 << 20),
+        ),
+        name="edl_grouped_pipeline",
+    )(*visits, rows, w1, w3, w2)
+
+
 def table(x, idx, w, w1, w3, w2):
     """The float32 layer, every expert over every row (on the host's
     terms: ``highest``)."""
@@ -258,33 +313,54 @@ def main():
         need = hit * E * 3 * D * F * 2
         want = table(x, idx, w, w1, w3, w2)
         top = float(jnp.max(jnp.abs(want)))
+        base = ragged_x3(rows, sizes, w1, w3, w2).astype(jnp.float32)
+        base_top = float(jnp.max(jnp.abs(base)))
         forms = {
             "ragged_dot_x3": (lambda: ragged_x3(rows, sizes, w1, w3, w2), None),
             "moe_dropless_ragged": (functools.partial(
                 jax.jit(moe.moe_dropless), x, idx, w, w1, w3, w2), True),
         }
+        many = n > em.MAX_ROWS
         for tiling in ((128, 2048, 768), (64, 2048, 768), (128, 1024, 768),
-                       (128, 512, 768)):
-            if rows_p.shape[0] % tiling[0] == 0:
+                       (128, 512, 768), (256, 2048, 768), (512, 1024, 768)):
+            if rows_p.shape[0] % tiling[0] == 0 and (
+                    many or tiling[0] <= 128):
                 forms[f"gmm_x3{list(tiling)}"] = (functools.partial(
                     gmm_x3, rows_p, sizes, w1, w3, w2, tiling), None)
         for tiling in ((128, 2048, 768), (64, 2048, 768)):
             forms[f"moe_dropless_gmm{list(tiling)}"] = (functools.partial(
                 moe_dropless_gmm, x, idx, w, w1, w3, w2, tiling), True)
-        forms["edl_expert_mlp"] = (functools.partial(
-            em.expert_mlp, x, idx, w, w1, w3, w2), True)
-        if n % 16 == 0:
-            for buffers in (2, 3, 4):
-                forms[f"edl_expert_mlp_ring[{buffers}]"] = (functools.partial(
-                    expert_mlp_ring, x, idx, w, w1, w3, w2, buffers=buffers),
-                    True)
+        if many:
+            for tile in (64, 128, 256):
+                if rows_p.shape[0] % tile == 0:
+                    forms[f"grouped_x1[{tile}]"] = (functools.partial(
+                        em.grouped_expert_mlp, rows_p, sizes, w1, w3, w2,
+                        tile=tile), None)
+                    forms[f"grouped_pipeline_x1[{tile}]"] = (
+                        functools.partial(grouped_pipeline, rows_p, sizes,
+                                          w1, w3, w2, tile=tile), None)
+            forms["moe_dropless_grouped"] = (functools.partial(
+                jax.jit(functools.partial(moe.moe_dropless, kernel=True)),
+                x, idx, w, w1, w3, w2), True)
+        else:
+            forms["edl_expert_mlp"] = (functools.partial(
+                em.expert_mlp, x, idx, w, w1, w3, w2), True)
+            if n % 16 == 0:
+                for buffers in (2, 3, 4):
+                    forms[f"edl_expert_mlp_ring[{buffers}]"] = (
+                        functools.partial(expert_mlp_ring, x, idx, w, w1, w3,
+                                          w2, buffers=buffers), True)
         for name, (fn, whole) in forms.items():
             try:
                 ms = timed(fn, args.iters)
-                err = float(jnp.max(jnp.abs(
-                    fn().astype(jnp.float32) - want))) / top if whole else None
+                # a whole layer against the float32 table; grouped
+                # matmuls alone against ``ragged_dot``'s, run by run
+                ref, scale = (want, top) if whole else (base, base_top)
+                err = float(jnp.max(jnp.abs(fn().astype(jnp.float32)[
+                    :ref.shape[0]] - ref))) / scale
                 report(form=name, rows=n, hit_share=hit, max_over_mean=skew,
-                       ms=ms, gbps=need / ms / 1e6, err=err)
+                       ms=ms, gbps=need / ms / 1e6,
+                       tflops=n * K * 6 * D * F / ms / 1e9, err=err)
             except Exception as e:  # a form the compiler refuses is a finding
                 report(form=name, rows=n, error=str(e)[:300])
     with open(args.out, "w") as f:

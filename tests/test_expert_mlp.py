@@ -1,7 +1,9 @@
 """``ops/expert_mlp.py`` (``edl_expert_mlp``: the routed experts of a
-decode step's few rows, no sort) under the Pallas interpreter, against
-the grouped form it stands in for (``parallel.moe.moe_dropless``
-without ``kernel``) and the benchmark's float32 table. float32 at
+decode step's few rows, no sort; ``edl_grouped_expert_mlp``: those of a
+prefill's many, each expert its run of the sorted rows) under the
+Pallas interpreter, against the grouped form they stand in for
+(``parallel.moe.moe_dropless`` without ``kernel``:
+``jax.lax.ragged_dot``) and the benchmark's float32 table. float32 at
 ``highest`` on both sides differs by the order of summation alone, so
 1e-5 holds; bfloat16 rows and weights are held to bfloat16's step."""
 
@@ -161,11 +163,13 @@ def test_a_share_nobody_chose_is_zeros():
     assert bool(jnp.all(got == 0.0)) and bool(jnp.all(want == 0.0))
 
 
-@pytest.mark.parametrize("case", ["129 rows", "int8 record", "no kernels"])
-def test_what_the_kernel_does_not_take_goes_through_the_grouped_matmul(case):
+@pytest.mark.parametrize("n", [20, 129])
+@pytest.mark.parametrize("case", ["int8 record", "no kernels"])
+def test_what_the_kernels_do_not_take_goes_through_the_grouped_matmul(case, n):
+    """The control's int8 record and ``use_flash=False``, at a decode
+    step's rows and at a prefill's."""
     from edl_tpu.models import deepseek_v3 as ds
 
-    n = 129 if case == "129 rows" else 20
     lp = layer(8)
     x = jax.random.normal(jax.random.PRNGKey(800), (n, D))
     idx, w = routed(lp, x)
@@ -183,8 +187,10 @@ def test_what_the_kernel_does_not_take_goes_through_the_grouped_matmul(case):
     assert "pallas_call" not in text
 
 
-@pytest.mark.parametrize("n", [1, 128])
-def test_a_decode_step_of_any_size_is_one_kernel_and_no_grouped_matmul(n):
+@pytest.mark.parametrize("n", [1, 128, 129, 1024])
+def test_a_step_of_any_size_is_one_kernel_and_no_grouped_matmul(n):
+    """Up to ``MAX_ROWS`` rows no sort either; past them the rows are
+    sorted and the one kernel is the grouped one."""
     lp = layer(9)
     x = jax.random.normal(jax.random.PRNGKey(900), (n, D))
     idx, w = routed(lp, x)
@@ -192,7 +198,9 @@ def test_a_decode_step_of_any_size_is_one_kernel_and_no_grouped_matmul(n):
         text = jaxpr_text(lambda x: moe.moe_dropless(
             x, idx, w, lp["we1"], lp["we3"], lp["we2"], kernel=True), x)
     assert text.count(" = pallas_call[") == 1
-    assert "ragged_dot" not in text and " sort[" not in text
+    assert "ragged_dot" not in text
+    assert (" sort[" in text) == (n > em.MAX_ROWS)
+    assert ("edl_grouped_expert_mlp" in text) == (n > em.MAX_ROWS)
 
 
 def test_more_rows_than_the_kernel_takes_are_refused_by_the_kernel():
@@ -217,6 +225,15 @@ def test_experts_that_do_not_fit_vmem_whole_twice_over_are_refused():
     assert jax.eval_shape(run, *shapes(2048, 768)).shape == (96, 2048)
     with pytest.raises(ValueError, match="MiB of VMEM"):
         jax.eval_shape(run, *shapes(7168, 2048))
+
+    def grouped(x, idx, w, w1, w3, w2):  # 96 x 6 sorted rows
+        rows = jnp.repeat(x, 6, axis=0)
+        return em.grouped_expert_mlp(
+            rows, jnp.zeros((E,), jnp.int32), w1, w3, w2, interpret=True)
+
+    assert jax.eval_shape(grouped, *shapes(2048, 768)).shape == (576, 2048)
+    with pytest.raises(ValueError, match="MiB of VMEM"):
+        jax.eval_shape(grouped, *shapes(7168, 2048))
 
 
 def test_a_weight_of_exactly_zero_reads_as_not_chosen():
@@ -262,3 +279,107 @@ def test_the_hit_list_is_the_hit_experts_ascending_then_the_last_again():
     c, _, _ = em.combine_weights(jnp.array([[3, 3]]), jnp.array([[1., 2.]]),
                                  4, 0)
     assert c.tolist() == [[0.0, 0.0, 0.0, 3.0]]
+
+
+# -- edl_grouped_expert_mlp: more rows than MAX_ROWS ---------------------------
+
+
+def skewed(seed, e=128):
+    """A layer whose router bias makes a few experts popular and leaves
+    some with no row at all (the cell's 2.5-3 x busiest over mean)."""
+    lp = layer(seed, e=e)
+    lp["router_bias"] = jax.random.normal(jax.random.PRNGKey(seed), (e,)) * 0.3
+    return lp
+
+
+# tokens, experts, choices a token, and what the case adds to "the
+# kernel's layer is the ragged_dot form's and the float32 table's"
+GROUPED = {
+    "256 tokens": dict(n=256),
+    "1024 tokens": dict(n=1024),
+    "4096 tokens": dict(n=4096),
+    "bfloat16 rows": dict(n=1024, dtype=jnp.bfloat16),
+    "rows not a multiple of the tile": dict(n=129, e=16, k=3),
+    "runs that end inside a tile": dict(n=192, e=16, k=2, to=(2, 5)),
+    "experts nobody chose": dict(n=256, poison=True),
+    "eight shares add up": dict(n=256, shares=8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED))
+def test_grouped_kernel_layer_is_the_ragged_form_and_the_references_table(case):
+    spec = dict(e=128, k=6, dtype=jnp.float32, to=None, poison=False,
+                shares=1)
+    spec.update(GROUPED[case])
+    n, e, k, dtype = spec["n"], spec["e"], spec["k"], spec["dtype"]
+    lp = skewed(len(case), e)
+    x = jax.random.normal(jax.random.PRNGKey(n), (n, D))
+    if spec["to"]:  # every token to the same experts: two runs of n rows
+        idx = jnp.tile(jnp.array([spec["to"]]), (n, 1))
+        w = jnp.tile(jnp.array([[0.7, 0.3]]), (n, 1))
+    else:
+        idx, w = routed(lp, x, k)
+    sizes = jnp.sum(idx.reshape(-1)[:, None] == jnp.arange(e), axis=0)
+    ends = jnp.cumsum(sizes)
+    assert int(jnp.sum(ends[:-1] % em.GROUP_TILE != 0)) > 0
+    assert (n * k % em.GROUP_TILE != 0) == (case.startswith("rows not"))
+    if not spec["to"] and e == 128:
+        assert float(jnp.max(sizes)) > 2.0 * n * k / e, "no skew"
+    with jax.default_matmul_precision("highest"):
+        table = jnp.zeros((n, e)).at[jnp.arange(n)[:, None], idx].add(w)
+        x = x.astype(dtype)
+        experts = {name: lp[name].astype(dtype)
+                   for name in ("we1", "we3", "we2")}
+        want = reference.routed(x.astype(jnp.float32), table, *(
+            experts[name].astype(jnp.float32)
+            for name in ("we1", "we3", "we2")))
+    top = float(jnp.max(jnp.abs(want)))
+    assert top > 0.1
+    sound = experts
+    if spec["poison"]:  # one of them read at whatever weight would show
+        unhit = (sizes == 0)[:, None, None]
+        assert int(jnp.sum(unhit)) > 0
+        experts = {name: jnp.where(unhit, jnp.nan, leaf)
+                   for name, leaf in experts.items()}
+    held = e // spec["shares"]
+    got = jnp.zeros((n, D), jnp.float32)
+    ragged = jnp.zeros((n, D), jnp.float32)
+    for first in range(0, e, held):
+        share = {name: leaf[first:first + held]
+                 for name, leaf in experts.items()}
+        kw = dict(first=first) if spec["shares"] > 1 else {}
+        part, grouped = both(x, idx, w, share, **kw)
+        if spec["poison"]:  # XLA:CPU's ragged_dot multiplies every expert
+            grouped = both(x, idx, w, sound, **kw)[1]
+        assert part.shape == (n, D) and part.dtype == dtype
+        got += part.astype(jnp.float32)
+        ragged += grouped.astype(jnp.float32)
+    if spec["shares"] > 1:  # one share alone is not the layer
+        assert err(part, want) > 0.1
+    if dtype == jnp.bfloat16:
+        # no further from the table than the form that rounds more often
+        assert err(got, want) < 2.0 ** -6 * top
+        assert err(got, want) <= err(ragged, want) + 2.0 ** -8 * top
+    else:
+        assert err(got, ragged) < 1e-5
+        assert err(got, want) < 1e-5
+
+
+def test_the_visits_are_each_run_tile_by_tile_then_the_last_again():
+    """Runs of 5, 0, 40, 3, 0, 0, 17, 30 rows in tiles of 16: a tile
+    that a run's end crosses is visited once for each run in it."""
+    sizes = jnp.array([5, 0, 40, 3, 0, 0, 17, 30])
+    g, t, fresh, slot, ahead, edges, n = (
+        a.tolist() for a in em.group_visits(sizes, 7, 16))
+    assert n == [9] and len(g) == 7 + 8 - 1
+    assert g[:9] == [0, 2, 2, 2, 3, 6, 6, 7, 7] and set(g[9:]) == {7}
+    assert t[:9] == [0, 0, 1, 2, 2, 3, 4, 4, 5] and set(t[9:]) == {5}
+    assert fresh[:9] == [1, 1, 0, 0, 1, 1, 0, 1, 0]
+    # the experts with rows take the two buffers in turn; each names
+    # the next one with rows, the last nobody
+    assert slot[:9] == [0, 1, 1, 1, 0, 1, 1, 0, 0]
+    assert ahead[:9] == [2, 3, 3, 3, 6, 7, 7, -1, -1]
+    assert edges == [0, 5, 5, 45, 48, 48, 48, 65, 95]
+    # nobody chose a held expert: no visit, and nothing is fetched
+    assert em.group_visits(jnp.zeros((4,), jnp.int32), 2, 16)[-1].tolist() \
+        == [0]

@@ -391,11 +391,16 @@ def test_decode_wide_block_fits_the_chip_and_updates_the_cache_in_place(v5e):
                 if shape in (whole, layer)
                 and op not in ("fusion", "parameter", "scatter",
                               "get-tuple-element")], "a copy of the cache"
-    experts = ("bf16[128,2048,768]", "bf16[128,768,2048]")
-    assert not [op for shape, op in made
-                if shape in experts
-                and op not in ("parameter", "get-tuple-element")], \
-        "experts copied"
+    assert not _expert_leaves_made(text), "experts copied"
+
+
+def _expert_leaves_made(text):
+    """Operations of an optimized decode-wide program that make an
+    array as large as a layer's experts (a copy, a transpose)."""
+    made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text)
+    return [op for shape, op in made
+            if shape in ("bf16[128,2048,768]", "bf16[128,768,2048]")
+            and op not in ("parameter", "get-tuple-element")]
 
 
 @pytest.mark.parametrize("rows", [16, 96, 128])
@@ -419,6 +424,28 @@ def test_expert_mlp_kernel_compiles_for_v5e(v5e, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+@pytest.mark.parametrize("tokens", [256, 2048, 4096])
+def test_grouped_expert_mlp_kernel_compiles_for_v5e(v5e, tokens):
+    """``edl_grouped_expert_mlp`` at the cell's widths over a prefill
+    bucket's sorted rows (six a token): a whole expert twice over in
+    VMEM, fetched by hand; the rows are written where they were read
+    (aliased), so nothing row- or expert-sized is made beside them."""
+    from edl_tpu.ops.expert_mlp import grouped_expert_mlp
+
+    one = SingleDeviceSharding(v5e[0])
+    e, d, f, k = 128, 2048, 768, 6
+    compiled = jax.jit(grouped_expert_mlp, donate_argnums=0).lower(
+        _sds((tokens * k, d), jnp.bfloat16, one), _sds((e,), jnp.int32, one),
+        _sds((e, d, f), jnp.bfloat16, one), _sds((e, d, f), jnp.bfloat16, one),
+        _sds((e, f, d), jnp.bfloat16, one),
+    ).compile()
+    text = compiled.as_text()
+    assert "edl_grouped_expert_mlp" in text and "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == tokens * k * d * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # -- PR 35: a cache that is a state a slot --------------------------------------
 
 # sha256 (first 16 hex digits) of the lowered text of each serving
@@ -427,15 +454,17 @@ def test_expert_mlp_kernel_compiles_for_v5e(v5e, rows):
 # the parent commit of PR 35 (546dfaa), whose seam change and
 # ``llama._qkv``'s ``qk_norm`` must leave these programs as they were.
 # PR 36 re-recorded decode-wide's block alone (``edl_expert_mlp`` for
-# the grouped matmuls): its prefill's digest standing is the proof that
-# prefill bypasses the kernel, the other pairs' that the dense decoders do.
+# the grouped matmuls), PR 38 its prefill alone
+# (``edl_grouped_expert_mlp`` for prefill's): the block's digest
+# standing is the proof that a decode step bypasses the new kernel, the
+# other pairs' that the dense decoders do.
 # PR 37 added decode-state's pair from its own parent (36433bc): the
 # seam widened for two kinds of cache, ``llama._qkv`` without RoPE and
 # ``_mlp``'s residual multiplier leave all four as they were
 PARENTS_TEXT = {
     "deepseek7b.decode-closed": ("1295e2201debccba", "2b18d84018a249e3"),
     "mistral7b.serve-open": ("f67a0903cf71b5a3", "9edf94936f77b1f2"),
-    "kanana2.decode-wide": ("095d2ebfb11a760f", "4627de3d25bb91b7"),
+    "kanana2.decode-wide": ("095d2ebfb11a760f", "10089310938df9f0"),
     "brumby14b.decode-state": ("5c42d86ecb8336c5", "aa4143d59a7dc56c"),
 }
 
@@ -478,6 +507,27 @@ def test_serving_cells_programs_lower_to_the_parents_text(v5e, cell):
             lowered.as_text()).encode()).hexdigest()[:16]
         for lowered in (block, prefill))
     assert got == PARENTS_TEXT[cell]
+
+
+def test_decode_wide_prefill_runs_the_grouped_kernel_once_an_expert_layer(v5e):
+    """``edl_serve_prefill_2048`` of ``kanana2.decode-wide``, optimized:
+    no ``ragged-dot`` (21 a prompt before PR 38), one
+    ``edl_grouped_expert_mlp`` an expert layer, no expert leaf copied
+    or transposed, and it fits beside the weights and the cache. The
+    temporaries are 0.52 GB (0.46 before PR 38: XLA kept one more
+    [12288, 2048] array in the other memory space; the 4096 bucket's,
+    the largest, stayed 0.87-0.88)."""
+    _, _, prefill = _serving_programs(v5e, "kanana2.decode-wide", 2048)
+    compiled = prefill.compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert len(re.findall(r"%ragged-dot[\w\-.]* = bf16", text)) == 0
+    assert len(re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*edl_grouped_expert_mlp",
+        text)) == 7, "edl_grouped_expert_mlp once an expert layer"
+    assert not _expert_leaves_made(text), "experts copied"
+    assert mem.temp_size_in_bytes < 0.55e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def _state_sized(text, cfg, slots):
