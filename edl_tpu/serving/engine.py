@@ -1061,8 +1061,10 @@ class ContinuousBatchingEngine:
         and live requests replayed — the engine object stays usable and
         no accepted request is silently lost."""
         try:
-            # parent of admit / prefill / dispatch / drain: its self
-            # time is the iteration's host bookkeeping
+            # parent of admit / account / dispatch / drain / replay: its
+            # self time is the eviction pass, what follows a dispatch's
+            # program call (_block_dispatched) and its children's own
+            # opening and closing
             with tracing.span("serving.step"):
                 return self._step_inner()
         except Exception as e:
@@ -1090,6 +1092,28 @@ class ContinuousBatchingEngine:
             # no longer starves running slots behind one monolithic
             # prefill dispatch
             emitted += self._advance_prefills()
+        # the host work between an admission's first-token sync and the
+        # next block: this stretch, and under the same name what a
+        # dispatch does before its program call
+        with tracing.span("serving.account"):
+            decoding = self._account_step()
+        if decoding:
+            if self.spec_k > 0:
+                emitted += self._step_spec()
+            else:
+                self._dispatch_block()
+                # double buffer: block k+1 is now on device; drain
+                # block k (bookkeeping overlaps the device work, no
+                # idle bubble)
+                while len(self._inflight) > 1:
+                    emitted += self._drain_one()
+        else:
+            emitted += self._drain_all()
+        return emitted
+
+    def _account_step(self) -> int:
+        """The step's gauges (live slots, queue depth, cache occupancy);
+        returns how many slots are decoding."""
         active_n = self.active_slots
         self.metrics.on_step(active_n, self.max_slots, self.queue.depth)
         if self._paged:
@@ -1118,22 +1142,9 @@ class ContinuousBatchingEngine:
             )
         # slots still mid-chunked-prefill have no decode state yet —
         # the block dispatch runs only when someone is actually decoding
-        decoding = sum(
+        return sum(
             1 for s in self._slots if s is not None and s.pf_next is None
         )
-        if decoding:
-            if self.spec_k > 0:
-                emitted += self._step_spec()
-            else:
-                self._dispatch_block()
-                # double buffer: block k+1 is now on device; drain
-                # block k (bookkeeping overlaps the device work, no
-                # idle bubble)
-                while len(self._inflight) > 1:
-                    emitted += self._drain_one()
-        else:
-            emitted += self._drain_all()
-        return emitted
 
     def _step_spec(self) -> int:
         """One speculative iteration: draft per decoding slot from its
@@ -1302,8 +1313,22 @@ class ContinuousBatchingEngine:
         return (jnp.asarray(tbl),)
 
     def _dispatch_block(self) -> None:
-        where = self._block_table()
-        old = (self._dtok, self._dpos, self._dact, self._drem) + self._cache
+        with tracing.span("serving.account"):
+            where = self._block_table()
+            old = (self._dtok, self._dpos, self._dact,
+                   self._drem) + self._cache
+            # one list a block: the drain of this block reads it again
+            rids = [s.rid for s in self._slots if s is not None]
+            attrs = {"horizon": self.horizon, "rids": rids}
+            cost = self._block_cost
+            if not self._paged:
+                # what of each kind of cache this block reads, under the
+                # model's own names for them
+                shares = self._cache_read()
+                attrs.update(shares)
+                cost = self._cost.decode_block(
+                    self.max_slots, self.horizon, self.max_len, shares
+                )
         # span measures the ENQUEUE cost only (the dispatch is async);
         # the device-side block time shows up as serving.drain on the
         # block that finally syncs it — together they are the
@@ -1312,18 +1337,6 @@ class ContinuousBatchingEngine:
         # same correlation key as /events?rid= (block spans are shared
         # across requests; per-request identity is the attr, not the
         # span).
-        # one list a block: the drain of this block reads it again
-        rids = [s.rid for s in self._slots if s is not None]
-        attrs = {"horizon": self.horizon, "rids": rids}
-        cost = self._block_cost
-        if not self._paged:
-            # what of each kind of cache this block reads, under the
-            # model's own names for them
-            shares = self._cache_read()
-            attrs.update(shares)
-            cost = self._cost.decode_block(
-                self.max_slots, self.horizon, self.max_len, shares
-            )
         with tracing.span("serving.dispatch", **attrs) as attrs:
             (toks, self._dtok, self._dpos, self._dact, self._drem,
              *cache, counters) = self._decode(
@@ -1397,15 +1410,17 @@ class ContinuousBatchingEngine:
         identically (``generated`` holds only drained tokens, so the
         replay's committed truth is complete mid-speculation)."""
         d = self.spec_k
-        dm = np.full((self.max_slots, d), -1, np.int32)
-        drafted: Dict[int, int] = {}
-        for i, row in drafts.items():
-            row = row[:d]
-            dm[i, :len(row)] = row
-            drafted[i] = len(row)
-        where = self._block_table()
-        old = (self._dtok, self._dpos, self._dact, self._drem) + self._cache
-        rids = [s.rid for s in self._slots if s is not None]
+        with tracing.span("serving.account"):
+            dm = np.full((self.max_slots, d), -1, np.int32)
+            drafted: Dict[int, int] = {}
+            for i, row in drafts.items():
+                row = row[:d]
+                dm[i, :len(row)] = row
+                drafted[i] = len(row)
+            where = self._block_table()
+            old = (self._dtok, self._dpos, self._dact,
+                   self._drem) + self._cache
+            rids = [s.rid for s in self._slots if s is not None]
         with tracing.span("serving.dispatch", horizon=self.horizon,
                           rids=rids, spec_k=d):
             (toks, self._dtok, self._dpos, self._dact, self._drem,
@@ -1428,7 +1443,7 @@ class ContinuousBatchingEngine:
         # rids: the list the block's dispatch built (the requests that
         # ride the block being synced)
         with tracing.span("serving.drain", rids=self._inflight[0][5]):
-            blk, t_dispatch, members, cost, drafted, _, counted = (
+            blk, t_dispatch, members, cost, drafted, rids, counted = (
                 self._inflight.popleft()
             )
             # chaos site: the popped block is lost on a crash here —
@@ -1440,64 +1455,68 @@ class ContinuousBatchingEngine:
                 counters, dispatch_attrs = counted
                 dispatch_attrs.update(
                     (k, float(v)) for k, v in counters.items())
-        # dispatch -> drained wall time: the decode-phase granule of
-        # the latency decomposition (end-to-end as the host saw it)
-        now = self.clock()
-        self.metrics.on_block(now - t_dispatch)
-        # roofline accounting: the block's analytic cost (horizon or
-        # verify, stamped at dispatch) over its busy window, clipped
-        # against the previous drain so the double buffer cannot
-        # charge overlapped device time twice
-        self._eff.observe(
-            "decode", cost, now - max(self._t_eff_last, t_dispatch)
-        )
-        self._t_eff_last = now
-        emitted = 0
-        spec_drafted = spec_accepted = 0
-        for i in range(self.max_slots):
-            sl = self._slots[i]
-            if sl is None:
-                continue  # freed by an earlier drain; lanes are -1
-            if members.get(i) != sl.rid:
-                # lane belonged to a different occupant (or none) when
-                # this block dispatched — its tokens are not this
-                # request's
-                continue
-            n = 0
-            outcome = None
-            for t in out[i]:
-                t = int(t)
-                if t < 0:
-                    break
-                sl.generated.append(t)
-                n += 1
-                if sl.eos_id is not None and t == sl.eos_id:
-                    outcome = "eos"
-                    break
-                if len(sl.generated) >= sl.max_new:
-                    outcome = "done"
-                    break
-            if n:
-                self.metrics.on_tokens(sl.rid, n)
-                emitted += n
-            if drafted is not None and drafted.get(i, 0) > 0:
-                # verify-block bookkeeping: of this row's emitted run,
-                # everything but the bonus token was an accepted draft
-                # (EOS/budget truncation included — the device emit
-                # mask and this host replay agree lane for lane)
-                nd = drafted[i]
-                acc = max(0, n - 1)
-                spec_drafted += nd
-                spec_accepted += acc
-                self._spec_policy.observe(sl.rid, nd, acc)
-                flight.emit("serve.verify", rid=sl.rid, drafted=nd,
-                            accepted=acc, emitted=n)
-            if outcome:
-                self._finish(i, outcome)
-        if drafted is not None:
-            self.metrics.on_spec(spec_drafted, spec_accepted)
-            if self._kvq_guard is not None:
-                self._kvq_guard.observe(spec_drafted, spec_accepted)
+        # the block is on the host: from here the device has nothing of
+        # this block to wait for, and the replay below is host work
+        with tracing.span("serving.replay", rids=rids) as replay:
+            # dispatch -> drained wall time: the decode-phase granule of
+            # the latency decomposition (end-to-end as the host saw it)
+            now = self.clock()
+            self.metrics.on_block(now - t_dispatch)
+            # roofline accounting: the block's analytic cost (horizon or
+            # verify, stamped at dispatch) over its busy window, clipped
+            # against the previous drain so the double buffer cannot
+            # charge overlapped device time twice
+            self._eff.observe(
+                "decode", cost, now - max(self._t_eff_last, t_dispatch)
+            )
+            self._t_eff_last = now
+            emitted = 0
+            spec_drafted = spec_accepted = 0
+            for i in range(self.max_slots):
+                sl = self._slots[i]
+                if sl is None:
+                    continue  # freed by an earlier drain; lanes are -1
+                if members.get(i) != sl.rid:
+                    # lane belonged to a different occupant (or none) when
+                    # this block dispatched — its tokens are not this
+                    # request's
+                    continue
+                n = 0
+                outcome = None
+                for t in out[i]:
+                    t = int(t)
+                    if t < 0:
+                        break
+                    sl.generated.append(t)
+                    n += 1
+                    if sl.eos_id is not None and t == sl.eos_id:
+                        outcome = "eos"
+                        break
+                    if len(sl.generated) >= sl.max_new:
+                        outcome = "done"
+                        break
+                if n:
+                    self.metrics.on_tokens(sl.rid, n)
+                    emitted += n
+                if drafted is not None and drafted.get(i, 0) > 0:
+                    # verify-block bookkeeping: of this row's emitted run,
+                    # everything but the bonus token was an accepted draft
+                    # (EOS/budget truncation included — the device emit
+                    # mask and this host replay agree lane for lane)
+                    nd = drafted[i]
+                    acc = max(0, n - 1)
+                    spec_drafted += nd
+                    spec_accepted += acc
+                    self._spec_policy.observe(sl.rid, nd, acc)
+                    flight.emit("serve.verify", rid=sl.rid, drafted=nd,
+                                accepted=acc, emitted=n)
+                if outcome:
+                    self._finish(i, outcome)
+            if drafted is not None:
+                self.metrics.on_spec(spec_drafted, spec_accepted)
+                if self._kvq_guard is not None:
+                    self._kvq_guard.observe(spec_drafted, spec_accepted)
+            replay["tokens"] = emitted
         return emitted
 
     def _drain_all(self) -> int:

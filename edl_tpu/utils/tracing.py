@@ -7,8 +7,10 @@ recompiles) into a process-wide tracer that can be dumped as
 chrome://tracing / Perfetto JSON.
 
 One primitive, two places. Every span is written to the ring (on
-``time.perf_counter``) and, in a process that has imported JAX, is also
-a ``jax.profiler.TraceAnnotation`` named ``"edl." + name`` that carries
+``time.perf_counter``, with the opening thread's CPU seconds beside its
+wall seconds and the ``seq`` of the span it opened under) and, in a
+process that has imported JAX, is also a
+``jax.profiler.TraceAnnotation`` named ``"edl." + name`` that carries
 the span's ``seq``: under a profiler session (``jax.profiler.trace`` /
 ``start_trace``) the span sits in the ``.xplane.pb`` beside the
 device's events, and outside one the annotation is a flag check. A span
@@ -53,6 +55,15 @@ class Span:
     # per-tracer monotonic id, taken when the span OPENS (survives ring
     # eviction); the profiler annotation of the span carries the same
     seq: int = 0
+    # CPU seconds of the opening thread between open and close
+    # (``time.thread_time``, exact to 2 x CPU_READ_EVERY_S and the
+    # clock's own step): a span that waits on nothing and reads far
+    # under ``dur_s`` was off its CPU. None for ``record()``, which
+    # times nothing itself
+    cpu_s: Optional[float] = None
+    # seq of the span open on the same thread when this one opened, or
+    # was recorded (of the same tracer); 0 for none
+    parent: int = 0
 
 
 # distributed-trace context hooks (installed by edl_tpu.obs.disttrace
@@ -69,6 +80,15 @@ def set_span_context_hooks(enter, exit) -> None:
     global _ctx_enter, _ctx_exit
     _ctx_enter, _ctx_exit = enter, exit
 
+
+# The thread's CPU clock is a system call, and not a cheap one
+# everywhere: 0.3 us on the machine the tests run on, 6.0 us on the
+# builder's TPU host (whose clock also steps in ticks of 10 ms), where
+# two reads a span were 12 of a span's 17 us (PERF.md, PR 39). A reading
+# younger than this is carried forward as if the thread had run since:
+# what ``cpu_s`` is for, a thread held off its CPU for tens of
+# milliseconds or seconds, is far above it.
+CPU_READ_EVERY_S = 1e-3
 
 ANNOTATION_PREFIX = "edl."
 _profiler = None  # jax.profiler, once the process has imported jax
@@ -88,6 +108,18 @@ def _annotation(name: str, seq: int, step_num: Optional[int] = None):
     return _profiler.StepTraceAnnotation(
         ANNOTATION_PREFIX + name, step_num=step_num, seq=seq
     )
+
+
+def _thread_cpu(local, now: float) -> float:
+    """The calling thread's CPU seconds at ``now`` (perf_counter):
+    read, or carried forward from a reading under CPU_READ_EVERY_S
+    old."""
+    at, cpu = local.cpu
+    if 0.0 <= now - at < CPU_READ_EVERY_S:
+        return cpu + (now - at)
+    cpu = time.thread_time()
+    local.cpu = (now, cpu)
+    return cpu
 
 
 def clock_offset_ns(span: "Span", annotation_start_ns: int,
@@ -132,6 +164,9 @@ class Tracer:
         self.enabled = True
         self.dropped = 0  # spans evicted after the ring filled
         self._listeners: List[Callable[[Span], None]] = []
+        # per thread: ``seqs`` of the spans open on it, innermost last,
+        # and ``cpu``, its last reading of the CPU clock (when, what)
+        self._open = threading.local()
 
     def span(self, name: str, **attrs: Any):
         """Time the block. Yields the span's attribute dict, so what is
@@ -155,6 +190,14 @@ class Tracer:
             yield attrs
             return
         seq = next(self._ids)
+        local = self._open
+        try:
+            stack = local.seqs
+        except AttributeError:
+            stack = local.seqs = []
+            local.cpu = (-1.0, 0.0)
+        parent = stack[-1] if stack else 0
+        stack.append(seq)
         state = ctx_attrs = None
         if _ctx_enter is not None:
             # the span body runs inside its OWN child trace context:
@@ -162,23 +205,28 @@ class Tracer:
             # within carry these ids (how /trace and /events agree)
             state, ctx_attrs = _ctx_enter()
         note = _annotation(name, seq, step_num)
-        # the two clocks are read back to back: the pair is what joins
-        # them (clock_offset_ns)
+        # the clocks are read back to back: perf_counter and the
+        # annotation's are the pair that joins ring and trace
+        # (clock_offset_ns)
         start = time.perf_counter()
         if note is not None:
             note.__enter__()
+        cpu = _thread_cpu(local, start)
         try:
             yield attrs
         finally:
             if note is not None:
                 note.__exit__(None, None, None)
-            dur = time.perf_counter() - start
+            end = time.perf_counter()
+            dur = end - start
+            cpu = max(_thread_cpu(local, end) - cpu, 0.0)
+            stack.pop()
             if _ctx_exit is not None:
                 _ctx_exit(state)
             if ctx_attrs:
                 attrs.update(ctx_attrs)
             self._store(Span(name, start - self._t0, dur, attrs,
-                             threading.get_ident(), seq))
+                             threading.get_ident(), seq, cpu, parent))
 
     def record(self, name: str, start_s: float, dur_s: float,
                attrs: Optional[Dict[str, Any]] = None) -> None:
@@ -186,16 +234,19 @@ class Tracer:
         absolute time.perf_counter(); stored relative to tracer start so
         chrome-trace timestamps line up across threads. Its annotation
         is a mark at the moment of recording (the profiler takes no
-        past events): it places the span's ``seq`` in the trace."""
+        past events): it places the span's ``seq`` in the trace, and
+        ``parent`` is the span that mark lies in."""
         if not self.enabled:
             return
         seq = next(self._ids)
+        stack = getattr(self._open, "seqs", None)
         note = _annotation(name, seq)
         if note is not None:
             with note:
                 pass
         self._store(Span(name, start_s - self._t0, dur_s,
-                         dict(attrs or {}), threading.get_ident(), seq))
+                         dict(attrs or {}), threading.get_ident(), seq,
+                         None, stack[-1] if stack else 0))
 
     def _store(self, span: Span) -> None:
         with self._lock:
@@ -253,22 +304,6 @@ class Tracer:
         out["_tracer"] = {"spans": len(spans), "dropped": dropped}
         return out
 
-    def to_chrome_trace(self) -> List[Dict[str, Any]]:
-        """Catapult "X" (complete) events, microsecond units — loadable in
-        chrome://tracing and Perfetto."""
-        return [
-            {
-                "name": s.name,
-                "ph": "X",
-                "ts": s.start_s * 1e6,
-                "dur": s.dur_s * 1e6,
-                "pid": os.getpid(),
-                "tid": s.thread % 2**31,
-                "args": s.attrs,
-            }
-            for s in self.spans()
-        ]
-
     def _snapshot(self):
         """(spans, dropped) under one lock acquire: readers must see a
         consistent pair (the unguarded ``self.dropped`` reads were an
@@ -303,7 +338,9 @@ class Tracer:
         metadata event carries ``max_seq``, the next cursor, so a fleet
         cadence tick fetches the delta, not the whole ring. (An event's
         own ``seq`` is its id from when it opened: the key it shares
-        with its profiler annotation, not a cursor.)"""
+        with its profiler annotation, not a cursor; ``parent`` is the
+        ``seq`` of the span it opened under, ``cpu_s`` the CPU seconds
+        its thread used meanwhile.)"""
         spans, dropped, cursor = self._page(since_seq, last_n)
         events = [
             {
@@ -314,6 +351,8 @@ class Tracer:
                 "pid": os.getpid(),
                 "tid": s.thread % 2**31,
                 "seq": s.seq,
+                "parent": s.parent,
+                "cpu_s": s.cpu_s,
                 "args": s.attrs,
             }
             for s in spans

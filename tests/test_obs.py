@@ -413,6 +413,41 @@ def test_bridge_tracer_observes_spans_as_histograms():
         tr.remove_listener(listener)
 
 
+def test_bridge_sees_the_engine_steps_host_phases():
+    """``serving.account`` and ``serving.replay`` reach the bridge like
+    the engine's older spans, one histogram series a name; the older
+    names count what they counted (one dispatch and one drain a block)."""
+    import jax
+
+    from edl_tpu.models import llama
+    from edl_tpu.serving.engine import ContinuousBatchingEngine
+
+    reg = obs.MetricsRegistry()
+    listener = obs.bridge_tracer(reg, tracing.tracer())
+    try:
+        cfg = llama.LlamaConfig.tiny()
+        eng = ContinuousBatchingEngine(
+            llama.init_params(jax.random.PRNGKey(0), cfg), cfg,
+            max_slots=2, max_len=64)
+        eng.submit("r1", [2, 3, 4], 6)
+        eng.run()
+        h = reg.get("edl_span_seconds")
+        blocks = h.stats(name="serving.dispatch")["count"]
+        # the prefill gives the first of six tokens, and the double
+        # buffer has one block more in flight when the last is drained
+        assert blocks == 6
+        assert h.stats(name="serving.drain")["count"] == blocks
+        assert h.stats(name="serving.replay")["count"] == blocks
+        # the step's gauges once a step, a dispatch's preparation once
+        # a block
+        steps = h.stats(name="serving.step")["count"]
+        assert h.stats(name="serving.account")["count"] == steps + blocks
+        assert h.stats(name="serving.replay")["sum"] < \
+            h.stats(name="serving.step")["sum"]
+    finally:
+        tracing.tracer().remove_listener(listener)
+
+
 # ---------------------------------------------------------------------------
 # monitor-source round trips (StoreSource / ServingSource -> registry)
 

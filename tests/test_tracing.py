@@ -235,6 +235,142 @@ def test_seq_is_taken_at_open_and_paging_follows_the_ring():
         "parent"]
 
 
+def _spin(seconds):
+    import time
+
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_cpu_s_tells_a_wait_from_work():
+    import time
+
+    tr = tracing.Tracer()
+    with tr.span("sleeps"):
+        time.sleep(0.05)
+    sleeps = tr.spans("sleeps")[0]
+    assert sleeps.dur_s >= 0.05 and sleeps.cpu_s < 0.2 * sleeps.dur_s
+    # a spinning thread that the machine takes off its CPU reads low,
+    # which is the point of the field: under the test run's other
+    # workers, one of a few short spins has to have kept its CPU
+    for _ in range(20):
+        with tr.span("spins"):
+            _spin(0.01)
+        spins = tr.spans("spins")[-1]
+        if abs(spins.cpu_s - spins.dur_s) <= 0.2 * spins.dur_s:
+            break
+    else:
+        pytest.fail(f"no spin of 20 kept its CPU: {tr.spans('spins')}")
+    tr.record("after.the.fact", time.perf_counter() - 0.5, 0.5)
+    # record() times nothing itself
+    assert tr.spans("after.the.fact")[0].cpu_s is None
+
+
+def test_the_cpu_clock_is_read_once_a_millisecond_at_most(monkeypatch):
+    """The thread's CPU clock is a system call (6 us a read on the TPU
+    host): a reading under CPU_READ_EVERY_S old is carried forward as
+    if the thread had run since, so spans in quick succession cost no
+    call each and a span that outlasts it is read at its close."""
+    import time
+
+    reads = []
+    real = time.thread_time
+
+    def counted():
+        reads.append(time.perf_counter())
+        return real()
+
+    monkeypatch.setattr(tracing.time, "thread_time", counted)
+    tr = tracing.Tracer()
+    t0 = time.perf_counter()
+    for _ in range(100):
+        with tr.span("quick"):
+            pass
+    took = time.perf_counter() - t0
+    assert len(reads) <= 2 + took / tracing.CPU_READ_EVERY_S
+    quick = tr.spans("quick")
+    # carried forward, a span's CPU time is its wall time
+    assert sum(1 for s in quick if s.cpu_s == pytest.approx(
+        s.dur_s, abs=1e-6)) >= 90
+    before = len(reads)
+    with tr.span("long"):
+        time.sleep(3 * tracing.CPU_READ_EVERY_S)
+    # read at its close (its open was carried, or read if the last
+    # reading had just aged out)
+    assert before + 1 <= len(reads) <= before + 2
+    assert tr.spans("long")[0].cpu_s < tracing.CPU_READ_EVERY_S
+
+
+def test_parent_is_the_enclosing_span_on_the_same_thread():
+    import threading
+    import time
+
+    tr = tracing.Tracer()
+
+    def elsewhere():
+        with tr.span("other.thread"):
+            pass
+
+    with tr.span("outer"):
+        with tr.span("inner"):
+            with tr.step_span("innermost", 3):
+                pass
+        with tr.span("sibling"):
+            pass
+        # a span timed by the caller lies where it was recorded
+        tr.record("recorded", time.perf_counter() - 0.1, 0.1)
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join()
+    with tr.span("next"):
+        pass
+    by = {s.name: s for s in tr.spans()}
+    assert by["outer"].parent == 0 and by["next"].parent == 0
+    assert by["inner"].parent == by["outer"].seq
+    assert by["innermost"].parent == by["inner"].seq
+    assert by["sibling"].parent == by["outer"].seq
+    assert by["recorded"].parent == by["outer"].seq
+    # opened while "outer" was open, but on a thread of its own
+    assert by["other.thread"].parent == 0
+    # another tracer's spans are not this one's parents
+    other = tracing.Tracer()
+    with tr.span("mine"):
+        with other.span("theirs"):
+            pass
+    assert other.spans("theirs")[0].parent == 0
+
+
+def test_a_span_that_raises_still_closes_its_place_in_the_nesting():
+    tr = tracing.Tracer()
+    with pytest.raises(ValueError):
+        with tr.span("outer"):
+            with tr.span("fails"):
+                raise ValueError("x")
+    with tr.span("after"):
+        pass
+    by = {s.name: s for s in tr.spans()}
+    assert by["fails"].parent == by["outer"].seq
+    assert by["after"].parent == 0 and by["fails"].cpu_s is not None
+
+
+def test_cpu_s_and_parent_ride_the_chrome_doc():
+    tr = tracing.Tracer()
+    with tr.span("outer"):
+        with tr.span("inner"):
+            _spin(0.002)
+    tr.record("recorded", 0.0, 0.1)
+    events = {e["name"]: e for e in tr.to_chrome_doc()["traceEvents"]
+              if e["ph"] == "X"}
+    assert events["inner"]["parent"] == events["outer"]["seq"]
+    assert events["outer"]["parent"] == 0
+    assert 0 < events["inner"]["cpu_s"] <= events["outer"]["cpu_s"]
+    assert events["recorded"]["cpu_s"] is None
+    json.dumps(events)  # /trace serves it as JSON
+    # the list of bare events went with its last caller
+    assert not hasattr(tr, "to_chrome_trace")
+
+
 def test_tracing_imports_and_records_with_jax_blocked():
     import subprocess
     import sys
@@ -246,6 +382,9 @@ def test_tracing_imports_and_records_with_jax_blocked():
         "with tracing.step_span('b', 3):\n    pass\n"
         "tracing.tracer().record('c', 0.0, 0.1)\n"
         "assert [s.name for s in tracing.tracer().spans()] == list('abc')\n"
+        "a, b, c = tracing.tracer().spans()\n"
+        "assert a.cpu_s >= 0 and b.cpu_s >= 0 and c.cpu_s is None\n"
+        "assert (a.parent, b.parent, c.parent) == (0, 0, 0)\n"
         "assert not hasattr(tracing, 'jax_profile')\n"
     )
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -343,3 +482,98 @@ def test_first_step_of_a_mesh_the_job_has_had_builds_nothing(cpu_devices):
         assert rec.attrs["load_s"] == ev.load_s
         assert rec.attrs["step_reused"] is ev.step_reused
         assert rec.dur_s == ev.recompile_s
+
+
+# -- the engine step's host side --------------------------------------------
+
+
+def _engine(**kw):
+    from edl_tpu.models import llama
+    from edl_tpu.serving.engine import ContinuousBatchingEngine
+
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    return ContinuousBatchingEngine(params, cfg, max_slots=2, max_len=64,
+                                    **kw)
+
+
+def _children(step):
+    return [s for s in tracing.tracer().spans() if s.parent == step.seq]
+
+
+@pytest.mark.parametrize("admits", [True, False])
+def test_engine_step_is_tiled_by_named_children(admits):
+    """A step that admits and one that only decodes: ``serving.account``
+    (twice: the step's gauges, and what a dispatch does before its
+    program call) and ``serving.replay`` are children of
+    ``serving.step`` by ``parent``, beside the spans that were there."""
+    eng = _engine()
+    eng.submit("r1", [2, 3, 4, 5], 12)
+    eng.step()  # admits r1, dispatches block 1
+    eng.step()  # dispatches block 2, drains block 1
+    if admits:
+        eng.submit("r2", [6, 7, 8], 5)
+    tracing.tracer().clear()
+    eng.step()
+    step, = tracing.tracer().spans("serving.step")
+    kids = _children(step)
+    names = [s.name for s in sorted(kids, key=lambda s: s.seq)]
+    assert names == (["serving.admit"] if admits else []) + [
+        "serving.account", "serving.account", "serving.dispatch",
+        "serving.drain", "serving.replay"]
+    # the children tile the step: none overlaps the next
+    ordered = sorted(kids, key=lambda s: s.start_s)
+    for a, b in zip(ordered, ordered[1:]):
+        assert a.start_s + a.dur_s <= b.start_s + 1e-6
+    assert step.start_s <= ordered[0].start_s
+    assert sum(s.dur_s for s in kids) <= step.dur_s
+    replay = next(s for s in kids if s.name == "serving.replay")
+    drain = next(s for s in kids if s.name == "serving.drain")
+    assert replay.attrs["rids"] == drain.attrs["rids"] == ["r1"]
+    assert replay.attrs["tokens"] == 1
+    assert all(s.cpu_s is not None for s in kids)
+    if admits:
+        admit = next(s for s in kids if s.name == "serving.admit")
+        assert {s.name for s in _children(admit)} == {
+            "serving.queue", "serving.prefill"}
+    # what was there keeps its attributes
+    dispatch = next(s for s in kids if s.name == "serving.dispatch")
+    assert dispatch.attrs["horizon"] == 1 and "kv_read_share" in dispatch.attrs
+    assert dispatch.attrs["rids"] == (["r1", "r2"] if admits else ["r1"])
+    eng.run()
+    assert eng.results["r1"].outcome == "done"
+
+
+def test_drain_all_replays_every_block_in_flight():
+    eng = _engine()
+    eng.submit("r1", [2, 3, 4, 5], 12)
+    eng.step()
+    eng._dispatch_block()
+    assert len(eng._inflight) == 2
+    tracing.tracer().clear()
+    assert eng._drain_all() == 2
+    names = [s.name for s in sorted(tracing.tracer().spans(),
+                                    key=lambda s: s.seq)]
+    assert names == ["serving.drain", "serving.replay"] * 2
+    assert [s.attrs["tokens"]
+            for s in tracing.tracer().spans("serving.replay")] == [1, 1]
+
+
+def test_paged_and_speculative_dispatches_account_too():
+    eng = _engine(block_size=16, spec_k=3)
+    eng.submit("r1", [5, 6, 5, 6, 5, 6, 5, 6], 8)
+    eng.run()
+    assert eng.results["r1"].outcome == "done"
+    spans = tracing.tracer().spans()
+    by_seq = {s.seq: s for s in spans}
+    for name in ("serving.account", "serving.replay", "serving.dispatch"):
+        mine = [s for s in spans if s.name == name]
+        assert mine
+        assert all(by_seq[s.parent].name == "serving.step" for s in mine)
+    # a verify dispatch prepares its draft matrix and table under the
+    # same name
+    verify = [s for s in spans if s.name == "serving.dispatch"
+              and "spec_k" in s.attrs]
+    assert verify
+    before = by_seq[verify[0].seq - 1]
+    assert before.name == "serving.account"
