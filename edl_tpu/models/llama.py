@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from edl_tpu.obs import compilewatch
 from edl_tpu.obs import costmodel as _costmodel
+from edl_tpu.parallel import remat
 from edl_tpu.parallel.mesh import MeshPlan
 
 compilewatch.install()  # compile telemetry for every program built here
@@ -51,19 +52,30 @@ class LlamaConfig:
     # FLOPs for O(L·B·T·d) instead of O(L·B·T·(d+ff+heads)) activation
     # HBM — what lets non-toy configs train on one chip
     remat: bool = False
-    # what the remat saves besides layer inputs — the FLOPs/HBM dial:
-    #   "full": recompute everything (min memory, +2 fwd-matmul units
-    #           of the 6-unit fwd+bwd budget)
-    #   "attn": also save the flash-attention output + logsumexp —
-    #           the backward reuses them instead of re-running the
-    #           (VPU-bound) softmax kernel; q/k/v reprojections stay
-    #           cheap matmul recomputes. ~2·d bf16 bytes/token/layer.
-    #   "mlp":  also save the SwiGLU gate/up products [B,T,d_ff] —
-    #           skips recomputing w1/w3, half the layer's recompute,
-    #           for 2·d_ff bf16 bytes/token/layer of HBM
-    #   "dots": save every weight-matmul output (near-zero recompute,
-    #           most HBM — jax dots_with_no_batch_dims_saveable)
-    remat_policy: str = "full"
+    # what the remat keeps beside the layer inputs: the FLOPs/HBM dial.
+    # Kept values are names in the traced layer (``KEEP_ORDER``, which
+    # gives each one's bytes a token a layer and the matmul it spares):
+    #   "fit":  what of ``KEEP_ORDER`` fits the room the trainer
+    #           offers for this step on this mesh, taken in its order
+    #           (``parallel/remat.py``; ``make_train_step`` sizes the
+    #           offer from the device's limit and holds the compiler's
+    #           ``memory_analysis()`` against it). "full" where nothing
+    #           fits, nothing is offered (a caller that is no trainer)
+    #           or nothing is named.
+    #   "full": recompute everything (least memory; the backward runs
+    #           the flash forward kernel and five of the seven
+    #           projections a second time, a third of the forward's
+    #           matmul FLOPs not counting wo and w2, whose second run is
+    #           dead and dropped)
+    #   "attn": keep the flash kernel's output and logsumexp; the
+    #           backward reuses them instead of running the (VPU-bound)
+    #           forward kernel again. Raises without the kernel.
+    #   "mlp":  keep the two [B,T,d_ff] products m @ w1 and m @ w3
+    #           (w1's BEFORE the silu: see ``_mlp``): skips both
+    #           recomputed MLP matmuls
+    #   "dots": keep every weight-matmul output (near-zero recompute,
+    #           most HBM: jax dots_with_no_batch_dims_saveable)
+    remat_policy: str = "fit"
     # sequence/context parallelism implementation when the mesh plan has
     # an sp axis: "ring" (ppermute neighbor exchange, scales past the
     # head count) or "ulysses" (two all-to-alls, full-sequence attention
@@ -490,7 +502,11 @@ def _qkv(
         # in the order the training step and prefill have always traced
         # them, so their lowered text and compile-cache entries stand
         y = _matw(a, lp[name], i8, wb)
-        return y if split_after else y.reshape(b, t, n, hd)
+        if split_after:
+            return y
+        # named for the training layer's remat (``KEEP_ORDER``): RoPE
+        # and the split are elementwise and redone from these
+        return checkpoint_name(y, "attn_" + name[1]).reshape(b, t, n, hd)
 
     q, k, v = product("wq", h), product("wk", kv), product("wv", kv)
     if split_after:
@@ -537,7 +553,11 @@ def _mlp(cfg: LlamaConfig, x: jnp.ndarray, lp: Dict,
     multiplier (``models/ssm_hybrid.py``)."""
     i8, wb = cfg.int8_mxu, cfg.int8_wgrad_bf16
     m = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    gate = checkpoint_name(jax.nn.silu(_matw(m, lp["w1"], i8, wb)), "mlp_gate")
+    # the two matmuls' products are what a remat policy can keep: the
+    # silu and the product are elementwise and cheap to redo, and
+    # silu's backward wants the pre-activation (keeping silu's output
+    # instead spared no matmul: PERF.md section 6, PR 40)
+    gate = jax.nn.silu(checkpoint_name(_matw(m, lp["w1"], i8, wb), "mlp_gate"))
     up = checkpoint_name(_matw(m, lp["w3"], i8, wb), "mlp_up")
     out = _matw(gate * up, lp["w2"], i8, wb)
     return x + out if residual is None else x + residual * out
@@ -565,28 +585,125 @@ def _layer(
     return (out, k, v) if with_kv else out
 
 
-def _remat_policy(cfg: LlamaConfig):
-    """The remat FLOPs/HBM dial (see LlamaConfig.remat_policy)."""
-    if cfg.remat_policy == "mlp":
-        return jax.checkpoint_policies.save_only_these_names(
-            "mlp_gate", "mlp_up"
+# What a rematerialised layer can keep beside its input, best first by
+# seconds of recomputation spared a byte kept (bytes a token a layer in
+# bfloat16 at Mistral-7B's widths; PERF.md section 6, PR 40):
+#   flash_out + flash_lse  8.1 KiB  spares the second edl_flash_fwd
+#   mlp_up                  28 KiB  spares m @ w3
+#   mlp_gate                28 KiB  spares m @ w1 (the PRE-activation:
+#                                   silu's backward wants it, and with
+#                                   silu's output kept instead the
+#                                   matmul was recomputed all the same)
+#   attn_q, attn_k, attn_v  12 KiB  spare a @ wq, a @ wk, a @ wv
+# The last three spare as many FLOPs a byte as each other (0.068 TFLOP
+# a KiB a token), so where ``mlp_up`` does not fit the smaller q/k/v
+# after it still may: ``remat.choose`` passes over what is too large.
+# ``wo``'s and ``w2``'s products are dead in the backward and XLA drops
+# their second run unasked.
+KEEP_ORDER = (
+    ("flash_out", "flash_lse"),
+    ("mlp_up",),
+    ("mlp_gate",),
+    ("attn_q", "attn_k", "attn_v"),
+)
+_POLICY_NAMES = {
+    "attn": KEEP_ORDER[0],
+    "mlp": KEEP_ORDER[2] + KEEP_ORDER[1],
+}
+
+
+def keep_candidates(cfg: LlamaConfig, tokens: int, flash: bool, tp: int = 1):
+    """``KEEP_ORDER`` with each entry's bytes on a device over all
+    layers, for ``tokens`` tokens a device: what :func:`remat.choose
+    <edl_tpu.parallel.remat.choose>` is asked. ``flash`` says whether
+    the traced program runs the flash kernel; the names of one that
+    does not are no candidates."""
+    s = jnp.dtype(cfg.dtype).itemsize
+    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    a_token = {
+        "flash_out": h * hd * s + h * 4,  # the lse: one float32 a head
+        "mlp_up": cfg.d_ff * s,
+        "mlp_gate": cfg.d_ff * s,
+        "attn_q": (h + 2 * kv) * hd * s,
+    }
+    return [
+        (names, cfg.n_layers * tokens * a_token[names[0]] // tp)
+        for names in KEEP_ORDER
+        if flash or names[0] != "flash_out"
+    ]
+
+
+def step_working_bytes(cfg: LlamaConfig, params: Dict, tokens: int,
+                       shards: int = 1) -> int:
+    """What a training step of this model fills on a device beside the
+    trainer's state with nothing kept, for ``tokens`` tokens a device
+    and parameters split ``shards`` ways: an estimate from shapes, for
+    the choice that has to be made before the one compile. Through the
+    whole step: the activation-dtype casts of the stacked weights (XLA
+    hoists them out of the scan) and each layer's input. Then the
+    larger of two phases. The head's: the logits in float32 and in the
+    activation dtype, beside the gradients of the head and the
+    embedding. The backward scan's: the layers' stacked gradients,
+    whole until the scan ends, beside about seven ``[tokens, d_ff]``
+    arrays of one layer's recomputation. Against
+    ``memory_analysis().peak_memory_in_bytes`` of described-v5e
+    compiles (Mistral widths L4 at 2, 4 and 5 rows of 4096, L9 fsdp 2
+    on 4 and 2 chips, the flagship at 8 rows of 2048) it reads 0.0 to
+    0.2 GiB low; the trainer's margin covers that."""
+    s = jnp.dtype(cfg.dtype).itemsize
+
+    def nbytes(tree):
+        return sum(x.size * jnp.dtype(x.dtype).itemsize
+                   for x in jax.tree_util.tree_leaves(tree)) // shards
+
+    layers = nbytes(params["layers"])
+    rest = nbytes({k: v for k, v in params.items() if k != "layers"})
+    casts = sum(x.size for x in jax.tree_util.tree_leaves(
+        params["layers"])) * s // shards
+    inputs = cfg.n_layers * tokens * cfg.d_model * s
+    head = rest + tokens * cfg.vocab * (4 + s)
+    scan = layers + 7 * tokens * cfg.d_ff * s
+    return casts + inputs + max(head, scan)
+
+
+def _remat_policy(cfg: LlamaConfig, kept: Tuple[str, ...] = ()):
+    """The remat FLOPs/HBM dial (see LlamaConfig.remat_policy); ``kept``
+    is what ``"fit"`` resolved to."""
+    if cfg.remat_policy == "attn" and not cfg.use_flash:
+        raise ValueError(
+            'remat_policy="attn" saves the flash kernel\'s named '
+            "residuals; without use_flash there is nothing to "
+            "save and the policy would silently degrade to full "
+            "rematerialization"
         )
-    if cfg.remat_policy == "attn":
-        if not cfg.use_flash:
-            raise ValueError(
-                'remat_policy="attn" saves the flash kernel\'s named '
-                "residuals; without use_flash there is nothing to "
-                "save and the policy would silently degrade to full "
-                "rematerialization"
-            )
-        return jax.checkpoint_policies.save_only_these_names(
-            "flash_out", "flash_lse"
-        )
-    if cfg.remat_policy == "dots":
+    if cfg.remat_policy in _POLICY_NAMES:
+        kept = _POLICY_NAMES[cfg.remat_policy]
+    elif cfg.remat_policy == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    if cfg.remat_policy == "full":
+    elif cfg.remat_policy not in ("fit", "full"):
+        raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
+    if not kept:
         return None
-    raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}")
+    return jax.checkpoint_policies.save_only_these_names(*kept)
+
+
+def _fit_kept(cfg: LlamaConfig, params: Dict, x: jnp.ndarray,
+              plan: Optional[MeshPlan], sp: int, pp: int):
+    """``remat_policy="fit"``: what of ``KEEP_ORDER`` fits the room the
+    trainer offers (``parallel/remat.py``), sized for one device of the
+    plan. Nothing under a pipeline, whose schedule
+    holds several microbatches' residuals at once, and nothing with no
+    offer open: both are ``"full"``."""
+    if pp > 1:
+        return ()
+    tp = plan.axis_size("tp") if plan is not None else 1
+    shards = tp * (plan.axis_size("fsdp") if plan is not None else 1)
+    rows = x.shape[0] // (plan.batch_shards() if plan is not None else 1)
+    tokens = max(rows, 1) * (x.shape[1] // sp)
+    return remat.choose(
+        keep_candidates(cfg, tokens, cfg.use_flash and sp == 1, tp),
+        step_working_bytes(cfg, params, tokens, shards),
+    )
 
 
 def forward(
@@ -645,7 +762,9 @@ def forward(
         return _layer(cfg, carry, lp, mesh=layer_mesh, sp=sp), None
 
     if cfg.remat:
-        body = jax.checkpoint(body, policy=_remat_policy(cfg))
+        kept = (_fit_kept(cfg, params, x, plan, sp, pp)
+                if cfg.remat_policy == "fit" else ())
+        body = jax.checkpoint(body, policy=_remat_policy(cfg, kept))
 
     if pp > 1:
         from edl_tpu.parallel.pipeline import pipeline_apply

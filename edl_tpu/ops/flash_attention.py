@@ -383,13 +383,20 @@ def _flash_fwd(qb, kb, vb, groups, block_q, block_k, causal, interpret):
         qb, kb, vb, groups, block_q, block_k, causal, interpret,
         with_lse=True,
     )
-    # named so a rematerialization policy can SAVE these two residuals
-    # (models/llama.py remat_policy="attn"): the backward then reuses
-    # them instead of re-running this kernel — q/k/v are cheap matmul
-    # recomputes, the softmax kernel is not (VPU-bound). The lse is
-    # saved COMPACT ([bh, t] — one lane of the kernel's lane-replicated
-    # layout) so the policy stores 4 bytes/row, not 512; the backward
-    # rebroadcasts at XLA level.
+    # named so a rematerialization policy can KEEP these two residuals
+    # (models/llama.py: the first entry of KEEP_ORDER, which
+    # remat_policy="fit" keeps wherever the step has room, and "attn"
+    # by name): the backward then reuses them instead of running this
+    # kernel a second time; q/k/v are redone from the layer's input by
+    # three matmuls. Measured at Mistral-7B's widths, 4 x 4096 tokens,
+    # 4 layers (PERF.md section 6, PR 40): the pair is 8.1 KiB a token
+    # a layer (0.51 GiB), the compiler's peak grows by 0.63 GiB, and
+    # the optimized step calls edl_flash_fwd once where it called it
+    # twice. The lse is kept COMPACT ([bh, t]: one lane of the kernel's
+    # lane-replicated layout) so it is 4 bytes a row, not 512; the
+    # backward rebroadcasts at XLA level. Nothing else is kept beside
+    # them: the backward's float32 copy of ``out`` (for ``delta``) is
+    # made and dropped inside each layer's backward.
     out = checkpoint_name(out, "flash_out")
     lse_c = checkpoint_name(lse[..., 0], "flash_lse")
     return out, (qb, kb, vb, out, lse_c)

@@ -224,6 +224,7 @@ class ElasticTrainer:
         # under stable keys so reshards replace rather than accumulate
         self._ledger = memledger.default_ledger()
         self._ledger_owner = f"trainer-{id(self)}"
+        self._ledger_kept: Optional[Dict] = None  # the step's, once filed
         import weakref
 
         weakref.finalize(self, self._ledger.release_owner, self._ledger_owner)
@@ -347,6 +348,22 @@ class ElasticTrainer:
         )
         self._ledger.register_tree(
             self._ledger_owner, "opt", self.state.opt_state, "opt"
+        )
+
+    def _ledger_register_kept(self) -> None:
+        """``edl_hbm_bytes{category="remat_kept"}``: what the installed
+        step's rematerialised layers keep from forward to backward, over
+        all devices like ``params`` and ``opt`` beside it. Known once
+        the step's first call has built it (``make_train_step``), so
+        this is asked after every dispatch and does something on the
+        first of a mesh."""
+        kept = getattr(self._step_fn, "kept", None)
+        if not kept or kept is self._ledger_kept:
+            return
+        self._ledger_kept = kept
+        self._ledger.register(
+            self._ledger_owner, "remat_kept",
+            kept["remat_kept_bytes"] * self.n_devices, "remat_kept",
         )
 
     @property
@@ -600,6 +617,7 @@ class ElasticTrainer:
                     self.state, metrics = self._step_fn(self.state, dev_batch)
             if first_on_mesh:
                 jax.block_until_ready(metrics["loss"])
+        self._ledger_register_kept()
         if first_on_mesh:
             ev.recompile_s = time.perf_counter() - tc
             ev.trace_s, ev.lower_s = built.trace_s, built.lower_s
@@ -608,7 +626,11 @@ class ElasticTrainer:
                 "reshard.recompile", tc, ev.recompile_s,
                 {"to_workers": self.n_workers, "trace_s": ev.trace_s,
                  "lower_s": ev.lower_s, "load_s": ev.load_s,
-                 "cache_hit": ev.cache_hit, "step_reused": ev.step_reused},
+                 "cache_hit": ev.cache_hit, "step_reused": ev.step_reused,
+                 # what this mesh's step keeps for its backward and the
+                 # room beside it: chosen when the step was first built
+                 # and filed with it, so a return reads the same
+                 **getattr(self._step_fn, "kept", {})},
             )
             obs_metrics.default_registry().histogram(
                 "edl_reshard_recompile_seconds",

@@ -9,20 +9,25 @@ params/optimizer state sharded per the mesh plan, gradients all-reduced
 
 from __future__ import annotations
 
+import itertools
+import math
 import time
 from functools import partial
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from flax import struct
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from edl_tpu.obs import compilewatch
 from edl_tpu.obs import metrics as obs_metrics
+from edl_tpu.parallel import remat
 from edl_tpu.parallel.mesh import MeshPlan
 from edl_tpu.parallel import sharding as shd
+from edl_tpu.utils import tracing
 
 compilewatch.install()  # compile telemetry for every program built here
 
@@ -143,38 +148,44 @@ def make_train_step(
     reference's pserver push/pull protocol).
     """
 
-    def edl_train_step(
-        state: TrainState, batch
-    ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
-        new_state, loss = _apply_update(loss_fn, tx, state, batch)
-        return new_state, {"loss": loss}
-
     # Sharding trees need a concrete state (opt_state structure is only
     # known then); build the jit lazily at first call. jax.jit itself
     # caches per input shape after that.
     cell: list = []
+    kept: Dict[str, Any] = {}
+
+    def build(state: TrainState, batch):
+        # a function of its own for every jit: jit's cache of traces is
+        # keyed by the function and does not see what ``_fit_to_device``
+        # offers around a trace
+        def edl_train_step(
+            state: TrainState, batch
+        ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
+            new_state, loss = _apply_update(loss_fn, tx, state, batch)
+            return new_state, {"loss": loss}
+
+        state_sh = _state_sharding(state, plan, mesh, param_pspecs)
+        batch_sh = jax.tree_util.tree_map(
+            lambda _: plan.batch_sharding(mesh), batch
+        )
+        metric_sh = NamedSharding(mesh, P())
+        # the function's name is the program's: its build
+        # lands in edl_compile_seconds{program="edl_train_step"}
+        # and, post-warmup, on the flight-recorder timeline — a
+        # steady-state loop that recompiles (the reshard
+        # recompile aside, which re-enters here by design) is
+        # paying seconds someone should see
+        # edl: no-lint[recompile-hazard] built once a mesh by step()'s first call (and again only where the compiler refused the one before); step() keeps it
+        return jax.jit(
+            edl_train_step,
+            in_shardings=(state_sh, batch_sh),
+            out_shardings=(state_sh, {"loss": metric_sh}),
+            donate_argnums=(0,) if donate else (),
+        )
 
     def step(state: TrainState, batch):
         if not cell:
-            state_sh = _state_sharding(state, plan, mesh, param_pspecs)
-            batch_sh = jax.tree_util.tree_map(
-                lambda _: plan.batch_sharding(mesh), batch
-            )
-            metric_sh = NamedSharding(mesh, P())
-            cell.append(
-                # the function's name is the program's: its build
-                # lands in edl_compile_seconds{program="edl_train_step"}
-                # and, post-warmup, on the flight-recorder timeline — a
-                # steady-state loop that recompiles (the reshard
-                # recompile aside, which re-enters here by design) is
-                # paying seconds someone should see
-                jax.jit(
-                    edl_train_step,
-                    in_shardings=(state_sh, batch_sh),
-                    out_shardings=(state_sh, {"loss": metric_sh}),
-                    donate_argnums=(0,) if donate else (),
-                )
-            )
+            cell.append(_fit_to_device(build, state, batch, mesh, kept))
         t = time.perf_counter()
         out = cell[0](state, batch)
         _record_dispatch(time.perf_counter() - t)
@@ -184,7 +195,110 @@ def make_train_step(
     # caller can lower it again and read what the compiler produced
     # (chip_smoke.py checks the kernel is really in there)
     step.program = cell
+    # a jit of the step for a state and a batch (their shapes suffice):
+    # what a described-device compile lowers without running anything
+    step.build = build
+    # what its rematerialised layers keep and the room left beside it,
+    # once the first call has compiled it (``_fit_to_device``)
+    step.kept = kept
     return step
+
+
+# Of a device's memory, what a step's compiled peak has to leave free:
+# the runtime's own reservations and what a caller holds beside the
+# state. The estimate that picks the rung leaves twice that: it reads
+# up to 0.3 GiB low, stepping down costs a compile, and near the limit
+# the compiler trades speed for bytes (``_compiler_traded``).
+HEADROOM_SHARE = 1 / 32
+
+
+def device_bytes_limit(mesh: Mesh) -> Optional[int]:
+    """The least ``bytes_limit`` over the mesh's devices, or None where
+    the backend reports none (the CPU)."""
+    limits = [
+        (d.memory_stats() or {}).get("bytes_limit")
+        for d in mesh.devices.flat
+        if d.process_index == jax.process_index()
+    ]
+    return min(limits) if limits and all(limits) else None
+
+
+def _device_nbytes(tree) -> int:
+    """Bytes of a tree of arrays on ONE device under their shardings
+    (a host array counts whole)."""
+    total = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        sharding = getattr(x, "sharding", None)
+        shape = np.shape(x) if sharding is None else sharding.shard_shape(
+            x.shape)
+        total += math.prod(shape) * np.dtype(x.dtype).itemsize
+    return total
+
+
+def _fit_to_device(build, state, batch, mesh: Mesh, kept: Dict[str, Any]):
+    """The jitted step of ``build``, compiled here so that what its
+    model's rematerialised layers keep is fitted to the device: the
+    room beside the state and the batch is offered to the model around
+    the trace (``parallel/remat.py``), the step is compiled ONCE, and
+    the compiler's ``memory_analysis()`` is held against the device's
+    limit. Only where the compiler refuses the step
+    (``RESOURCE_EXHAUSTED``), leaves less than the headroom or has
+    started to rematerialise on its own (``_compiler_traded``) is it
+    traced again with one rung less, in a ``jax.jit`` of its own. The
+    call that follows finds the executable in jit's own caches. Fills
+    ``kept`` and records the ``train.build_step`` span. Where the
+    device reports no limit nothing is offered or compiled here."""
+    limit = device_bytes_limit(mesh)
+    if limit is None:
+        return build(state, batch)
+    t0 = time.perf_counter()
+    held = _device_nbytes(state) + _device_nbytes(batch)
+    headroom = int(limit * HEADROOM_SHARE)
+    for back_off in itertools.count():
+        jitted = build(state, batch)
+        with remat.offer(limit - held - 2 * headroom, back_off) as offer:
+            try:
+                compiled = jitted.lower(state, batch).compile()
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e) or not offer.rungs:
+                    raise
+                continue
+        peak = _peak_bytes(compiled)
+        if not offer.rungs or (
+                limit - peak >= headroom and not _compiler_traded(compiled)):
+            break
+    kept.update(
+        remat_kept=",".join(offer.kept),
+        remat_kept_bytes=offer.kept_bytes,
+        hbm_headroom_bytes=limit - peak,
+        compiles=back_off + 1,
+    )
+    tracing.tracer().record(
+        "train.build_step", t0, time.perf_counter() - t0, dict(kept))
+    return jitted
+
+
+def _compiler_traded(compiled) -> bool:
+    """Whether the compiler, short of memory, rematerialised on its own:
+    its pass names what it computes a second time ``<op>.remat``. It
+    starts doing so well inside the limit (a v5e: with about a GiB
+    still free), and what it recomputes cost more than the kept
+    residuals spared (PERF.md section 6, PR 40: 963 ms a step against
+    913 one rung lower), so a step that shows the sign is a rung too
+    rich whatever its headroom reads."""
+    return ".remat" in compiled.as_text()
+
+
+def _peak_bytes(compiled) -> int:
+    """A device's bytes at the compiled step's peak, arguments and
+    donated outputs included, as the compiler holds them to the limit."""
+    m = compiled.memory_analysis()
+    peak = getattr(m, "peak_memory_in_bytes", 0)
+    # a backend that reports no peak: the sum is its upper bound
+    return peak or (
+        m.argument_size_in_bytes + m.temp_size_in_bytes
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    )
 
 
 def make_train_multistep(

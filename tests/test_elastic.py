@@ -454,3 +454,130 @@ def test_reshard_counters_say_how_often_the_step_was_reused(cpu_devices):
         assert reg.get("edl_reshard_stall_seconds").stats()["count"] == 3
     finally:
         obs_metrics.reset_default_registry()
+
+
+# ---------------------------------------------------------------------------
+# what a step's rematerialised layers keep is fitted to the device when the
+# step is first built, and filed with it (PR 40)
+
+
+def _tiny_llama_trainer(devices, limit, monkeypatch, peak=None):
+    """An ``ElasticTrainer`` over a toy dense decoder with ``remat`` on,
+    fsdp 2, on devices that claim ``limit`` bytes each."""
+    import dataclasses
+
+    from edl_tpu.models import llama
+    from edl_tpu.train import trainer as tr_mod
+
+    monkeypatch.setattr(tr_mod, "device_bytes_limit", lambda mesh: limit)
+    if peak is not None:
+        monkeypatch.setattr(tr_mod, "_peak_bytes", peak)
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab=128), remat=True)
+    rng = np.random.RandomState(0)
+    trainer = ElasticTrainer(
+        None, optax.adafactor(1e-3), mesh_spec=MeshSpec(fsdp=2),
+        per_chip_batch=2, devices=devices,
+        param_pspecs=lambda plan: llama.param_pspecs(cfg, plan),
+        make_loss=lambda plan, mesh: llama.make_loss_fn(cfg, plan, mesh),
+    )
+    trainer.start(llama.init_params(jax.random.PRNGKey(0), cfg), len(devices))
+    return trainer, (lambda rows: llama.synthetic_tokens(
+        rng, rows, 16, cfg.vocab))
+
+
+def test_a_meshs_step_is_fitted_once_and_filed_with_what_it_keeps(
+        cpu_devices, monkeypatch):
+    """4 -> 2 -> 4 on devices with room for everything: each mesh's step
+    is compiled ONCE, on its first visit, with the whole of
+    ``llama.KEEP_ORDER`` the dense program names; the return to four
+    compiles nothing and reads the same record; the build's span and
+    ``reshard.recompile`` carry what was kept, and the memory ledger
+    files it under ``remat_kept`` beside ``params`` and ``opt``."""
+    from edl_tpu.obs import compilewatch, memledger
+    from edl_tpu.obs import metrics as obs_metrics
+    from edl_tpu.utils import tracing
+
+    reg = obs_metrics.reset_default_registry()
+    memledger.reset_default_ledger(reg)
+    tracing.tracer().clear()
+    try:
+        tr, data = _tiny_llama_trainer(cpu_devices[:4], 1 << 40, monkeypatch)
+        compiles = reg.counter("edl_compiles_total", "", ("program",))
+        steps_built = lambda: compiles.value(program="edl_train_step")  # noqa: E731
+        before = steps_built()
+        tr.train_steps(data, 2)
+        assert steps_built() - before == 1
+        on_four = tr._step_fn.kept
+        assert on_four["remat_kept"] == "mlp_up,mlp_gate,attn_q,attn_k,attn_v"
+        assert on_four["compiles"] == 1 and on_four["hbm_headroom_bytes"] > 0
+        gauge = reg.get("edl_hbm_bytes")
+        assert gauge.value(category="remat_kept") == (
+            4 * on_four["remat_kept_bytes"])
+        tr.request_rescale(2)
+        tr.train_steps(data, 2)
+        assert steps_built() - before == 2
+        on_two = tr._step_fn.kept
+        assert on_two is not on_four and on_two["compiles"] == 1
+        assert gauge.value(category="remat_kept") == (
+            2 * on_two["remat_kept_bytes"])
+        tr.request_rescale(4)
+        with compilewatch.Window() as built:
+            tr.train_steps(data, 2)
+        assert built.programs == 0 and steps_built() - before == 2
+        assert tr._step_fn.kept is on_four
+        assert gauge.value(category="remat_kept") == (
+            4 * on_four["remat_kept_bytes"])
+        spans = tracing.tracer().spans
+        assert [s.attrs for s in spans("train.build_step")] == [
+            on_four, on_two]
+        recompiles = spans("reshard.recompile")
+        assert [s.attrs["step_reused"] for s in recompiles] == [False, True]
+        for span, kept in zip(recompiles, (on_two, on_four)):
+            assert {k: span.attrs[k] for k in kept} == kept
+        assert np.isfinite(tr.report.losses).all()
+    finally:
+        obs_metrics.reset_default_registry()
+        memledger.reset_default_ledger()
+
+
+def test_a_step_the_compiler_leaves_no_headroom_steps_down_a_rung(
+        cpu_devices, monkeypatch):
+    """Where the compiled peak leaves less than the headroom the step is
+    traced again one rung lower, and only then: two compiles, the last
+    entry of the first choice given up."""
+    from edl_tpu.train import trainer as tr_mod
+
+    limit = 1 << 40
+    real, calls = tr_mod._peak_bytes, []
+
+    def peak(compiled):
+        calls.append(real(compiled))
+        return limit if len(calls) == 1 else calls[-1]
+
+    tr, data = _tiny_llama_trainer(cpu_devices[:4], limit, monkeypatch, peak)
+    tr.train_steps(data, 1)
+    kept = tr._step_fn.kept
+    assert kept["compiles"] == 2 == len(calls)
+    assert kept["remat_kept"] == "mlp_up,mlp_gate"
+    assert np.isfinite(tr.report.losses).all()
+
+
+def test_no_limit_no_offer_the_step_is_the_plain_jit(cpu_devices):
+    """A backend that reports no memory limit (the CPU): nothing is
+    offered, compiled ahead or recorded, and the step trains."""
+    import dataclasses
+
+    from edl_tpu.models import llama
+    from edl_tpu.train import trainer as tr_mod
+
+    assert tr_mod.device_bytes_limit(
+        jax.sharding.Mesh(np.array(cpu_devices[:1]), ("dp",))) is None
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(vocab=128), remat=True)
+    tr = ElasticTrainer(
+        llama.make_loss_fn(cfg), optax.adafactor(1e-3), per_chip_batch=2,
+        devices=cpu_devices[:2])
+    tr.start(llama.init_params(jax.random.PRNGKey(0), cfg), 2)
+    rng = np.random.RandomState(0)
+    rep = tr.train_steps(
+        lambda rows: llama.synthetic_tokens(rng, rows, 16, cfg.vocab), 2)
+    assert tr._step_fn.kept == {} and np.isfinite(rep.losses).all()

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from edl_tpu.api.job import MeshSpec
@@ -234,24 +235,142 @@ def _remat_loss_and_grads(cfg, t=16):
     return float(loss), grads
 
 
-def test_remat_policies_grad_and_match():
+def _assert_grads_close(g, g0, rtol=1e-4, atol=1e-6):
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=rtol, atol=atol
+        ),
+        g,
+        g0,
+    )
+
+
+@pytest.mark.parametrize("policy", ["fit", "full", "mlp", "dots"])
+def test_remat_policies_grad_and_match(policy):
     """Every remat policy produces the same loss and finite grads as
-    the no-remat baseline (ADVICE r2: the policy dial had no coverage)."""
+    the no-remat baseline (ADVICE r2: the policy dial had no coverage);
+    "fit" with no trainer's offer open is "full"."""
     import dataclasses
 
     base = llama.LlamaConfig.tiny()
     l0, g0 = _remat_loss_and_grads(base)
-    for policy in ("full", "mlp", "dots"):
-        cfg = dataclasses.replace(base, remat=True, remat_policy=policy)
-        l, g = _remat_loss_and_grads(cfg)
-        np.testing.assert_allclose(l, l0, rtol=1e-6, err_msg=policy)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
-            ),
-            g,
-            g0,
-        )
+    cfg = dataclasses.replace(base, remat=True, remat_policy=policy)
+    l, g = _remat_loss_and_grads(cfg)
+    np.testing.assert_allclose(l, l0, rtol=1e-6, err_msg=policy)
+    _assert_grads_close(g, g0)
+
+
+def _dots(fn, *args):
+    """Matmuls in the optimized program (a scan's body counts once)."""
+    import re
+
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return len(re.findall(r" dot\(", text))
+
+
+# rungs of llama.KEEP_ORDER the room is sized for -> (names kept with
+# the flash kernel in the program, names kept without it, matmuls of
+# the optimized loss-and-gradient program without it that are spared)
+_FIT_ROOMS = {
+    "none": ((), (), 0),
+    "flash_pair": (("flash_out", "flash_lse"), (), 0),
+    "mlp_up": (("flash_out", "flash_lse", "mlp_up"), ("mlp_up",), 1),
+    "unbounded": (
+        ("flash_out", "flash_lse", "mlp_up", "mlp_gate",
+         "attn_q", "attn_k", "attn_v"),
+        ("mlp_up", "mlp_gate", "attn_q", "attn_k", "attn_v"), 5),
+}
+
+
+@pytest.mark.parametrize("flash", [True, False], ids=["flash", "dense"])
+@pytest.mark.parametrize("room", list(_FIT_ROOMS))
+def test_fit_keeps_the_richest_prefix_that_fits_the_offer(room, flash):
+    """remat_policy="fit" under a trainer's offer: what is kept is what
+    of KEEP_ORDER the room allows, here a prefix (sized from the model's
+    own bytes, so the test follows a change of widths), a program
+    without the flash kernel never keeps its names, the kept matmuls
+    are really spared, and loss and gradients are "full"'s."""
+    import dataclasses
+
+    from edl_tpu.ops.flash_attention import interpret_kernels
+    from edl_tpu.parallel import remat
+
+    rows, t = 2, 128
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), remat=True, use_flash=flash)
+    assert cfg.remat_policy == "fit"
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    toks = llama.synthetic_tokens(np.random.RandomState(0), rows, t, cfg.vocab)
+    loss_and_grads = lambda c: (  # noqa: E731
+        lambda p, b: jax.value_and_grad(llama.make_loss_fn(c))(p, b))
+    # the room: the model's estimate of its step plus the bytes of the
+    # candidates up to the named rung of the FLASH program's list, so
+    # "flash_pair" is room the dense program cannot use for anything
+    with_flash = llama.keep_candidates(cfg, rows * t, True)
+    rungs = list(_FIT_ROOMS).index(room)
+    working = llama.step_working_bytes(cfg, params, rows * t)
+    room_bytes = (1 << 60) if room == "unbounded" else working + sum(
+        b for _, b in with_flash[:rungs])
+    want, want_dense, spared = _FIT_ROOMS[room]
+    with interpret_kernels():
+        l0, g0 = jax.jit(loss_and_grads(
+            dataclasses.replace(cfg, remat_policy="full")))(params, toks)
+        with remat.offer(room_bytes) as offer:
+            l, g = jax.jit(loss_and_grads(cfg))(params, toks)
+        assert offer.kept == (want if flash else want_dense)
+        assert offer.kept_bytes == sum(
+            b for names, b in with_flash if set(names) <= set(offer.kept))
+        # one rung down is the prefix one shorter
+        with remat.offer(room_bytes, back_off=1) as lower:
+            jax.eval_shape(loss_and_grads(cfg), params, toks)
+        assert lower.kept == offer.kept[:len(offer.kept) - len(
+            next((n for n in reversed(llama.KEEP_ORDER)
+                  if set(n) <= set(offer.kept)), ()))]
+        if not flash:
+            full = _dots(loss_and_grads(
+                dataclasses.replace(cfg, remat_policy="full")), params, toks)
+            with remat.offer(room_bytes):
+                assert _dots(loss_and_grads(cfg), params, toks) == full - spared
+    np.testing.assert_allclose(float(l), float(l0), rtol=1e-6)
+    _assert_grads_close(g, g0, rtol=1e-5)
+
+
+@pytest.mark.parametrize("room,back_off,want", [
+    (0, 0, ()),
+    (5, 0, ("a",)),              # b is too large, c after it still fits
+    (7, 0, ("a", "c")),
+    (7, 1, ("a",)),              # a rung down: the last one taken goes
+    (15, 0, ("a", "b")),
+    (17, 0, ("a", "b", "c")),
+    (17, 2, ("a",)),
+    (17, 5, ()),
+])
+def test_choose_takes_in_order_what_still_fits(room, back_off, want):
+    from edl_tpu.parallel import remat
+
+    candidates = [(("a",), 5), (("b",), 10), (("c",), 2)]
+    assert remat.choose(candidates, 0) == ()  # no offer open
+    with remat.offer(100 + room, back_off) as offer:
+        assert remat.choose(candidates, 100) == want
+    assert offer.kept == want and offer.rungs == len(want)
+    assert offer.kept_bytes == sum(
+        b for names, b in candidates if names[0] in want)
+
+
+def test_fit_is_full_under_a_pipeline_and_with_no_offer():
+    import dataclasses
+
+    from edl_tpu.parallel import remat
+
+    cfg = dataclasses.replace(llama.LlamaConfig.tiny(), remat=True)
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    x = jnp.zeros((4, 16, cfg.d_model), cfg.dtype)
+    assert llama._fit_kept(cfg, params, x, None, 1, 1) == ()
+    with remat.offer(1 << 60) as offer:
+        assert llama._fit_kept(cfg, params, x, None, 1, 2) == ()
+        assert offer.kept == ()
+        assert llama._fit_kept(cfg, params, x, None, 1, 1) == (
+            "mlp_up", "mlp_gate", "attn_q", "attn_k", "attn_v")
 
 
 def test_remat_attn_policy_runs_with_flash():

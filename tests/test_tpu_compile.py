@@ -716,3 +716,96 @@ def test_decode_attention_at_the_hybrid_cells_cache(v5e, pack):
         q, cache, cache, _sds((72,), jnp.int32, one),
         _sds((), jnp.int32, one)).compile()
     assert "edl_decode_attn" in compiled.as_text()
+
+
+# -- what the training step's rematerialised layers keep (PR 40) --------------
+
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)  # what the compiler holds a v5e to
+
+
+def _train_steady_step(v5e):
+    """``mistral7b.train-steady``'s step as ``ElasticTrainer`` builds it:
+    Mistral-7B's widths at 4 layers, 4 x 4097 tokens, adafactor, the
+    float32 state donated; (build, state, batch, mesh) of shapes alone."""
+    import optax
+
+    from edl_tpu.api.job import MeshSpec
+    from edl_tpu.train import trainer as tr
+
+    cfg = llama.LlamaConfig(
+        **dict(SERVING_CELLS["mistral7b-L16"][1], n_layers=4),
+        dtype=jnp.bfloat16, use_flash=True, remat=True)
+    plan = MeshPlan.from_spec(MeshSpec(), 1)
+    mesh = plan.build(v5e[:1])
+    tx = optax.adafactor(1e-3)
+    pspecs = llama.param_pspecs(cfg, plan)
+    shape = jax.eval_shape(lambda: tr.TrainState.create(
+        llama.init_params(jax.random.PRNGKey(0), cfg), tx))
+    state_sh = tr._state_sharding(shape, plan, mesh, pspecs)
+    state = jax.tree_util.tree_map(
+        lambda x, s: _sds(x.shape, x.dtype, s), shape, state_sh)
+    batch = {"tokens": _sds((4, 4097), jnp.int32, plan.batch_sharding(mesh))}
+    step = tr.make_train_step(
+        llama.make_loss_fn(cfg, plan, mesh), tx, plan, mesh, pspecs)
+    return step, state, batch, mesh
+
+
+def _flash_fwd_calls(text):
+    return len(re.findall(r"custom-call\(.*edl_flash_fwd", text))
+
+
+def test_train_steady_step_keeps_what_fits_the_chip_in_one_compile(
+        v5e, monkeypatch):
+    """The cell's step, fitted to the described chip by the trainer
+    itself (``make_train_step``'s first call, up to the dispatch): ONE
+    compile, the compiler's peak under the device's limit by the
+    headroom, and the flash forward kernel once in the optimized
+    program (the backward reuses what the layer kept) where
+    ``remat_policy="full"`` has it twice."""
+    from edl_tpu.obs import compilewatch
+    from edl_tpu.train import trainer as tr
+
+    monkeypatch.setattr(tr, "device_bytes_limit", lambda mesh: V5E_BYTES_LIMIT)
+    step, state, batch, mesh = _train_steady_step(v5e)
+    kept = {}
+    with compilewatch.Window() as built:
+        jitted = tr._fit_to_device(step.build, state, batch, mesh, kept)
+    assert built.programs == 1 and kept["compiles"] == 1
+    assert kept["remat_kept"].split(",")[:2] == ["flash_out", "flash_lse"]
+    assert kept["hbm_headroom_bytes"] >= V5E_BYTES_LIMIT * tr.HEADROOM_SHARE
+    # the call that follows finds this executable: lowering again under
+    # no offer is the same trace, and its compile is jit's cached one
+    with compilewatch.Window() as again:
+        compiled = jitted.lower(state, batch).compile()
+    assert again.programs == 0
+    assert tr._peak_bytes(compiled) == (
+        V5E_BYTES_LIMIT - kept["hbm_headroom_bytes"])
+    assert _flash_fwd_calls(compiled.as_text()) == 1
+    # and "full", the parent's program: the kernel twice, more room
+    monkeypatch.setattr(tr, "device_bytes_limit", lambda mesh: None)
+    full = tr._fit_to_device(step.build, state, batch, mesh, {})
+    compiled_full = full.lower(state, batch).compile()
+    assert _flash_fwd_calls(compiled_full.as_text()) == 2
+    assert tr._peak_bytes(compiled_full) < tr._peak_bytes(compiled)
+
+
+def test_a_step_the_compiler_refuses_or_squeezes_is_traced_again_a_rung_lower(
+        v5e, monkeypatch):
+    """A device that claims more memory than the described chip has: the
+    estimate lets the whole of ``KEEP_ORDER`` through; the compiler
+    refuses it (``RESOURCE_EXHAUSTED``) and the next rung too; the flash
+    pair with ``mlp_up`` it compiles, 1.16 GiB inside the limit, but
+    only by rematerialising on its own (``.remat`` in its text: on the
+    chip that step is slower than "full"), so the trainer settles on
+    the flash pair: four compiles, the rare path."""
+    from edl_tpu.train import trainer as tr
+
+    monkeypatch.setattr(tr, "device_bytes_limit", lambda mesh: 1 << 40)
+    step, state, batch, mesh = _train_steady_step(v5e)
+    kept = {}
+    jitted = tr._fit_to_device(step.build, state, batch, mesh, kept)
+    assert kept["compiles"] == 4
+    assert kept["remat_kept"] == "flash_out,flash_lse"
+    compiled = jitted.lower(state, batch).compile()
+    assert tr._peak_bytes(compiled) < V5E_BYTES_LIMIT
+    assert not tr._compiler_traded(compiled)
