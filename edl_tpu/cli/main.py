@@ -895,6 +895,7 @@ _SERVED_FAMILIES = {
     "deepseek_v3": ("edl_tpu.models.deepseek_v3", "DeepseekV3Config"),
     "retention": ("edl_tpu.models.retention", "RetentionConfig"),
     "ssm_hybrid": ("edl_tpu.models.ssm_hybrid", "SSMHybridConfig"),
+    "glm_dsa": ("edl_tpu.models.glm_dsa", "GlmDsaConfig"),
 }
 
 
@@ -902,7 +903,7 @@ def _load_llama_serving(export_dir: str, mesh_arg: str, int8: bool,
                         families=("llama",)):
     """Load a published export for a decoding consumer — shared by
     ``edl generate`` and ``edl serve`` (which also takes the
-    ``deepseek_v3``, ``retention`` and ``ssm_hybrid`` families:
+    ``deepseek_v3``, ``retention``, ``ssm_hybrid`` and ``glm_dsa`` families:
     ``families``). ``mesh_arg`` (MeshPlan
     grammar) loads the params SHARDED with the training layout so
     exports bigger than one chip's HBM serve at all (the dense decoder

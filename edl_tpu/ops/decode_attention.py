@@ -143,12 +143,18 @@ def _kernel(
 
 
 def _latent_kernel(
-    slot_ref, blk_ref, pos_ref, layer_ref, q_ref, c_ref,
-    o_ref, m_ref, l_ref, acc_ref, *, block_s: int, rank: int, sm_scale: float,
+    slot_ref, blk_ref, pos_ref, layer_ref, q_ref, c_ref, *rest,
+    block_s: int, rank: int, sm_scale: float, chosen: bool = False,
 ):
     """:func:`_kernel` for a latent cache: one operand, read once, is
-    the keys (all its columns) and the values (its first ``rank``)."""
+    the keys (all its columns) and the values (its first ``rank``).
+    ``chosen``: a further operand, one row of float32 a slot and block,
+    is added to every head's scores: 0 at a position the slot attends,
+    ``NEG_INF`` at one it does not (a block with none leaves a state
+    that the first attended position's maximum wipes out)."""
     del layer_ref
+    bias_ref = rest[0] if chosen else None
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     t = pl.program_id(0)
     si = blk_ref[t]
     pos = pos_ref[slot_ref[t]]
@@ -157,7 +163,8 @@ def _latent_kernel(
     def _block(last: bool):
         c = c_ref[...]  # [block_s, rank + rope]
         _online_block(
-            q_ref[...], c, c[:, :rank], None, pos - si * block_s + 1,
+            q_ref[...], c, c[:, :rank],
+            bias_ref[...] if chosen else None, pos - si * block_s + 1,
             sm_scale * LOG2E, m_ref, l_ref, acc_ref, o_ref, last,
         )
 
@@ -314,6 +321,7 @@ def decode_attention_latent(
     sm_scale: float,
     block_s: int | None = None,
     interpret: bool = False,
+    chosen: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Single-query attention in the latent space (the absorbed form of
     multi-head latent attention): every query head of a slot against
@@ -330,7 +338,12 @@ def decode_attention_latent(
 
     Grid, scalar prefetch and softmax are :func:`decode_attention`'s:
     the list of live (slot, S-block) pairs, base-2 online softmax in
-    float32. There is no head bias: all heads read all columns."""
+    float32. There is no head bias: all heads read all columns.
+
+    ``chosen`` [B, S] bool: the positions each slot attends, of those
+    up to its ``pos`` (at least one of them); every live block is still
+    read, and the positions not chosen are masked out of the softmax (a
+    model whose indexer chooses a slot's keys; models/glm_dsa.py)."""
     b, h, width = q.shape
     n_layers, _, s, _ = cache.shape
     if block_s is None:
@@ -338,7 +351,8 @@ def decode_attention_latent(
     if s % block_s:
         raise ValueError(f"block_s={block_s} must divide the cache length {s}")
     kernel = functools.partial(
-        _latent_kernel, block_s=block_s, rank=rank, sm_scale=sm_scale
+        _latent_kernel, block_s=block_s, rank=rank, sm_scale=sm_scale,
+        **({} if chosen is None else {"chosen": True})
     )
     pos = jnp.clip(pos.astype(jnp.int32), 0, s - 1)
     steps, slot_of, block_of = live_blocks(pos, block_s, s // block_s)
@@ -349,6 +363,13 @@ def decode_attention_latent(
     def cache_map(t, slot_ref, blk_ref, pos_ref, layer_ref):
         return (layer_ref[0], slot_ref[t], blk_ref[t], 0)
 
+    masks, mask_specs = (), []
+    if chosen is not None:
+        masks = (jnp.where(chosen, 0.0, NEG_INF).astype(jnp.float32)
+                 .reshape(b, 1, s),)
+        mask_specs = [pl.BlockSpec(
+            (None, 1, block_s),
+            lambda t, slot_ref, blk_ref, *_: (slot_ref[t], 0, blk_ref[t]))]
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -357,6 +378,7 @@ def decode_attention_latent(
             in_specs=[
                 pl.BlockSpec((None, h, width), slot_map),
                 pl.BlockSpec((None, None, block_s, width), cache_map),
+                *mask_specs,
             ],
             out_specs=pl.BlockSpec((None, h, rank), slot_map),
             scratch_shapes=[
@@ -373,5 +395,5 @@ def decode_attention_latent(
         name="edl_decode_attn_latent",
     )(
         slot_of, block_of, pos,
-        jnp.reshape(layer, (1,)).astype(jnp.int32), q, cache,
+        jnp.reshape(layer, (1,)).astype(jnp.int32), q, cache, *masks,
     )

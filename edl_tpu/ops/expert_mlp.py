@@ -70,9 +70,14 @@ the rows as ``moe_dropless`` sorts them and the runs' sizes:
   ``ragged_dot`` x 3; sort, gathers, un-sort and the float32 combine
   stay ``moe_dropless``'s.
 
-An expert is taken whole by both: shapes whose expert twice over (one
-computing, one arriving) would not fit a v5e's VMEM are refused, not
-tiled.
+An expert that fits a v5e's VMEM twice over (one computing, one
+arriving) is taken whole by both. A wider one (6144 x 2048: 75 MB)
+passes in tiles of its width ``f``, which SwiGLU splits cleanly: ``h[:,
+j] = silu(x @ w1[:, j]) * (x @ w3[:, j])`` and ``y = sum_j h[:, j] @
+w2[j, :]``, the sum in the float32 accumulator. Both kernels then run
+over a second grid axis of ``f`` tiles with all three matrices as
+BlockSpec operands (the pipeline fetches tile ``j + 1`` under tile
+``j``); the shapes that fit take the whole-expert paths they took.
 
 Off-TPU the kernels run only under the Pallas interpreter, asked for by
 the caller (``interpret=True``, or ``flash_attention.interpret_kernels``
@@ -98,6 +103,25 @@ VMEM_BYTES = 128 << 20  # a v5e's
 ROW_TILE = 16  # a bf16 tile's sublanes: rows are padded to whole tiles
 
 
+def _weighted_term(x_ref, c_ref, expert, w1_ref, w3_ref, w2_ref):
+    """The rows through the matrices the refs hold (an expert's, or one
+    tile of its ``f``), each weighted by its ``c`` for ``expert``:
+    float32 [N, d], zero for a row that did not choose it."""
+    x = x_ref[...]
+    dt = x.dtype
+    a = jnp.dot(x, w1_ref[...].astype(dt),
+                preferred_element_type=jnp.float32)
+    b = jnp.dot(x, w3_ref[...].astype(dt),
+                preferred_element_type=jnp.float32)
+    c = c_ref[...]  # [N, E]: this expert's column, by its lane
+    lane = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    col = jnp.sum(jnp.where(lane == expert, c, 0.0), axis=1,
+                  keepdims=True)
+    h = jnp.where(col != 0.0, jax.nn.silu(a) * b * col, 0.0).astype(dt)
+    return jnp.dot(h, w2_ref[...].astype(dt),
+                   preferred_element_type=jnp.float32)
+
+
 def _kernel(
     hit_ref, n_ref, x_ref, c_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref
 ):
@@ -109,23 +133,47 @@ def _kernel(
 
     @pl.when(i < n_ref[0])
     def _expert():
-        x = x_ref[...]
-        dt = x.dtype
-        a = jnp.dot(x, w1_ref[...].astype(dt),
-                    preferred_element_type=jnp.float32)
-        b = jnp.dot(x, w3_ref[...].astype(dt),
-                    preferred_element_type=jnp.float32)
-        c = c_ref[...]  # [N, E]: this expert's column, by its lane
-        lane = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
-        col = jnp.sum(jnp.where(lane == hit_ref[i], c, 0.0), axis=1,
-                      keepdims=True)
-        h = jnp.where(col != 0.0, jax.nn.silu(a) * b * col, 0.0).astype(dt)
-        acc_ref[...] += jnp.dot(h, w2_ref[...].astype(dt),
-                                preferred_element_type=jnp.float32)
+        acc_ref[...] += _weighted_term(
+            x_ref, c_ref, hit_ref[i], w1_ref, w3_ref, w2_ref)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _out():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tiled_kernel(
+    hit_ref, n_ref, x_ref, c_ref, w1_ref, w3_ref, w2_ref, o_ref, acc_ref
+):
+    """``_kernel`` over a second grid axis of ``f`` tiles: one tile of
+    an expert's ``h`` a step, summed into the same accumulator."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < n_ref[0])
+    def _tile():
+        acc_ref[...] += _weighted_term(
+            x_ref, c_ref, hit_ref[i], w1_ref, w3_ref, w2_ref)
+
+    @pl.when((i == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+    def _out():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def f_tile(d: int, f: int, itemsize: int, budget: int) -> int:
+    """The widest tile of an expert's ``f`` columns, a multiple of 128
+    that divides ``f``, whose three blocks (``[d, tile]`` twice and
+    ``[tile, d]``) fit ``budget`` bytes three times over (computing,
+    arriving, and a copy where they are cast)."""
+    for tile in range(f, 0, -128):
+        if f % tile == 0 and tile % 128 == 0 \
+                and 3 * 3 * d * tile * itemsize <= budget:
+            return tile
+    raise ValueError(
+        f"experts of {d} x {f}: no tile of f, a multiple of 128 that "
+        f"divides it, fits {budget >> 20} MiB of VMEM")
 
 
 def combine_weights(idx: jnp.ndarray, w: jnp.ndarray, held: int, first: int):
@@ -192,9 +240,8 @@ def expert_mlp(
             + 6 * rows * f * 4
             + 2 * rows * max(held, 128) * 4 + (4 << 20))
     if vmem > VMEM_BYTES:
-        raise ValueError(
-            f"experts of {d} x {f} need {vmem >> 20} MiB of VMEM whole; "
-            f"the chip has {VMEM_BYTES >> 20}")
+        out = _expert_mlp_tiled(hit, n_hit, x, c, w1, w3, w2, interpret)
+        return out[:n] if pad else out
     out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -219,6 +266,55 @@ def expert_mlp(
         name="edl_expert_mlp",
     )(hit, n_hit, x, c, w1, w3, w2)
     return out[:n] if pad else out
+
+
+def _expert_mlp_tiled(hit, n_hit, x, c, w1, w3, w2, interpret):
+    """``edl_expert_mlp`` for an expert too wide to take whole: the
+    grid is (hit experts, tiles of ``f``); a step past the hit experts
+    names the last tile of the last of them, which is already there."""
+    rows, d = x.shape
+    held, _, f = w1.shape
+    item = max(w1.dtype.itemsize, x.dtype.itemsize)
+    fixed = rows * d * (4 * x.dtype.itemsize + 4) \
+        + 2 * rows * max(held, 128) * 4 + (4 << 20)
+    tile = f_tile(d, f, item, (VMEM_BYTES // 2) - fixed)
+    n_f = f // tile
+
+    def at(i, j, hit_ref, n_ref):
+        return hit_ref[i], jnp.where(i < n_ref[0], j, n_f - 1)
+
+    def up(i, j, hit_ref, n_ref):
+        e, t = at(i, j, hit_ref, n_ref)
+        return (e, 0, t)
+
+    def down(i, j, hit_ref, n_ref):
+        e, t = at(i, j, hit_ref, n_ref)
+        return (e, t, 0)
+
+    vmem = 3 * 3 * d * tile * item + fixed + 6 * rows * tile * 4
+    return pl.pallas_call(
+        _tiled_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(held, n_f),
+            in_specs=[
+                pl.BlockSpec((rows, d), lambda i, j, *_: (0, 0)),
+                pl.BlockSpec((rows, held), lambda i, j, *_: (0, 0)),
+                pl.BlockSpec((None, d, tile), up),
+                pl.BlockSpec((None, d, tile), up),
+                pl.BlockSpec((None, tile, d), down),
+            ],
+            out_specs=pl.BlockSpec((rows, d), lambda i, j, *_: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(vmem),
+        ),
+        interpret=interpret,
+        name="edl_expert_mlp",
+    )(hit, n_hit, x, c, w1, w3, w2)
 
 
 # -- more rows than MAX_ROWS: each expert its own run of the sorted rows -----
@@ -268,16 +364,107 @@ def visit_tile(x_ref, w1, w3, w2, o_ref, t, start, end):
     ``start <= row < end`` (the expert's run) are written. The tile
     stays in VMEM while consecutive visits name it: each run of rows in
     it is written by its own expert's visit."""
-    x = x_ref[...]
+    _write_run(o_ref, _swiglu_tile(x_ref[...], w1, w3, w2), t, start, end)
+
+
+def _swiglu_tile(x, w1, w3, w2):
+    """A row tile through an expert's matrices (or one tile of their
+    ``f``): float32 [tile, d]."""
     dt = x.dtype
     a = jnp.dot(x, w1.astype(dt), preferred_element_type=jnp.float32)
     b = jnp.dot(x, w3.astype(dt), preferred_element_type=jnp.float32)
     h = (jax.nn.silu(a) * b).astype(dt)
-    y = jnp.dot(h, w2.astype(dt), preferred_element_type=jnp.float32)
-    tile = x.shape[0]
+    return jnp.dot(h, w2.astype(dt), preferred_element_type=jnp.float32)
+
+
+def _write_run(o_ref, y, t, start, end):
+    """Row tile ``t``'s rows ``start <= row < end`` take ``y``'s; the
+    others keep what the tile holds."""
+    tile = y.shape[0]
     row = t * tile + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
     mine = (row >= start) & (row < end)
     o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
+
+
+def _grouped_tiled_kernel(
+    g_ref, t_ref, edge_ref, n_ref, x_ref, w1_ref, w3_ref, w2_ref, o_ref,
+    acc_ref
+):
+    """A visit over a second grid axis of ``f`` tiles: the row tile's
+    ``y`` summed tile by tile in float32, written (the rows of the
+    visit's expert, by ``where``) with the last."""
+    v, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(v < n_ref[0])
+    def _visit():
+        y = _swiglu_tile(x_ref[...], w1_ref[...], w3_ref[...], w2_ref[...])
+
+        @pl.when(j == 0)
+        def _first():
+            acc_ref[...] = y
+
+        @pl.when(j > 0)
+        def _more():
+            acc_ref[...] += y
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _write():
+            g = g_ref[v]
+            _write_run(o_ref, acc_ref[...], t_ref[v], edge_ref[g],
+                       edge_ref[g + 1])
+
+
+def _grouped_tiled(rows, visits, n_tiles, w1, w3, w2, tile, interpret):
+    """``edl_grouped_expert_mlp`` for an expert too wide to take whole:
+    the grid is (visits, tiles of ``f``), the three matrices BlockSpec
+    operands. An expert whose run spans several row tiles is fetched
+    once a row tile."""
+    g, t, _, _, _, edges, n_visits = visits
+    _, d = rows.shape
+    held, _, f = w1.shape
+    item = max(w1.dtype.itemsize, rows.dtype.itemsize)
+    fixed = 4 * tile * d * rows.dtype.itemsize + 2 * tile * d * 4 + (4 << 20)
+    ft = f_tile(d, f, item, (VMEM_BYTES // 2) - fixed)
+    n_f = f // ft
+
+    def row_tile(v, j, g_ref, t_ref, *_):
+        return (t_ref[v], 0)
+
+    def at(v, j, g_ref, t_ref, edge_ref, n_ref):
+        return g_ref[v], jnp.where(v < n_ref[0], j, n_f - 1)
+
+    def up(v, j, *refs):
+        e, c = at(v, j, *refs)
+        return (e, 0, c)
+
+    def down(v, j, *refs):
+        e, c = at(v, j, *refs)
+        return (e, c, 0)
+
+    vmem = 3 * 3 * d * ft * item + fixed + 4 * tile * ft * 4
+    return pl.pallas_call(
+        _grouped_tiled_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_tiles + held - 1, n_f),
+            in_specs=[
+                pl.BlockSpec((tile, d), row_tile),
+                pl.BlockSpec((None, d, ft), up),
+                pl.BlockSpec((None, d, ft), up),
+                pl.BlockSpec((None, ft, d), down),
+            ],
+            out_specs=pl.BlockSpec((tile, d), row_tile),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, rows.dtype),
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(vmem),
+        ),
+        interpret=interpret,
+        name="edl_grouped_expert_mlp",
+    )(g, t, edges, n_visits, rows, w1, w3, w2)
 
 
 def _grouped_kernel(
@@ -347,11 +534,11 @@ def grouped_expert_mlp(
     vmem = (2 * 3 * d * f * w1.dtype.itemsize + 3 * d * f * item
             + 4 * tile * d * rows.dtype.itemsize
             + 4 * tile * f * 4 + 2 * tile * d * 4 + (4 << 20))
-    if vmem > VMEM_BYTES:
-        raise ValueError(
-            f"experts of {d} x {f} need {vmem >> 20} MiB of VMEM whole; "
-            f"the chip has {VMEM_BYTES >> 20}")
     visits = group_visits(sizes, n_tiles, tile)
+    if vmem > VMEM_BYTES:
+        out = _grouped_tiled(
+            rows, visits, n_tiles, w1, w3, w2, tile, interpret)
+        return out[:m] if pad else out
 
     def row_tile(v, g_ref, t_ref, *_):
         return (t_ref[v], 0)

@@ -237,9 +237,13 @@ def _memo(key, make):
 # cache, ``state_live_share`` of a per-slot state; a model with both
 # answers both), from the host's slot table: ``held`` has one entry a
 # slot, the tokens it holds or None for an idle one; ``block`` is the
-# S-block above.
+# S-block above. Beside the six, ``_SEAM_OPTIONAL``: a config MAY say
+# into how many pieces of query rows its one prefill program walks a
+# bucket (``cfg.serve_prefill_pieces(bucket)``): the ``serving.prefill``
+# span then carries ``pieces``.
 _SEAM = ("serve_cache_spec", "serve_cache_kinds", "serve_prefill",
          "serve_decode_block", "serve_attn_block", "serve_cache_read")
+_SEAM_OPTIONAL = ("serve_prefill_pieces",)
 
 
 def _block_program(cfg, b: int, s: int, horizon: int, sampling: bool):
@@ -670,6 +674,8 @@ class ContinuousBatchingEngine:
                 f"{type(cfg).__name__} cannot be served: it lacks "
                 f"{', '.join(missing)}"
             )
+        (self._prefill_pieces,) = (
+            getattr(cfg, name, None) for name in _SEAM_OPTIONAL)
         if not isinstance(cfg, llama.LlamaConfig) and (
             block_size or prefix_cache or prefill_chunk
             or kv_quant != "off" or spec_k
@@ -1844,7 +1850,12 @@ class ContinuousBatchingEngine:
             disttrace.root("rid", rid) if rid is not None
             else contextlib.nullcontext()
         )
-        with rid_root, tracing.span("serving.prefill", bucket=tb, rid=rid):
+        # a model that walks a bucket in pieces inside its one program
+        # says how many (``_SEAM_OPTIONAL``; models/glm_dsa.py)
+        pieces = self._prefill_pieces
+        extra = {"pieces": pieces(tb)} if pieces and not self._paged else {}
+        with rid_root, tracing.span("serving.prefill", bucket=tb, rid=rid,
+                                    **extra):
             (tok0, self._dtok, self._dpos, self._dact, self._drem,
              self._deos, *cache) = self._prefill_for(tb)(
                 self.params,

@@ -7,6 +7,8 @@ Pallas interpreter, against the grouped form they stand in for
 ``highest`` on both sides differs by the order of summation alone, so
 1e-5 holds; bfloat16 rows and weights are held to bfloat16's step."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -212,28 +214,93 @@ def test_more_rows_than_the_kernel_takes_are_refused_by_the_kernel():
                       lp["we2"], interpret=True)
 
 
-def test_experts_that_do_not_fit_vmem_whole_twice_over_are_refused():
-    """An expert is taken whole; the widths that would need ``f`` in
-    blocks bring them. The cell's 2048 x 768 pass (traced, not run)."""
-    def shapes(d, f):
-        sds = jax.ShapeDtypeStruct
-        return (sds((96, d), jnp.bfloat16), sds((96, 6), jnp.int32),
-                sds((96, 6), jnp.float32), sds((E, d, f), jnp.bfloat16),
-                sds((E, d, f), jnp.bfloat16), sds((E, f, d), jnp.bfloat16))
+def wide_shapes(d, f, rows=96):
+    sds = jax.ShapeDtypeStruct
+    return (sds((rows, d), jnp.bfloat16), sds((rows, 6), jnp.int32),
+            sds((rows, 6), jnp.float32), sds((E, d, f), jnp.bfloat16),
+            sds((E, d, f), jnp.bfloat16), sds((E, f, d), jnp.bfloat16))
 
+
+def grouped_of_a_step(x, idx, w, w1, w3, w2):  # rows x 6 sorted rows
+    rows = jnp.repeat(x, 6, axis=0)
+    return em.grouped_expert_mlp(
+        rows, jnp.zeros((E,), jnp.int32), w1, w3, w2, interpret=True)
+
+
+@pytest.mark.parametrize("d, f, tiled", [
+    (2048, 768, False),  # the other expert model's cell: whole, as it was
+    (6144, 2048, True),  # 75 MB an expert: in tiles of f
+    (7168, 2048, True),
+])
+def test_an_expert_that_does_not_fit_vmem_whole_passes_in_tiles_of_f(
+        d, f, tiled):
+    """The shapes that fit take the whole-expert kernels they took (one
+    grid axis); a wider expert runs over a second axis of ``f`` tiles
+    (traced, not run)."""
     run = lambda *a: em.expert_mlp(*a, interpret=True)
-    assert jax.eval_shape(run, *shapes(2048, 768)).shape == (96, 2048)
-    with pytest.raises(ValueError, match="MiB of VMEM"):
-        jax.eval_shape(run, *shapes(7168, 2048))
+    for fn, rows in ((run, 96), (grouped_of_a_step, 576)):
+        text = jaxpr_text(fn, *wide_shapes(d, f))
+        assert jax.eval_shape(fn, *wide_shapes(d, f)).shape == (rows, d)
+        two_axes = re.search(r"grid=\(\d+, (\d+)\)", text)
+        assert bool(two_axes) == tiled
+        assert not tiled or int(two_axes.group(1)) > 1
 
-    def grouped(x, idx, w, w1, w3, w2):  # 96 x 6 sorted rows
-        rows = jnp.repeat(x, 6, axis=0)
-        return em.grouped_expert_mlp(
-            rows, jnp.zeros((E,), jnp.int32), w1, w3, w2, interpret=True)
 
-    assert jax.eval_shape(grouped, *shapes(2048, 768)).shape == (576, 2048)
-    with pytest.raises(ValueError, match="MiB of VMEM"):
-        jax.eval_shape(grouped, *shapes(7168, 2048))
+def test_a_width_no_tile_divides_is_refused():
+    with pytest.raises(ValueError, match="no tile of f"):
+        jax.eval_shape(lambda *a: em.expert_mlp(*a, interpret=True),
+                       *wide_shapes(16384, 4000))
+
+
+@pytest.fixture
+def small_vmem(monkeypatch):
+    """A VMEM in which an expert of 256 x 1024 float32 does not fit
+    whole and a tile of 128 of its columns does: the tiled kernels at a
+    size the interpreter runs."""
+    monkeypatch.setattr(em, "VMEM_BYTES", 13 << 20)
+
+
+@pytest.mark.parametrize("n", [5, 64, 128, 300])
+def test_the_tiled_kernels_are_the_whole_expert_kernels_and_ragged_dot(
+        n, small_vmem, monkeypatch):
+    """The same routing through the tiled path (four or eight tiles of ``f``),
+    through the whole-expert path (the VMEM as it is) and through the
+    grouped matmuls: one result."""
+    d, f, e = 256, 1024, 4
+    lp = layer(40 + n, e=e, d=d, f=f)
+    x = jax.random.normal(jax.random.PRNGKey(300 + n), (n, d))
+    idx, w = routed(lp, x, k=2)
+    tiled, grouped = both(x, idx, w, lp)
+    with interpret_kernels():
+        text = jaxpr_text(lambda x: moe.moe_dropless(
+            x, idx, w, lp["we1"], lp["we3"], lp["we2"], kernel=True), x)
+    assert int(re.search(r"grid=\(\d+, (\d+)\)", text).group(1)) >= 4
+    monkeypatch.undo()
+    # another row count: the whole-expert kernels are jitted by shape
+    x2 = jnp.concatenate([x, x[:1]])
+    idx2, w2 = jnp.concatenate([idx, idx[:1]]), jnp.concatenate([w, w[:1]])
+    whole, _ = both(x2, idx2, w2, lp)
+    assert err(tiled, grouped) < 2e-5
+    assert err(tiled, whole[:n]) < 2e-5
+    assert float(jnp.max(jnp.abs(grouped))) > 0.1
+
+
+def test_the_tiled_kernel_takes_a_share_and_skips_an_expert_nobody_chose(
+        small_vmem):
+    """Experts 2 and 3 of 4 held here; expert 3's weights are NaN and
+    nobody chooses it."""
+    d, f = 256, 1024
+    lp = layer(77, e=4, d=d, f=f)
+    x = jax.random.normal(jax.random.PRNGKey(78), (24, d))
+    idx = jnp.tile(jnp.array([[0, 2]], jnp.int32), (24, 1))
+    w = jnp.full((24, 2), 0.5)
+    held = {k: lp[k][2:].at[1].set(jnp.nan) for k in ("we1", "we3", "we2")}
+    with interpret_kernels(), jax.default_matmul_precision("highest"):
+        got = moe.moe_dropless(x, idx, w, held["we1"], held["we3"],
+                               held["we2"], first=2, kernel=True)
+        want = 0.5 * reference._swiglu(
+            x, lp["we1"][2], lp["we3"][2], lp["we2"][2])
+    assert err(got, want) < 2e-5
 
 
 def test_a_weight_of_exactly_zero_reads_as_not_chosen():

@@ -809,3 +809,100 @@ def test_a_step_the_compiler_refuses_or_squeezes_is_traced_again_a_rung_lower(
     compiled = jitted.lower(state, batch).compile()
     assert tr._peak_bytes(compiled) < V5E_BYTES_LIMIT
     assert not tr._compiler_traded(compiled)
+
+
+# -- PR 41: a learned indexer chooses the positions attended --------------------
+
+LONG_SPARSE = "glm5.long-sparse"
+CHIP_BYTES = 15.75 * 2 ** 30  # what the compiler holds a v5e program to
+
+
+@pytest.mark.parametrize("rows, grouped", [(32, False), (2048 * 8, True)])
+def test_an_expert_of_6144_x_2048_passes_in_tiles_of_f(v5e, rows, grouped):
+    """Both expert kernels at the sparse latent-attention cell's widths
+    (8 held experts of 6144 x 2048 bf16, 75 MB each: no expert fits
+    VMEM whole): a decode step's 32 rows through ``edl_expert_mlp``, a
+    prefill piece's 2048 x 8 sorted rows through
+    ``edl_grouped_expert_mlp``, each over a second grid axis of ``f``
+    tiles, nothing expert-sized made beside them."""
+    from edl_tpu.ops import expert_mlp as em
+
+    one = SingleDeviceSharding(v5e[0])
+    e, d, f, k = 8, 6144, 2048, 8
+    experts = (_sds((e, d, f), jnp.bfloat16, one),
+               _sds((e, d, f), jnp.bfloat16, one),
+               _sds((e, f, d), jnp.bfloat16, one))
+    if grouped:
+        lowered = jax.jit(em.grouped_expert_mlp, donate_argnums=0).lower(
+            _sds((rows, d), jnp.bfloat16, one), _sds((e,), jnp.int32, one),
+            *experts)
+    else:
+        lowered = jax.jit(em.expert_mlp).lower(
+            _sds((rows, d), jnp.bfloat16, one),
+            _sds((rows, k), jnp.int32, one),
+            _sds((rows, k), jnp.float32, one), *experts)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    name = "edl_grouped_expert_mlp" if grouped else "edl_expert_mlp"
+    assert name in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not re.findall(r"= bf16\[8,(?:6144,2048|2048,6144)\]\S* copy\(",
+                          text)
+
+
+def _cache_sized(text, cfg, b, s):
+    """Operations of an optimized program that make an array as large
+    as a cache array of the sparse latent-attention cell, or as a layer
+    of one (a copy, a transpose, a slice)."""
+    L = cfg.n_layers
+    shapes = {f"bf16[{lead}{b},{s},{w}]" for w in (cfg.cache_width,
+                                                  cfg.index_dim)
+              for lead in ("", f"{L},")}
+    made = re.findall(r"= (bf16\[[\d,]+\])\S* ([\w\-]+)\(", text)
+    return [(shape, op) for shape, op in made if shape in shapes
+            and op not in ("fusion", "parameter", "scatter",
+                           "get-tuple-element", "dynamic-update-slice")]
+
+
+def test_long_sparse_block_reads_the_live_rows_masked_and_in_place(v5e):
+    """``edl_serve_block`` of ``glm5.long-sparse`` (one dense + four
+    expert layers at published widths, 32 slots x 32768): weights and
+    both cache arrays are 13.46 GB of the chip's 15.75 GiB and both
+    arrays alias their outputs; a layer's attention is one
+    ``edl_decode_attn_latent`` over the slot's live rows with the
+    positions not chosen masked (no sort of the scores and no gather of
+    rows: 0.73 and 1.94 ms a layer when there were, PR 41), an expert
+    layer's routed experts one ``edl_expert_mlp``; nothing the size of
+    a cache array or of a layer of one is made."""
+    cfg, block, _ = _serving_programs(v5e, LONG_SPARSE, 2048)
+    compiled = block.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8.05e9
+    assert mem.temp_size_in_bytes < 128 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < CHIP_BYTES
+    kernels = re.findall(
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*?kernel_name = \"(\w+)\"",
+        text) or re.findall(r"(edl_decode_attn_latent|edl_expert_mlp)\"", text)
+    assert text.count("edl_decode_attn_latent") >= cfg.n_layers
+    assert text.count("edl_expert_mlp") >= cfg.n_layers - cfg.n_dense_layers
+    assert not re.findall(r"f32\[32,32768\]\S* sort\(", text), kernels
+    assert not re.findall(r"bf16\[32,2048,640\]\S* gather\(", text)
+    assert not _cache_sized(text, cfg, 32, 32768)
+
+
+def test_long_sparse_largest_prefill_fits_beside_32_long_slots(v5e):
+    """``edl_serve_prefill_32768``, the largest bucket, in pieces of
+    2048 rows inside the one program, beside the weights and 32 slots
+    of 32768 positions: the compiler's peak stays under the chip's
+    limit (15.4 GB; a bucket whole would hold 1.07 GB of queries and
+    2.1 GB of expanded keys and values), the first piece runs
+    ``edl_flash_fwd`` and an expert layer's rows one
+    ``edl_grouped_expert_mlp`` a piece."""
+    cfg, _, prefill = _serving_programs(v5e, LONG_SPARSE, 32768)
+    compiled = prefill.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8.05e9
+    assert mem.peak_memory_in_bytes < CHIP_BYTES
+    assert mem.temp_size_in_bytes < 2.6e9
+    assert "edl_flash_fwd" in text and "edl_grouped_expert_mlp" in text
+    assert not _cache_sized(text, cfg, 32, 32768)
