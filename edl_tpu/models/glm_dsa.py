@@ -41,9 +41,17 @@ built so far are the carry), every layer inside a piece: index scores
 against the earlier positions in blocks of keys, the selection as a
 mask (the ``index_topk``-th largest score by bisection on the scores'
 bits, no sort), and a masked sweep over the earlier latent rows in
-blocks, each expanded as it is visited, with a running softmax. A piece
-that starts past ``last`` is skipped; a position past ``last`` is never
-selected, and its rows are written as zeros.
+blocks, each expanded as it is visited, with a running softmax. The
+sweep of a piece is one Pallas kernel a layer
+(``edl_sparse_prefill_attn``, ``ops/sparse_prefill_attention.py``: the
+running maximum, sum and accumulator stay in VMEM across the key
+blocks) under ``use_flash`` where ``Wkvb`` is a plain array and pieces
+and key blocks are whole tiles (``_kernel_key_block``); otherwise
+``_sweep``, the same mathematics in XLA's own lines, which the kernel is
+tested against (an int8 record for ``Wkvb``, ``use_flash=False``, the
+tests' tiny pieces). A piece that starts past ``last`` is skipped; a
+position past ``last`` is never selected, and its rows are written as
+zeros.
 
 **Decode.** One query a slot: index scores against the slot's index
 keys in blocks up to the farthest live position, the selection as a
@@ -642,8 +650,35 @@ def _attend_selected(cfg, q_nope, q_rope, qi, w, lat, kidx, layer: int,
         valid = jnp.arange(tb)[None, None, :] <= upto[..., None]
         sel = select_mask(table, valid, cfg.index_topk)
     with jax.named_scope("attn.sparse"):
-        return _sweep(cfg, jnp.concatenate([q_nope, q_rope], axis=-1), lat,
-                      layer, sel, n_blocks, lp)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        bk = _kernel_key_block(cfg, p, kb, tb, lp)
+        if not bk:
+            return _sweep(cfg, q, lat, layer, sel, n_blocks, lp)
+        from edl_tpu.ops.flash_attention import _INTERPRET
+        from edl_tpu.ops.sparse_prefill_attention import \
+            sparse_prefill_attention
+
+        return sparse_prefill_attention(
+            q, lat, lp["wkvb"], sel, jnp.int32(layer), n_blocks * kb // bk,
+            rank=cfg.kv_rank, rope=cfg.qk_rope_dim,
+            sm_scale=1.0 / float(np.sqrt(cfg.qk_dim)), block_k=bk,
+            interpret=_INTERPRET.get())
+
+
+def _kernel_key_block(cfg: GlmDsaConfig, p: int, kb: int, tb: int, lp: Dict):
+    """Key positions of one block of ``edl_sparse_prefill_attn`` for
+    pieces of ``p`` rows swept in blocks of ``kb``, or 0 where the
+    sweep stays XLA's (``_sweep``): without ``use_flash``, with an int8
+    record for ``Wkvb``, or where pieces and blocks are not whole
+    tiles. The kernel's own block is as large as it likes them while a
+    piece is whole blocks of it: every piece then ends on one, and the
+    live blocks hold the same positions in either size."""
+    from edl_tpu.ops import sparse_prefill_attention as spa
+
+    if not cfg.use_flash or isinstance(lp["wkvb"], dict):
+        return 0
+    bk = spa.BLOCK_K if spa.BLOCK_K % kb == 0 and p % spa.BLOCK_K == 0 else kb
+    return bk if spa.fits(p, bk, tb) else 0
 
 
 def _run(params: Dict, tokens, last, cfg: GlmDsaConfig, every: bool):

@@ -306,6 +306,195 @@ def test_latent_kernel_masks_the_positions_not_chosen(block_s):
     assert err(every[1:], want[1:]) > 1e-2
 
 
+# -- (c') a prefill piece's attention as one kernel -----------------------------
+
+PIECE, BLOCK = 128, 128  # the least the kernel takes: an int8 tile's lanes
+LONG = 4 * PIECE
+
+
+@pytest.fixture
+def whole_tiles(monkeypatch):
+    walk_in(monkeypatch, PIECE, BLOCK)
+
+
+def _piece_case(j, b=1, topk=24, last=None, dtype=jnp.float32, mask=None,
+                seed=0):
+    """Operands of piece ``j`` (queries ``j * PIECE ..``) of a bucket of
+    ``LONG`` at the rehearsal's head widths: (cfg, q, lat, sel, n_blocks,
+    lp). The rows behind the piece's live key blocks are NaN: nothing
+    of them may reach an output."""
+    cfg = cfg_of(dtype=dtype, use_flash=True, index_topk=topk)
+    key = jax.random.split(jax.random.PRNGKey(seed + 7 * j), 4)
+    q = jax.random.normal(
+        key[0], (b, PIECE, cfg.n_heads, cfg.qk_dim), jnp.float32)
+    lat = jax.random.normal(
+        key[1], (2, b, LONG, cfg.cache_width), jnp.float32)
+    lat = lat.at[..., cfg.latent_width:].set(0)
+    n_blocks = (j + 1) * PIECE // BLOCK
+    lat = lat.at[:, :, n_blocks * BLOCK:].set(jnp.nan)
+    wkvb = jax.random.normal(
+        key[2], (cfg.kv_rank, cfg.n_heads * (cfg.qk_nope_dim + cfg.v_dim)),
+        jnp.float32) * cfg.kv_rank ** -0.5
+    positions = j * PIECE + jnp.arange(PIECE)
+    last = jnp.full((b,), LONG - 1) if last is None else jnp.asarray(last)
+    upto = jnp.minimum(positions[None, :], last[:, None])
+    valid = jnp.arange(LONG)[None, None, :] <= upto[..., None]
+    table = jax.random.normal(key[3], (b, PIECE, LONG), jnp.float32)
+    sel = (mask or g.select_mask)(table, valid, topk)
+    return (cfg, q.astype(dtype), lat.astype(dtype), sel,
+            jnp.int32(n_blocks), {"wkvb": wkvb})
+
+
+def _kernel(cfg, q, lat, sel, n_blocks, lp, layer=1, **form):
+    from edl_tpu.ops.sparse_prefill_attention import sparse_prefill_attention
+
+    with jax.default_matmul_precision("highest"):
+        return sparse_prefill_attention(
+            q, lat, lp["wkvb"], sel, jnp.int32(layer), n_blocks,
+            rank=cfg.kv_rank, rope=cfg.qk_rope_dim,
+            sm_scale=cfg.qk_dim ** -0.5, block_k=BLOCK, interpret=True,
+            **form)
+
+
+def _swept(cfg, q, lat, sel, n_blocks, lp, layer=1):
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(lambda q, lat, sel, n: g._sweep(
+            cfg, q, lat, layer, sel, n, lp))(q, lat, sel, n_blocks)
+
+
+SWEEP_CASES = {
+    # a different count of live key blocks for each piece, the dead
+    # ones behind them NaN; serving's batch of 1 and ``forward``'s of 2
+    "piece_0_one_live_block": dict(j=0),
+    "piece_1_two_live_blocks": dict(j=1, b=2),
+    "piece_2_three_live_blocks": dict(j=2),
+    "piece_3_every_block_live": dict(j=3, b=2),
+    # the prompt ends inside the piece: the rows past it attend what
+    # ``last``'s does
+    "last_inside_the_piece": dict(j=1, b=2, last=[PIECE + 37, LONG - 1]),
+    # piece 0's early queries have fewer positions than index_topk,
+    # and its first a single one
+    "fewer_positions_than_index_topk": dict(j=0, b=2, topk=64),
+    "one_position_chosen_a_row": dict(j=2, topk=1),
+    "heads_one_a_step_rows_in_tiles": dict(j=2, b=2, heads=1, block_q=32),
+    "bfloat16": dict(j=3, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SWEEP_CASES))
+def test_the_sparse_prefill_kernel_is_the_sweep(whole_tiles, case):
+    """``edl_sparse_prefill_attn`` (the interpreter) against ``_sweep``
+    on one piece's operands."""
+    spec = dict(SWEEP_CASES[case])
+    form = {k: spec.pop(k) for k in ("heads", "block_q") if k in spec}
+    args = _piece_case(**spec)
+    got, want = _kernel(*args, **form), _swept(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert not bool(jnp.isnan(got).any())
+    tol = 2e-5 if got.dtype == jnp.float32 else 2e-2
+    assert err(got.astype(jnp.float32), want.astype(jnp.float32)) < tol
+    assert float(jnp.max(jnp.abs(want))) > 0.3
+
+
+def test_the_kernel_with_every_position_marked_is_causal_attention(
+        whole_tiles):
+    """A mask of all the valid positions: plain causal attention over
+    the expanded keys and values, written out here."""
+    cfg, q, lat, sel, n_blocks, lp = _piece_case(2, b=2, topk=LONG)
+    got = _kernel(cfg, q, lat, sel, n_blocks, lp)
+    n, r = cfg.qk_nope_dim, cfg.kv_rank
+    live = lat[1, :, :3 * PIECE]
+    with jax.default_matmul_precision("highest"):
+        kv = (live[..., :r] @ lp["wkvb"]).reshape(2, 3 * PIECE, cfg.n_heads,
+                                                  -1)
+        k = jnp.concatenate([kv[..., :n], jnp.broadcast_to(
+            live[:, :, None, r:cfg.latent_width],
+            kv.shape[:3] + (cfg.qk_rope_dim,))], axis=-1)
+        s = jnp.einsum("bphd,bshd->bhps", q, k) * cfg.qk_dim ** -0.5
+        causal = (jnp.arange(3 * PIECE)[None, :]
+                  <= 2 * PIECE + jnp.arange(PIECE)[:, None])
+        p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+        want = jnp.einsum("bhps,bshd->bphd", p, kv[..., n:])
+    assert err(got, want.reshape(got.shape)) < 2e-5
+
+
+def test_the_kernel_tells_the_first_positions_from_the_best_as_the_sweep(
+        whole_tiles):
+    """The planted fault ``_first_mask`` moves the kernel's output by
+    what it moves ``_sweep``'s."""
+    best = _piece_case(3, b=2)
+    first = _piece_case(3, b=2, mask=_first_mask)
+    moved = _kernel(*first) - _kernel(*best)
+    assert err(moved, _swept(*first) - _swept(*best)) < 4e-5
+    assert float(jnp.max(jnp.abs(moved))) > 0.3
+
+
+def test_a_row_that_attends_nothing_reads_zero_as_the_sweeps(whole_tiles):
+    cfg, q, lat, sel, n_blocks, lp = _piece_case(1)
+    sel = sel.at[0, 5].set(False)
+    got = _kernel(cfg, q, lat, sel, n_blocks, lp)
+    assert float(jnp.max(jnp.abs(got[0, 5]))) == 0.0
+    assert err(got, _swept(cfg, q, lat, sel, n_blocks, lp)) < 2e-5
+
+
+@pytest.fixture(scope="module")
+def long_tokens():
+    return np.random.default_rng(1).integers(0, 256, (2, 3 * PIECE),
+                                             dtype=np.int32)
+
+
+def test_forward_through_the_kernel_is_the_references(
+        params, long_tokens, monkeypatch):
+    """Pieces and key blocks of whole tiles and ``use_flash``: every
+    piece of ``forward`` (none is dense: a piece is longer than
+    ``index_topk``) attends through ``edl_sparse_prefill_attn``, and
+    the logits are the reference's at that length."""
+    from edl_tpu.ops import sparse_prefill_attention as spa
+
+    walk_in(monkeypatch, PIECE, BLOCK)
+    calls = []
+    kernel = spa.sparse_prefill_attention
+    monkeypatch.setattr(
+        spa, "sparse_prefill_attention",
+        lambda *a, **kw: calls.append(kw["block_k"]) or kernel(*a, **kw))
+    monkeypatch.setattr(g, "_sweep", None)
+    run = jax.jit(lambda p, t: reference.logits_row(p, t, CONFIG))
+    want = jnp.stack([run(params, jnp.asarray(row)) for row in long_tokens])
+    got = forward(params, long_tokens, cfg_of(use_flash=True))
+    assert err(got, want) < TOL
+    # traced once a layer in the scan's body (the first piece is a
+    # piece of the scan too)
+    assert calls == [BLOCK] * CONFIG["num_hidden_layers"]
+
+
+@pytest.mark.parametrize("piece, key_block, use_flash, int8, takes", [
+    (PIECE, BLOCK, True, False, "kernel"),
+    (2 * PIECE, BLOCK, True, False, "kernel"),
+    (8, 8, True, False, "sweep"),  # the other tests' sizes: not whole tiles
+    (PIECE, 8, True, False, "sweep"),
+    (PIECE, BLOCK, False, False, "sweep"),
+    (PIECE, BLOCK, True, True, "sweep"),  # an int8 record for Wkvb
+])
+def test_which_pieces_take_the_kernel(
+        params, long_tokens, monkeypatch, piece, key_block, use_flash, int8,
+        takes):
+    from edl_tpu.ops import sparse_prefill_attention as spa
+
+    walk_in(monkeypatch, piece, key_block)
+    took = []
+    monkeypatch.setattr(spa, "sparse_prefill_attention", lambda q, *a, **kw: (
+        took.append("kernel"), jnp.zeros(q.shape[:2] + (64,), q.dtype))[1])
+    sweep = g._sweep
+    monkeypatch.setattr(g, "_sweep", lambda *a: (
+        took.append("sweep"), sweep(*a))[1])
+    cfg = cfg_of(use_flash=use_flash)
+    tree = g.quantize_params_int8(params) if int8 else params
+    with interpret_kernels():
+        jax.eval_shape(lambda p, t: g.forward(p, t, cfg), tree,
+                       jnp.asarray(long_tokens[:, :2 * PIECE]))
+    assert set(took) == {takes}
+
+
 # -- (d) the share ties to the model -------------------------------------------
 
 

@@ -905,4 +905,26 @@ def test_long_sparse_largest_prefill_fits_beside_32_long_slots(v5e):
     assert mem.peak_memory_in_bytes < CHIP_BYTES
     assert mem.temp_size_in_bytes < 2.6e9
     assert "edl_flash_fwd" in text and "edl_grouped_expert_mlp" in text
+    assert "edl_sparse_prefill_attn" in text
     assert not _cache_sized(text, cfg, 32, 32768)
+
+
+def _kernel_calls(text, name):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and re.match(rf"\s*%{name}[.\d]* = ", line)]
+
+
+def test_long_sparse_prefill_attends_the_chosen_in_one_kernel_a_layer(v5e):
+    """``edl_serve_prefill_8192`` (a dense piece and a scan over three
+    more): the scan's body holds ``edl_sparse_prefill_attn`` once a
+    layer and the dense piece none (it runs ``edl_flash_fwd``, once a
+    layer), and nothing the size of a visit's scores (64 heads x 2048
+    rows x 512 keys in float32, 268 MB, which ``_sweep`` wrote and read
+    every visit: 125 such arrays in the program's text before PR 42)
+    or of its accumulator is made."""
+    cfg, _, prefill = _serving_programs(v5e, LONG_SPARSE, 8192)
+    text = prefill.compile().as_text()
+    assert len(_kernel_calls(text, "edl_sparse_prefill_attn")) == cfg.n_layers
+    assert len(_kernel_calls(text, "edl_flash_fwd")) == cfg.n_layers
+    assert not re.findall(r"f32\[(?:1,)?64,2048,(?:512|256)\]", text)
